@@ -300,25 +300,31 @@ def _hook_method(cls, name: str, what: str) -> bool:
 
 def install() -> bool:
     """Patch the array interposition + wrap the explicit transfer
-    APIs. Idempotent; returns whether the hooks are live. Lazy on
-    purpose: nothing is touched until a window is armed (or a test
-    installs explicitly), so the disabled path never pays."""
+    APIs. Idempotent; returns True once the hooks are live and RAISES
+    when they cannot be installed: a sanitizer that silently failed to
+    hook would leave the "0 undeclared transfers" gate passing on no
+    evidence. Lazy on purpose: nothing is touched until a window is
+    armed (or a test installs explicitly), so the disabled path never
+    pays."""
     global _installed
     if _installed:
         return True
     import jax
-    try:
-        from jaxlib.xla_extension import ArrayImpl
-    except ImportError:  # jaxlib layout drift: sanitizer degrades
-        return False
-    for name, what in (("__array__", "np.asarray()/__array__"),
-                       ("item", ".item()"),
-                       ("tolist", ".tolist()"),
-                       ("__float__", "float()"),
-                       ("__int__", "int()"),
-                       ("__bool__", "bool()"),
-                       ("__index__", "__index__")):
-        _hook_method(ArrayImpl, name, what)
+    from jaxlib._jax import ArrayImpl
+    missed = [name for name, what in (
+        ("__array__", "np.asarray()/__array__"),
+        ("item", ".item()"),
+        ("tolist", ".tolist()"),
+        ("__float__", "float()"),
+        ("__int__", "int()"),
+        ("__bool__", "bool()"),
+        ("__index__", "__index__"))
+        if not _hook_method(ArrayImpl, name, what)]
+    if missed:
+        _restore_originals()
+        raise RuntimeError(
+            f"jitsan: cannot interpose ArrayImpl.{'/'.join(missed)} on "
+            f"this jaxlib; the transfer gate would be unenforced")
 
     dg, dp = jax.device_get, jax.device_put
 
@@ -343,19 +349,21 @@ def install() -> bool:
     return True
 
 
+def _restore_originals() -> None:
+    """Restore every attribute recorded in ``_originals``."""
+    import jax
+    for (owner, name), orig in list(_originals.items()):
+        setattr(jax if owner == "jax" else owner, name, orig)
+    _originals.clear()
+
+
 def uninstall() -> None:
     """Restore every patched attribute (tests only; production leaves
     the hooks in place for the life of the process)."""
     global _installed
     if not _installed:
         return
-    import jax
-    for (owner, name), orig in list(_originals.items()):
-        if owner == "jax":
-            setattr(jax, name, orig)
-        else:
-            setattr(owner, name, orig)
-    _originals.clear()
+    _restore_originals()
     _installed = False
 
 
@@ -364,13 +372,12 @@ def uninstall() -> None:
 def arm(label: str, force: bool = False) -> bool:
     """Open a measurement window on the GLOBAL sanitizer. Returns
     whether the window is live: under ``NDS_TPU_JITSAN=1`` (or
-    ``force=True``) the hooks install and recording starts; otherwise
-    this is a no-op and :func:`disarm` reports an inactive window —
-    gates degrade to unenforced, never to wrong."""
+    ``force=True``) the hooks install (raising if they cannot) and
+    recording starts; otherwise this is a no-op and :func:`disarm`
+    reports an inactive window."""
     if not (enabled() or force):
         return False
-    if not install():
-        return False
+    install()
     _ensure_exit_report()
     _SAN.arm(label)
     return True

@@ -42,80 +42,6 @@ _override: "PlanCache | None" = None
 _override_set = False
 
 
-_codegen_checked = False
-
-
-def _jaxlib_knows_flag(flag: str) -> bool:
-    """Whether this jaxlib's XLA understands ``flag`` (grep over the
-    installed package, cached on disk per jaxlib+flag): an UNKNOWN
-    XLA_FLAGS entry aborts the process at first device use on jaxlib
-    >= 0.4.36, so never set one blind (same probe contract as
-    tests/conftest.py)."""
-    try:
-        import hashlib
-        import pathlib
-        import shlex
-        import subprocess
-        import tempfile
-
-        import jaxlib  # no backend init: metadata import only
-        root = os.path.dirname(os.path.abspath(jaxlib.__file__))
-        tag = hashlib.sha256(
-            f"{jaxlib.__version__}|{root}|{flag}".encode()
-        ).hexdigest()[:12]
-        cache = pathlib.Path(tempfile.gettempdir()) / (
-            f"nds_tpu_xlaflag_probe_{tag}")
-        if cache.exists():
-            return cache.read_text() == "1"
-        ok = subprocess.run(
-            ["sh", "-c", f"grep -rqs {shlex.quote(flag)} "
-                         f"{shlex.quote(root)}"],
-            timeout=120).returncode == 0
-        cache.write_text("1" if ok else "0")
-        return ok
-    except Exception:  # noqa: BLE001 - no grep/jaxlib layout surprises
-        return True
-
-
-def ensure_reloadable_codegen() -> None:
-    """Pin ``--xla_cpu_parallel_codegen_split_count=1`` before the
-    backend initializes (idempotent, once per process).
-
-    XLA:CPU splits large modules across parallel codegen units and the
-    serialized executable only carries the primary unit's symbols —
-    reloading a big program (sort comparators, reduce-window regions)
-    then fails with "Symbols not found". One codegen unit makes every
-    persisted executable reloadable; measured compile-time cost on the
-    NDS q93/96/7 set is ~2%. If jax already initialized its backends
-    the flag cannot take effect — persisted large CPU programs then
-    degrade to warned fresh compiles on reload, queries never fail."""
-    global _codegen_checked
-    if _codegen_checked:
-        return
-    _codegen_checked = True
-    flag = "xla_cpu_parallel_codegen_split_count"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if flag in flags:
-        return
-    import sys
-    if "jax" in sys.modules:
-        try:
-            from jax._src import xla_bridge as _xb
-            if getattr(_xb, "_backends", None):
-                # flags parse at first client creation; too late now
-                print("PLAN-CACHE NOTE: jax backend already "
-                      "initialized — cannot pin "
-                      f"--{flag}=1; large CPU executables may not "
-                      "reload from the cache (degrades to fresh "
-                      "compiles)")
-                return
-        except Exception:  # noqa: BLE001 - private-symbol drift
-            pass
-    if not _jaxlib_knows_flag(flag):
-        return
-    os.environ["XLA_FLAGS"] = f"{flags} --{flag}=1".strip()
-
-
 def configure(cache_dir: "str | None",
               readonly: bool = False) -> "PlanCache | None":
     """Programmatic activation (EngineConfig ``cache.dir`` path).
@@ -124,8 +50,6 @@ def configure(cache_dir: "str | None",
     global _override, _override_set
     _override = PlanCache(cache_dir, readonly) if cache_dir else None
     _override_set = True
-    if _override is not None:
-        ensure_reloadable_codegen()
     return _override
 
 
@@ -151,8 +75,6 @@ def active() -> "PlanCache | None":
     if key != _resolved_key:
         _resolved_key = key
         _resolved = PlanCache(d, ro) if d else None
-        if _resolved is not None:
-            ensure_reloadable_codegen()
     return _resolved
 
 
@@ -168,7 +90,6 @@ def export_env(cache_cfg) -> None:
         return
     os.makedirs(d, exist_ok=True)
     os.environ[ENV_DIR] = d
-    ensure_reloadable_codegen()
     # only an EXPLICIT yaml readonly key overrides the operator's
     # environment: a `cache: {dir}` block without it must not silently
     # clear a fleet-wide NDS_TPU_PLAN_CACHE_READONLY=1 pin and start

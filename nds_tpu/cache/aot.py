@@ -28,9 +28,20 @@ from __future__ import annotations
 
 import time
 
+from nds_tpu.analysis import locksan
 from nds_tpu.cache import fingerprint as fpmod
 
 _unserializable_warned: set = set()
+
+# Traces never interleave: tracing is where the executors fill their
+# trace-time collectors (the exchange's module-level skew sink, the
+# per-program side dicts), and a second thread tracing at the same
+# moment would hand one program the other's tracers. The XLA compile
+# that follows runs OUTSIDE the lock — it is single-threaded, releases
+# the GIL and costs minutes per sort-bearing program on the TPU, so
+# compiling several programs from several threads is how a cold start
+# stays inside a time limit (chip_smoke.py's warm-up does).
+_TRACE_LOCK = locksan.rlock("cache.aot._TRACE_LOCK")
 
 
 def platform_parts() -> dict:
@@ -94,12 +105,23 @@ def serialize_compiled(compiled) -> "tuple | None":
         return None
 
 
-def deserialize_compiled(payload: dict):
+def deserialize_compiled(payload: dict, devices=None):
     """payload dict -> live jax.stages.Compiled (raises on failure; the
-    caller treats any raise as a miss)."""
+    caller treats any raise as a miss).
+
+    ``devices`` are the devices the program was compiled for, in
+    assignment order: the mesh's devices for a sharded program; None
+    means the one default device every DeviceExecutor / chunk-scan /
+    compactor program runs on. jax must be told — left to itself it
+    loads the executable across ALL local devices and the first
+    dispatch dies on a shard-count mismatch (8 virtual CPU devices,
+    or a four-chip host)."""
+    import jax
     from jax.experimental import serialize_executable as se
-    return se.deserialize_and_load(payload["exec"], payload["in_tree"],
-                                   payload["out_tree"])
+    return se.deserialize_and_load(
+        payload["exec"], payload["in_tree"], payload["out_tree"],
+        execution_devices=(list(devices) if devices is not None
+                           else [jax.devices()[0]]))
 
 
 def lower_and_compile(jitted, *args, fresh: bool = False,
@@ -121,16 +143,30 @@ def lower_and_compile(jitted, *args, fresh: bool = False,
     from nds_tpu.analysis import jitsan
     jitsan.on_compile(kind)
     import jax
+    with _TRACE_LOCK:
+        lowered = jitted.lower(*args)
     if not fresh or not jax.config.jax_enable_compilation_cache:
-        return jitted.lower(*args).compile()
+        return _compile(lowered, kind)
     from nds_tpu.utils import xla_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    xla_cache._drop_memoized_verdict()
+    xla_cache.reset()
     try:
-        return jitted.lower(*args).compile()
+        return _compile(lowered, kind)
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
-        xla_cache._drop_memoized_verdict()
+        xla_cache.reset()
+
+
+def _compile(lowered, kind: str):
+    """The compiler's refusal surfaces as CompileRefused with its own
+    message (resilience/retry.py: deterministic, never an OOM)."""
+    import jax
+    from nds_tpu.resilience.retry import CompileRefused
+    try:
+        return lowered.compile()
+    except jax.errors.JaxRuntimeError as exc:
+        raise CompileRefused(
+            f"XLA refused to compile the {kind} program: {exc}") from exc
 
 
 def fresh_for(cache, fp: "str | None") -> bool:
@@ -169,21 +205,23 @@ def call_compatible(compiled, *args) -> bool:
 
 def load_cached(cache, fp: str, kind: str,
                 timings: "dict | None" = None,
-                args: "tuple | None" = None, count: bool = True):
+                args: "tuple | None" = None, count: bool = True,
+                devices=None):
     """Cache consult: -> (compiled, extra) on a verified hit, else
     None. Deserialize failures and signature-incompatible executables
     degrade to a miss (warned + counted); ``timings`` gains
     ``cache_load_ms`` on the hit path. ``count=False`` skips the hit
     increment for callers that still have their own verification to
     run (the sharded path's key-split compat check) and count the
-    final verdict themselves."""
+    final verdict themselves. ``devices``: see
+    :func:`deserialize_compiled`."""
     from nds_tpu.cache.store import _warn, obs_metrics
     t0 = time.perf_counter()
     payload = cache.get(fp, expect_kind=kind)
     if payload is None:
         return None
     try:
-        compiled = deserialize_compiled(payload)
+        compiled = deserialize_compiled(payload, devices)
     except Exception as exc:  # noqa: BLE001 - degrade to fresh compile
         _warn(f"deserialize failed for {fp[:12]}… "
               f"({type(exc).__name__}: {exc}); recompiling fresh")
@@ -213,7 +251,7 @@ def load_cached(cache, fp: str, kind: str,
 
 def persist(cache, fp: str, kind: str, compiled,
             extra: "dict | None" = None,
-            meta: "dict | None" = None) -> bool:
+            meta: "dict | None" = None, devices=None) -> bool:
     """Serialize + store a freshly compiled program (no-op on readonly
     caches and unserializable backends).
 
@@ -232,7 +270,7 @@ def persist(cache, fp: str, kind: str, compiled,
     if platform_parts().get("platform") == "cpu":
         try:
             deserialize_compiled({"exec": blob, "in_tree": in_tree,
-                                  "out_tree": out_tree})
+                                  "out_tree": out_tree}, devices)
         except Exception as exc:  # noqa: BLE001 - capability probe
             key = f"roundtrip:{type(exc).__name__}"
             if key not in _unserializable_warned:

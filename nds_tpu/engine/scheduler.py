@@ -501,8 +501,8 @@ class ExecutionPipeline:
     @staticmethod
     def _default_channel(backend: str):
         # probe jax ONLY for the distributed backend: process_count()
-        # initializes the platform, and a pure-CPU phase must never
-        # touch (or block on) a remote accelerator plugin
+        # initializes the platform, and a host-only phase must never
+        # open a chip another process may need
         if backend != "distributed":
             return NullChannel()
         try:

@@ -6,8 +6,13 @@ subprocesses in spec order — data-gen (base + per-stream refresh sets)
 `nds/nds_bench.py:60-74`) -> power -> throughput 1 -> maintenance 1 ->
 throughput 2 -> maintenance 2 -> validate (optional: post-maintenance
 engine outputs diffed against a CPU-oracle round, nds/validate.py) —
-with crash isolation via report-file state passing (SURVEY.md §3.4),
-then compute the 4-term composite metric (`nds/nds_bench.py:334-357`):
+with crash isolation via report-file state passing (SURVEY.md §3.4).
+The orchestrator itself never touches jax: a chip belongs to one
+process, so every device phase is ONE child at a time — with
+``backend: tpu`` each throughput test is a single ``--in_process``
+child time-sharing the chip, never a fan-out and never run inside the
+orchestrator (which would then hold the chip against the maintenance
+child that follows). Then compute the 4-term composite metric (`nds/nds_bench.py:334-357`):
 
     Q   = Sq * 99
     Tpt = Tpower * Sq / 3600 ;  Ttt = (Ttt1 + Ttt2) / 3600
@@ -277,44 +282,54 @@ def run_full_bench(cfg: dict, resume: bool = False) -> dict:
         "power_test", _power_test)["power_time_s"]
 
     def _throughput(round_no):
-        from nds_tpu.nds.throughput import (
-            run_streams, run_streams_inprocess,
-        )
+        from nds_tpu.nds import throughput as tp
         streams_n = get_stream_range(num_streams, round_no)
         tstreams = [os.path.join(stream_dir, f"query_{i}.sql")
                     for i in streams_n]
         tdir = os.path.join(report_dir, f"throughput{round_no}")
-        # one TPU chip cannot be opened by N subprocesses; the
-        # in-process mode time-shares it (cpu/distributed keep the
-        # reference's process fan-out). Overridable via YAML.
+        # one TPU chip cannot be opened by N subprocesses: there the
+        # test is ONE child that time-shares it (--in_process);
+        # cpu/distributed keep the reference's process fan-out, which
+        # this process supervises without touching jax. Overridable
+        # via YAML.
         mode = cfg.get("throughput_mode",
                        "inprocess" if backend == "tpu"
                        else "subprocess")
-        # in-process mode starts its own emitter in THIS process;
-        # subprocess mode inherits the var (run_streams re-points it
-        # per stream). Save/restore so a user's own setting survives.
         snap_env = _snap_env(f"throughput{round_no}") or {}
-        saved = {k: os.environ.get(k) for k in snap_env}
-        os.environ.update(snap_env)
-        try:
-            if mode == "inprocess":
-                ttt, codes = run_streams_inprocess(
-                    wh_dir, tstreams, tdir, backend=backend)
-            else:
+        if mode == "inprocess":
+            cmd = [sys.executable, "-m", "nds_tpu.nds.throughput",
+                   wh_dir, *tstreams, "--out_dir", tdir,
+                   "--backend", backend, "--in_process"]
+            # the child's report file is the only way Ttt comes back:
+            # a stale one must not stand in for a child that died
+            elapse_path = os.path.join(tdir, tp.ELAPSE_FILE)
+            if os.path.exists(elapse_path):
+                os.remove(elapse_path)
+            rc = _run_rc(cmd, backend=backend, extra_env=snap_env)
+            if not os.path.exists(elapse_path):
+                raise subprocess.CalledProcessError(rc or 1, cmd)
+            ttt, codes = tp.read_elapse(tdir)
+        else:
+            # run_streams re-points the snapshot var per stream; it
+            # reads it from THIS process's environment. Save/restore
+            # so a user's own setting survives.
+            saved = {k: os.environ.get(k) for k in snap_env}
+            os.environ.update(snap_env)
+            try:
                 # YAML ``watchdog: {stall_s, max_restarts}`` arms
                 # subprocess stream supervision (kill + bounded
                 # restarts; README Resilience)
                 wd_cfg = cfg.get("watchdog") or {}
-                ttt, codes = run_streams(
+                ttt, codes = tp.run_streams(
                     wh_dir, tstreams, tdir, backend=backend,
                     stall_s=wd_cfg.get("stall_s"),
                     max_restarts=wd_cfg.get("max_restarts"))
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
+            finally:
+                for k, v in saved.items():
+                    if v is None:
+                        os.environ.pop(k, None)
+                    else:
+                        os.environ[k] = v
         _analyze_phase(f"throughput{round_no}", tdir)
         if any(codes):
             raise SystemExit(

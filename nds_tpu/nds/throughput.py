@@ -19,10 +19,43 @@ instead of a bare failure count.
 from __future__ import annotations
 
 import argparse
+import json
 import math
 import os
 import sys
 import time
+
+ELAPSE_FILE = "throughput_elapse.json"
+
+
+def refuse_chip_fanout(backend: str, n_streams: int) -> None:
+    """A chip belongs to one process at a time: ``backend=tpu`` streams
+    launched as N subprocesses would each open the same device, and all
+    but the first fail or hang. Fail fast, before anything is spawned,
+    unless the user pinned ``JAX_PLATFORMS=cpu`` (the CPU rehearsal,
+    where children share nothing)."""
+    from nds_tpu.utils.power_core import cpu_pinned
+    if backend != "tpu" or n_streams < 2 or cpu_pinned():
+        return
+    raise RuntimeError(
+        f"{n_streams} subprocess streams cannot share one TPU chip "
+        f"(--backend tpu): run the throughput test with --in_process "
+        f"(one process time-shares the chip across all streams)")
+
+
+def write_elapse(out_dir: str, elapse: float, codes: list) -> None:
+    """The throughput phase's report file: the bench orchestrators run
+    this driver as a child and read Ttt back from here (state passes
+    between phases via files, never via the orchestrator's memory)."""
+    from nds_tpu.io.integrity import write_json_atomic
+    write_json_atomic(os.path.join(out_dir, ELAPSE_FILE),
+                      {"elapse_s": elapse, "codes": list(codes)})
+
+
+def read_elapse(out_dir: str) -> "tuple[float, list]":
+    with open(os.path.join(out_dir, ELAPSE_FILE)) as f:
+        doc = json.load(f)
+    return float(doc["elapse_s"]), list(doc["codes"])
 
 
 def _stream_specs(data_dir: str, stream_paths: list[str], out_dir: str,
@@ -94,6 +127,7 @@ def run_streams(data_dir: str, stream_paths: list[str], out_dir: str,
     from nds_tpu.resilience.supervise import (
         StreamSupervisor, describe_summary,
     )
+    refuse_chip_fanout(backend, len(stream_paths))
     os.makedirs(out_dir, exist_ok=True)
     specs = _stream_specs(data_dir, stream_paths, out_dir, backend,
                           input_format, allow_failure,
@@ -115,8 +149,9 @@ def run_streams(data_dir: str, stream_paths: list[str], out_dir: str,
 def run_streams_inprocess(data_dir: str, stream_paths: list[str],
                           out_dir: str, backend: str = "tpu",
                           input_format: str = "parquet",
-                          ) -> tuple[float, list[int]]:
-    """Single-process multi-stream throughput for ONE-chip runs.
+                          suite=None) -> tuple[float, list[int]]:
+    """Single-process multi-stream throughput for ONE-chip runs
+    (``suite``: a power_core.Suite; default NDS).
 
     The reference splits cluster executors between concurrent streams
     (`nds/README.md:530-535`); N subprocesses each opening the same
@@ -138,19 +173,21 @@ def run_streams_inprocess(data_dir: str, stream_paths: list[str],
     snap = MetricsSnapshotter.from_env(progress)
     if snap:
         snap.start()
+    if suite is None:
+        from nds_tpu.nds.power import SUITE as suite
     try:
-        return _run_streams_inprocess(data_dir, stream_paths, out_dir,
-                                      backend, input_format, progress)
+        return _run_streams_inprocess(suite, data_dir, stream_paths,
+                                      out_dir, backend, input_format,
+                                      progress)
     finally:
         if snap:
             progress["current_query"] = None
             snap.stop()
 
 
-def _run_streams_inprocess(data_dir, stream_paths, out_dir, backend,
-                           input_format, progress
+def _run_streams_inprocess(suite, data_dir, stream_paths, out_dir,
+                           backend, input_format, progress
                            ) -> tuple[float, list[int]]:
-    from nds_tpu.nds.power import SUITE
     from nds_tpu.resilience import faults
     from nds_tpu.resilience.journal import QueryJournal, config_digest
     from nds_tpu.resilience.retry import (
@@ -168,11 +205,11 @@ def _run_streams_inprocess(data_dir, stream_paths, out_dir, backend,
     start = time.time()
     config = EngineConfig(overrides={"engine.backend": backend})
     policy = RetryPolicy.from_config(config)
-    session = power_core.make_session(SUITE, config)
+    session = power_core.make_session(suite, config)
     pipeline = session._executor_factory(session.tables)
     power_core.load_warehouse(
-        SUITE, session, data_dir, input_format,
-        schemas=power_core.suite_schemas(SUITE, config))
+        suite, session, data_dir, input_format,
+        schemas=power_core.suite_schemas(suite, config))
     streams = []
     for sp in stream_paths:
         name = os.path.splitext(os.path.basename(sp))[0]
@@ -186,8 +223,8 @@ def _run_streams_inprocess(data_dir, stream_paths, out_dir, backend,
         qj.reset()
         streams.append({
             "name": name,
-            "queries": list(SUITE.parse_query_stream(sp).items()),
-            "tlog": TimeLog(f"nds-tpu-throughput-{name}"),
+            "queries": list(suite.parse_query_stream(sp).items()),
+            "tlog": TimeLog(f"{suite.name}-tpu-throughput-{name}"),
             "failures": 0,
             # per-stream BenchReport material: statuses/exception text
             # per query, so throughput failures are diagnosable from
@@ -374,6 +411,7 @@ def main(argv=None) -> None:
                                     args.allow_failure,
                                     stall_s=args.stall_s,
                                     max_restarts=args.max_restarts)
+    write_elapse(args.out_dir, elapse, codes)
     print(f"Throughput Time: {elapse} s over {len(args.streams)} streams")
     sys.exit(1 if any(codes) and not args.allow_failure else 0)
 

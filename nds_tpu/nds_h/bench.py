@@ -2,7 +2,11 @@
 
 The NDS-H analog of `nds/nds_bench.py:367-498`: run phases in TPC order
 as subprocesses (crash isolation by design — state passes via report
-files, SURVEY.md §3.4), then compute a composite metric.
+files, SURVEY.md §3.4), then compute a composite metric. The
+orchestrator itself never touches jax: a chip belongs to one process,
+so every device phase is ONE child at a time (with ``backend: tpu`` the
+throughput test is a single ``--in_process`` child time-sharing the
+chip, not a fan-out).
 
 Phases: data-gen -> load(transcode) -> stream-gen (RNGSEED = load end
 timestamp, `nds/nds_bench.py:60-74`) -> power -> throughput. TPC-H has no
@@ -104,12 +108,15 @@ def run_full_bench(cfg: dict) -> dict:
                 for i in range(1, num_streams + 1)]
     ttt = None
     if not cfg.get("skip", {}).get("throughput_test", False):
-        from nds_tpu.nds_h.throughput import run_streams
-        ttt, codes = run_streams(
-            wh_dir, tstreams, os.path.join(report_dir, "throughput"),
-            backend=backend)
-        if any(codes):
-            raise SystemExit(f"throughput streams failed: {codes}")
+        from nds_tpu.nds.throughput import read_elapse
+        tdir = os.path.join(report_dir, "throughput")
+        cmd = [sys.executable, "-m", "nds_tpu.nds_h.throughput",
+               wh_dir, *tstreams, "--out_dir", tdir,
+               "--backend", backend]
+        if backend == "tpu":
+            cmd.append("--in_process")
+        _run(cmd, backend=backend)
+        ttt, _codes = read_elapse(tdir)
     metrics["throughput_time_s"] = ttt
 
     # no composite without a real throughput term (a fabricated Ttt would

@@ -49,7 +49,7 @@ CATEGORIES = ("parse_plan", "compile", "execute", "materialize",
               "host_staging", "prefetch_wait", "exchange",
               "straggler_wait", "retry_backoff")
 
-# span name -> category (exact names; see README span taxonomy)
+# span name -> category (exact names; see README span catalogue)
 _SPAN_CATEGORY = {
     "sql.parse": "parse_plan",
     "sql.plan": "parse_plan",
@@ -1096,8 +1096,8 @@ def diff_runs(base: dict, cur: dict, pct: float = 10.0,
     if chr_base is not None or chr_cur is not None:
         d["cache_hit_rate"] = {"base": chr_base, "cur": chr_cur}
     # banked/stale device times are not comparable evidence: a diff
-    # over them must FAIL loudly (ROADMAP item 2 — the BENCH_r04/r05
-    # rot class), never gate-pass on numbers nobody measured this run
+    # over them must FAIL loudly, never gate-pass on numbers nobody
+    # measured this run
     stale = {side: a["stale_device_times"]
              for side, a in (("base", base), ("cur", cur))
              if a.get("stale_device_times")}

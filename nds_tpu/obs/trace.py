@@ -26,7 +26,7 @@ Design constraints, in order:
   README "Observability"), and the root is retained on
   ``Tracer.last_roots`` for the BenchReport JSON.
 
-The span taxonomy and the event schema are documented in the README
+The span catalogue and the event schema are documented in the README
 and enforced by ``tools/check_trace_schema.py``.
 """
 

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P_
 
-from nds_tpu.analysis import jitsan
+from nds_tpu.analysis import jitsan, locksan
 from nds_tpu.engine import device_exec as dx
 from nds_tpu.engine.device_exec import DCtx, DVal, DeviceExecError, _ok
 from nds_tpu.io.host_table import HostTable
@@ -48,22 +48,23 @@ from nds_tpu.resilience import faults
 from nds_tpu.sql import plan as P
 from nds_tpu.utils.report import TaskFailureCollector
 
-if hasattr(jax, "shard_map"):  # jax>=0.8
-    _shard_map = jax.shard_map
-else:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+_DISPATCH_LOCK = locksan.lock("parallel.dist_exec._DISPATCH_LOCK")
 
 
 def shard_map(fn, **kw):
-    """shard_map with replication checking off, across jax versions (the
-    kwarg was renamed check_rep -> check_vma)."""
-    import inspect
-    params = inspect.signature(_shard_map).parameters
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return _shard_map(fn, **kw)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(fn, check_vma=False, **kw)
+
+
+def _pextreme(op, x, axes):
+    """Cross-device min/max of a per-device scalar (``op`` is jnp.min
+    or jnp.max): gather the scalars and reduce locally. NOT
+    lax.pmin/pmax — the TPU's 64-bit emulation lowers only SUM
+    all-reduces, and an s64 pmax is refused by its compiler
+    ("UNIMPLEMENTED: Supported lowering only of Sum all reduce"; the
+    scaled-int64 decimals and the overflow count are all s64). Same
+    value on every platform."""
+    return op(lax.all_gather(x, axes))
 
 # tables at or above this row count shard across the mesh; smaller ones
 # replicate (the Spark broadcast threshold analog, but by rows)
@@ -148,13 +149,19 @@ class DistributedExecutor(dx.DeviceExecutor):
         obs_metrics.counter("slack_replans_total").inc()
 
     def _dev(self, arr: np.ndarray, sharded: bool):
-        """Host array -> device buffer. Single-process: plain upload
-        (jit lays it out). Multi-process: a global jax.Array built
-        shard-by-shard so each host only holds its own rows."""
-        if not self.multiprocess:
-            return jnp.asarray(arr)
-        from nds_tpu.parallel.multihost import make_global_array
+        """Host array -> device buffer laid out on the MESH: a sharded
+        table's rows split across its devices, everything else
+        replicated on each. (A plain ``jnp.asarray`` lands whole on
+        device 0, and the compiled program then re-distributes it on
+        EVERY dispatch — invisible on virtual CPU devices, a full-table
+        copy per query on real chips.) Multi-process: a global
+        jax.Array built shard-by-shard so each host only holds its own
+        rows."""
         spec = P_(self.axes) if sharded else P_()
+        if not self.multiprocess:
+            from jax.sharding import NamedSharding
+            return jax.device_put(arr, NamedSharding(self.mesh, spec))
+        from nds_tpu.parallel.multihost import make_global_array
         return make_global_array(self.mesh, spec, np.asarray(arr))
 
     # buffers: sharded tables pad to a multiple of n_dev
@@ -290,8 +297,9 @@ class DistributedExecutor(dx.DeviceExecutor):
         # key-split compat check load_cached cannot run itself
         with tracer.span("cache.load", fp=fp[:12]):
             bufs = self._collect_buffers(planned)
-            hit = cache_aot.load_cached(pc, fp, type(self).__name__,
-                                        timings, count=False)
+            hit = cache_aot.load_cached(
+                pc, fp, type(self).__name__, timings, count=False,
+                devices=self.mesh.devices.flat)
         if hit is None:
             return False
         compiled, extra = hit
@@ -326,7 +334,8 @@ class DistributedExecutor(dx.DeviceExecutor):
                                "dicts": side.get("dicts"),
                                "kernels": side.get("kernels"),
                                "ops_est": side.get("ops_est")},
-                              meta={"slack": slack})
+                              meta={"slack": slack},
+                              devices=self.mesh.devices.flat)
 
     # survivor cap for turning a SHARDED filtered scan into a
     # replicated reduced build side (the broadcast-join move Spark AQE
@@ -381,7 +390,7 @@ class DistributedExecutor(dx.DeviceExecutor):
     # tighter than the single-chip default: 8-device shard_map compile
     # memory/time is the binding constraint (q64 traced to 54k jaxpr
     # eqns in ONE program and its 8-device compile exceeded 130 GB host
-    # RAM before splitting — VERDICT r4 weak #2)
+    # RAM before splitting — DIST99.json HOST_LIMIT)
     STAGE_WEIGHT = int(os.environ.get("NDS_TPU_STAGE_DIST", "24"))
 
     def _plan_for_dispatch(self, planned):
@@ -526,12 +535,18 @@ class DistributedExecutor(dx.DeviceExecutor):
                                      state["jitted"])
             # ndslint: waive[NDS102] -- execute bracket start; closed below after device_get
             t1 = _time.perf_counter()
-            with jitsan.dispatch(type(self).__name__):
-                row, outs, overflow, skew = state["jitted"](shard_bufs,
-                                                            repl_bufs)
-            # one batched device->host round trip (see DeviceExecutor)
-            row_h, outs_h, overflow_h, skew_h = jax.device_get(
-                (row, outs, overflow, skew))
+            # one collective program in flight per process: two host
+            # threads launching multi-device programs can enqueue them
+            # on the devices in different orders, and the collectives
+            # then wait on each other for ever
+            with _DISPATCH_LOCK:
+                with jitsan.dispatch(type(self).__name__):
+                    row, outs, overflow, skew = state["jitted"](
+                        shard_bufs, repl_bufs)
+                # one batched device->host round trip (see
+                # DeviceExecutor)
+                row_h, outs_h, overflow_h, skew_h = jax.device_get(
+                    (row, outs, overflow, skew))
             if float(skew_h) > 0:
                 # worst per-shuffle destination skew this program saw:
                 # visible in live snapshots before it becomes a
@@ -589,7 +604,7 @@ class _DistTrace(dx._Trace):
         for o in self._overflows[1:]:
             tot = tot + o.astype(jnp.int64)
         # every device sees every exchange; max across devices is enough
-        return lax.pmax(tot, self.axes)
+        return _pextreme(jnp.max, tot, self.axes)
 
     # ------------------------------------------------------------- helpers
 
@@ -891,9 +906,8 @@ class _DistTrace(dx._Trace):
             else:
                 fill = I64_MAX if spec.func == "min" else I64_MIN
                 masked = jnp.where(w, dv.arr.astype(jnp.int64), fill)
-            red = jnp.min(masked) if spec.func == "min" else jnp.max(masked)
-            red = (lax.pmin(red, self.axes) if spec.func == "min"
-                   else lax.pmax(red, self.axes))
+            op = jnp.min if spec.func == "min" else jnp.max
+            red = _pextreme(op, op(masked), self.axes)
             return red.reshape(1), valid, dv.sdict
         raise DeviceExecError(spec.func)
 

@@ -1,18 +1,20 @@
 """Failure classification + retry policy with backoff and deadlines.
 
 Accelerator runtimes fail in modes classic SQL engines never see
-(PAPERS.md, Query Processing on Tensor Computation Runtimes): HBM
-exhaustion and compile-time resource errors are TRANSIENT — a retry
-after freeing buffers, shrinking chunks, or doubling exchange slack
-usually succeeds — while parse/plan/verify errors are DETERMINISTIC
-and retrying them just triples the time to the same stack trace. This
-module is the single place that distinction lives:
+(PAPERS.md, Query Processing on Tensor Computation Runtimes): a
+RUNTIME HBM exhaustion is TRANSIENT — a retry after freeing buffers,
+shrinking chunks, or doubling exchange slack usually succeeds — while
+parse/plan/verify errors and a program the COMPILER refuses
+(``CompileRefused``) are DETERMINISTIC and retrying them just triples
+the time to the same stack trace. This module is the single place that
+distinction lives:
 
 - ``classify(exc)`` -> TRANSIENT | DETERMINISTIC. Transient: injected
   faults (``resilience.faults``), RESOURCE_EXHAUSTED / out-of-memory
-  (jaxlib's XlaRuntimeError vocabulary), exchange-capacity overflow.
-  Everything else — parse/plan/verify errors included — is
-  deterministic and never retried.
+  raised at dispatch (jaxlib's XlaRuntimeError vocabulary),
+  exchange-capacity overflow. Everything else — parse/plan/verify
+  errors and compile-time refusals included — is deterministic and
+  never retried.
 - ``RetryPolicy`` — attempt cap, exponential backoff with seeded
   deterministic jitter, and a per-query wall-clock deadline. Owned by
   the unified execution pipeline (``engine/scheduler.py``), which runs
@@ -85,6 +87,21 @@ def check_deadline() -> None:
             "query deadline exceeded mid-attempt "
             "(engine.query_deadline_s)")
 
+class CompileRefused(RuntimeError):
+    """The XLA compiler refused a program (``cache/aot.py
+    lower_and_compile`` raises it with the compiler's words). Always
+    DETERMINISTIC and never an OOM, whatever the message says: the TPU
+    compiler reports a program that cannot fit the chip as
+    ``RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of
+    memory in memory space hbm`` — the same status word a runtime
+    allocation failure carries — and compiling the same program again
+    can only refuse again. Left to the message markers below it would
+    walk device -> chunked -> cpu and end as a CPU wall-clock under
+    ``backend=tpu``; it must surface as that query's failure. A
+    RUNTIME allocation failure (raised at dispatch, not here) keeps
+    walking the ladder."""
+
+
 # message fragments that mark a transient accelerator/runtime failure
 # (jaxlib surfaces device OOM as XlaRuntimeError("RESOURCE_EXHAUSTED:
 # ..."); the exchange retry loop raises on persisted overflow)
@@ -101,6 +118,8 @@ def is_oom(exc: BaseException) -> bool:
     halves its chunk size on these before giving up)."""
     if isinstance(exc, faults_mod.InjectedOOM):
         return True
+    if isinstance(exc, CompileRefused):
+        return False
     msg = str(exc)
     return "RESOURCE_EXHAUSTED" in msg or "ut of memory" in msg
 
@@ -116,7 +135,7 @@ def classify(exc: BaseException) -> str:
         return DETERMINISTIC
     if isinstance(exc, faults_mod.InjectedTransientFault):
         return TRANSIENT
-    if isinstance(exc, QueryDeadlineExceeded):
+    if isinstance(exc, (QueryDeadlineExceeded, CompileRefused)):
         return DETERMINISTIC
     from nds_tpu.io.integrity import CorruptArtifact
     if isinstance(exc, CorruptArtifact):

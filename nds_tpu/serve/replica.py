@@ -176,9 +176,13 @@ async def serve_replica(srv, host: str, port: int,
     # handle resolves), then exit resumable
     print(f"[replica {srv.replica_id}] draining "
           f"(budget {drain_s:g}s)", flush=True)
-    tcp.close()     # the listener only: live connections keep
-    await asyncio.wait_for(  # serving while the backlog drains
-        tcp.wait_closed(), timeout=30.0)
+    # the listener only: live connections keep serving while the
+    # backlog drains. close() shuts the listening sockets at once; NOT
+    # awaiting wait_closed() here — on Python 3.12 it also waits for
+    # every live connection to end, and the router holds its connection
+    # open for the replica's whole life, so the drain would sit out a
+    # timeout instead of exiting 75
+    tcp.close()
     srv.begin_drain()
     deadline = time.monotonic() + max(0.1, drain_s)
     while time.monotonic() < deadline:

@@ -1,52 +1,49 @@
 """Persistent XLA compilation cache.
 
-The engine compiles one XLA program per (query, scale factor). First
-compiles are expensive (tens of seconds on TPU); the jax persistent
+The engine compiles one XLA program per (query, scale factor), and on
+the TPU every program that carries a 64-bit sort costs minutes to
+compile (CHANGES.md PR 21 has the rehearsed seconds); jax's persistent
 compilation cache amortizes them across processes and across benchmark
 rounds — the engine-side analog of the reference's warmed-JVM steady
 state (`nds/nds_power.py:184-322` keeps one Spark session across the
 whole stream for the same reason).
+
+Where it lives is decided from OUTSIDE the program: if
+``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it and this
+module sets no directory at all; otherwise the cache is the fixed
+``<checkout>/.xla_cache``. Nothing is derived from a pid, the time, a
+temp name or the order of calls — a cache whose path moves between
+runs never hits.
 """
 
 from __future__ import annotations
 
 import os
 
-_DEFAULT_DIR = os.path.join(
+DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".xla_cache")
 
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
-def enable(cache_dir: str | None = None) -> str:
-    """Turn on jax's persistent compilation cache. Idempotent.
 
-    The cache dir is suffixed by a digest of the XLA_FLAGS in effect:
-    jax's cache key EXCLUDES codegen debug options, so an entry
-    compiled under different flags would otherwise be served silently
-    — observed as "Symbols not found" when the plan cache
-    (nds_tpu/cache/) re-serializes an executable a stale entry built
-    with parallel-split codegen (cache.ensure_reloadable_codegen pins
-    the split count precisely so executables can reload)."""
-    import hashlib
-
+def enable() -> str:
+    """Turn on jax's persistent compilation cache. Idempotent; returns
+    the directory in use."""
     import jax
 
-    cache_dir = cache_dir or os.environ.get(
-        "NDS_TPU_XLA_CACHE", _DEFAULT_DIR)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if flags:
-        cache_dir = os.path.join(
-            cache_dir,
-            "flags-" + hashlib.sha256(flags.encode()).hexdigest()[:10])
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    path = os.environ.get(ENV_DIR)
+    if not path:
+        path = DEFAULT_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # a prior disable() must not stick
     jax.config.update("jax_enable_compilation_cache", True)
     # cache every program: benchmark queries are all worth persisting
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _drop_memoized_verdict()
-    return cache_dir
+    reset()
+    return path
 
 
 def disable() -> None:
@@ -57,20 +54,13 @@ def disable() -> None:
     compiles."""
     import jax
     jax.config.update("jax_enable_compilation_cache", False)
-    _drop_memoized_verdict()
+    reset()
 
 
-def _drop_memoized_verdict() -> None:
-    """``compilation_cache.is_cache_used`` memoizes its on/off verdict
-    at the FIRST compile and then ignores every later
-    ``jax_enable_compilation_cache`` update, so an enable()/disable()
-    after any compile would silently not take. ``reset_cache()`` drops
-    the memo (and the dir-bound cache singleton) so the next compile
+def reset() -> None:
+    """jax memoizes the cache's on/off verdict (and the directory-bound
+    cache object) at the FIRST compile and then ignores every later
+    config update; ``reset_cache()`` drops both so the next compile
     re-reads the config."""
-    try:
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 - private API: a jax that moved it
-        # presumably also dropped the memoization; the config update
-        # above is then sufficient, and session creation must not die
-        pass
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()

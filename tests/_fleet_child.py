@@ -35,7 +35,6 @@ import sys
 
 def setup(port: str, pid: int, nproc: int, ndev: int) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["NDS_TPU_PLATFORM"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     flags = " ".join(f for f in flags.split()
                      if "xla_force_host_platform_device_count" not in f)
@@ -46,8 +45,6 @@ def setup(port: str, pid: int, nproc: int, ndev: int) -> None:
     os.environ["NDS_TPU_COORDINATOR"] = f"localhost:{port}"
     os.environ["NDS_TPU_NUM_PROCESSES"] = str(nproc)
     os.environ["NDS_TPU_PROCESS_ID"] = str(pid)
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 

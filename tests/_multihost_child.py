@@ -16,7 +16,6 @@ def main() -> None:
     # 4-process tier runs 4x2, the 2-process tier 2x4
     ndev = int(sys.argv[4]) if len(sys.argv) > 4 else 4
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["NDS_TPU_PLATFORM"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     flags = " ".join(f for f in flags.split()
                      if "xla_force_host_platform_device_count" not in f)
@@ -31,7 +30,6 @@ def main() -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 

@@ -192,6 +192,25 @@ class TestJitsanRuntime:
     def test_selftest(self, jitsan):
         assert jitsan.selftest()
 
+    def test_install_is_live_on_this_jaxlib(self, jitsan):
+        """The interposition really sits on the array type jax hands
+        out (jaxlib moved it once: install() then "degraded" in silence
+        and the transfer gate enforced nothing)."""
+        import jax.numpy as jnp
+        hooked = type(jnp.zeros(1)).item
+        assert hooked.__closure__ is not None      # our wrapper
+        assert jitsan.install() is True            # idempotent
+
+    def test_install_raises_when_it_cannot_interpose(self, monkeypatch):
+        from nds_tpu.analysis import jitsan as js
+        js.uninstall()
+        monkeypatch.setattr(js, "_hook_method", lambda *a: False)
+        with pytest.raises(RuntimeError, match="cannot interpose"):
+            js.install()
+        with pytest.raises(RuntimeError, match="cannot interpose"):
+            js.arm("gate", force=True)   # an armed gate never degrades
+        assert js._originals == {}
+
 
 def test_static_catalog_covers_documented_rules():
     ids = {r.id for r in jit_hazards.default_rules()}

@@ -250,6 +250,57 @@ class TestAot:
         assert parts["platform"] == "cpu"
         assert "jax" in parts and "jaxlib" in parts
 
+    def test_hit_dispatches_on_a_many_device_host(self, tmp_path):
+        """A persisted single-device program must load onto ONE device
+        of an 8-device host (or a four-chip one): left to jax, the
+        executable loads across all local devices and its first
+        dispatch dies with "Expected args to
+        execute_sharded_on_local_devices to have 8 shards"."""
+        import jax
+        import jax.numpy as jnp
+
+        from nds_tpu.cache import aot
+        assert len(jax.devices()) == 8
+        store = PlanCache(str(tmp_path / "c"))
+        fp = "56" + "0" * 62
+        x = jnp.arange(64, dtype=jnp.float32)   # a live device buffer
+        compiled = aot.lower_and_compile(
+            jax.jit(lambda a: jnp.cumsum(a) * 2), x, fresh=True)
+        assert aot.persist(store, fp, "T", compiled)
+        loaded, _extra = aot.load_cached(store, fp, "T", args=(x,))
+        out = loaded(x)
+        assert out.sharding.device_set == {jax.devices()[0]}
+        assert np.array_equal(np.asarray(out), np.asarray(compiled(x)))
+
+    def test_mesh_program_hit_dispatches_on_its_mesh(self, tmp_path):
+        """...and a sharded program onto its mesh's devices, in mesh
+        order: four of the eight here."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P_
+
+        from nds_tpu.cache import aot
+        from nds_tpu.parallel.dist_exec import shard_map
+        from nds_tpu.parallel.mesh import DATA_AXIS, make_mesh
+        mesh = make_mesh(4)
+        fn = jax.jit(shard_map(
+            lambda a: a + lax.psum(jnp.sum(a), DATA_AXIS), mesh=mesh,
+            in_specs=P_(DATA_AXIS), out_specs=P_(DATA_AXIS)))
+        x = jax.device_put(np.arange(64, dtype=np.float32),
+                           NamedSharding(mesh, P_(DATA_AXIS)))
+        compiled = aot.lower_and_compile(fn, x, fresh=True)
+        store = PlanCache(str(tmp_path / "c"))
+        fp = "78" + "0" * 62
+        assert aot.persist(store, fp, "T", compiled,
+                           devices=mesh.devices.flat)
+        loaded, _extra = aot.load_cached(store, fp, "T", args=(x,),
+                                         devices=mesh.devices.flat)
+        out = loaded(x)
+        assert out.sharding.device_set == set(mesh.devices.flat)
+        assert np.array_equal(np.asarray(out), np.asarray(x) + 2016.0)
+
 
 # ----------------------------------------- executor integration (device)
 

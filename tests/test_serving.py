@@ -417,36 +417,64 @@ class TestAnalyzeTenants:
         assert analyze.diff_runs(a_clean, a_clean)["passed"] is True
 
 
-# ----------------------------------------------------- bench stale exit
+# ------------------------------------------- bench / cache placement
 
-class TestBenchStaleExit:
-    def test_stale_bank_emits_but_fails(self, tmp_path, monkeypatch,
-                                        capsys):
-        import bench
-        monkeypatch.setattr(bench, "DATA_ROOT", str(tmp_path))
-        monkeypatch.setattr(bench, "LEGS", ["nds_h"])
-        monkeypatch.setattr(bench, "_probe_backend", lambda *a: "")
-        bench.BANK.clear()
-        with open(bench._dev_bank_path("nds_h"), "w") as f:
-            json.dump({"rows": None, "times": {"1": 2.0}}, f)
-        with open(bench._cpu_bank_path("nds_h"), "w") as f:
-            json.dump({"rows": None, "times": {"1": 4.0}}, f)
-        rc = bench.main()
-        assert rc == bench.EXIT_STALE_METRIC
-        out = capsys.readouterr().out.strip().splitlines()
-        line = json.loads(out[-1])
-        assert line["stale_device_times"] is True
+def test_bench_refuses_non_tpu_platform(tmp_path, monkeypatch, capsys):
+    """Root bench.py measures a TPU or prints nothing: on any other
+    live platform main() returns non-zero and stdout carries no metric
+    line (no replay, no CPU continuation)."""
+    import bench
+    monkeypatch.setattr(bench, "DATA_ROOT", str(tmp_path))
+    monkeypatch.setattr(bench, "LEGS", ["nds_h"])
+    bench.BANK.clear()
+    rc = bench.main()
+    assert rc != 0
+    out = capsys.readouterr()
+    assert out.out.strip() == ""
+    assert "not 'tpu'" in out.err
+    assert os.listdir(tmp_path) == []   # nothing generated or banked
 
-    def test_no_bank_fails_too(self, tmp_path, monkeypatch, capsys):
-        import bench
-        monkeypatch.setattr(bench, "DATA_ROOT", str(tmp_path))
-        monkeypatch.setattr(bench, "LEGS", ["nds_h"])
-        monkeypatch.setattr(bench, "_probe_backend", lambda *a: "")
-        bench.BANK.clear()
-        rc = bench.main()
-        assert rc == bench.EXIT_NO_METRIC
-        out = capsys.readouterr().out.strip().splitlines()
-        assert json.loads(out[-1])["device_unreachable"] is True
+
+def test_xla_cache_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: that directory IS the cache —
+    enable() sets no dir in jax's config and appends nothing. Unset:
+    the same in-checkout path on every call, whatever XLA_FLAGS says."""
+    import jax
+
+    from nds_tpu.utils import xla_cache
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, val):
+        updates.append(name)
+        real_update(name, val)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    try:
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        assert xla_cache.enable() == outside
+        assert "jax_compilation_cache_dir" not in updates
+        assert not os.path.exists(outside)  # jax creates it, not we
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        first = xla_cache.enable()
+        monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", "")
+                           + " --xla_cpu_enable_fast_math=false")
+        assert xla_cache.enable() == first == os.path.join(
+            repo, ".xla_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        monkeypatch.undo()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        xla_cache.reset()
 
 
 # --------------------------------------------------------- NDS115 rule

@@ -608,11 +608,6 @@ def run_corrupt_load(workdir: str) -> int:
 
 
 def main() -> int:
-    # pin the reloadable-codegen flag BEFORE any scenario initializes
-    # jax: the cache-corruption scenario's warm rerun asserts zero
-    # compiles, which needs persisted CPU executables to deserialize
-    from nds_tpu import cache as plan_cache
-    plan_cache.ensure_reloadable_codegen()
     with tempfile.TemporaryDirectory(prefix="nds_chaos_") as workdir:
         rc = run_chaos_stream(workdir)
         rc |= run_journal_check(workdir)
