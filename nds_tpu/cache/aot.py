@@ -26,12 +26,26 @@ distributed executor's sharded/replicated key split).
 
 from __future__ import annotations
 
+import threading
 import time
 
 from nds_tpu.analysis import locksan
 from nds_tpu.cache import fingerprint as fpmod
+from nds_tpu.obs.trace import get_tracer
 
 _unserializable_warned: set = set()
+
+# the jax.monitoring event jax records, on the compiling thread, when
+# its persistent compilation cache serves an executable (the one
+# chip_smoke.py's and the benchmark's CompileCounter count)
+_PERSISTENT_HIT = "/jax/compilation_cache/cache_hits"
+_hit_seen = threading.local()
+_hit_listener_on = False
+
+
+def _on_jax_event(name: str, **_kw) -> None:
+    if name == _PERSISTENT_HIT:
+        _hit_seen.flag = True
 
 # Traces never interleave: tracing is where the executors fill their
 # trace-time collectors (the exchange's module-level skew sink, the
@@ -143,8 +157,13 @@ def lower_and_compile(jitted, *args, fresh: bool = False,
     from nds_tpu.analysis import jitsan
     jitsan.on_compile(kind)
     import jax
-    with _TRACE_LOCK:
-        lowered = jitted.lower(*args)
+    with get_tracer().span("compile.lower", kind=kind) as span:
+        t0 = time.perf_counter()
+        with _TRACE_LOCK:
+            # the wait is thread-seconds nobody saw while a warm-up
+            # compiled several programs at a time
+            span.set(lock_wait_ms=(time.perf_counter() - t0) * 1000)
+            lowered = jitted.lower(*args)
     if not fresh or not jax.config.jax_enable_compilation_cache:
         return _compile(lowered, kind)
     from nds_tpu.utils import xla_cache
@@ -161,12 +180,24 @@ def _compile(lowered, kind: str):
     """The compiler's refusal surfaces as CompileRefused with its own
     message (resilience/retry.py: deterministic, never an OOM)."""
     import jax
+    import jax.monitoring
     from nds_tpu.resilience.retry import CompileRefused
-    try:
-        return lowered.compile()
-    except jax.errors.JaxRuntimeError as exc:
-        raise CompileRefused(
-            f"XLA refused to compile the {kind} program: {exc}") from exc
+    global _hit_listener_on
+    if not _hit_listener_on:
+        _hit_listener_on = True
+        jax.monitoring.register_event_listener(_on_jax_event)
+    _hit_seen.flag = False
+    # the compiler on a cold run, a read of jax's persistent cache on a
+    # warm one: `persistent_cache_hit` says which
+    with get_tracer().span("compile.xla", kind=kind) as span:
+        try:
+            return lowered.compile()
+        except jax.errors.JaxRuntimeError as exc:
+            raise CompileRefused(
+                f"XLA refused to compile the {kind} program: {exc}"
+            ) from exc
+        finally:
+            span.set(persistent_cache_hit=bool(_hit_seen.flag))
 
 
 def fresh_for(cache, fp: "str | None") -> bool:
@@ -221,7 +252,9 @@ def load_cached(cache, fp: str, kind: str,
     if payload is None:
         return None
     try:
-        compiled = deserialize_compiled(payload, devices)
+        with get_tracer().span("compile.deserialize", kind=kind,
+                               bytes=len(payload.get("exec") or b"")):
+            compiled = deserialize_compiled(payload, devices)
     except Exception as exc:  # noqa: BLE001 - degrade to fresh compile
         _warn(f"deserialize failed for {fp[:12]}… "
               f"({type(exc).__name__}: {exc}); recompiling fresh")
@@ -263,6 +296,14 @@ def persist(cache, fp: str, kind: str, compiled,
     (TPU skips the check: a trial load would claim device memory.)"""
     if cache.readonly:
         return False
+    with get_tracer().span("compile.persist", kind=kind) as span:
+        stored = _persist(cache, fp, kind, compiled, extra, meta,
+                          devices)
+        span.set(stored=stored)
+    return stored
+
+
+def _persist(cache, fp, kind, compiled, extra, meta, devices) -> bool:
     ser = serialize_compiled(compiled)
     if ser is None:
         return False

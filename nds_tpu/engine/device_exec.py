@@ -397,6 +397,12 @@ class DeviceExecutor:
         # interleaving: another query's _finish must not consume
         # or clear this query's pending bill)
         self._stage_timings: dict[object, dict] = {}
+        # host->device placements and scan views built so far, counted
+        # where they are made (_to_device, scan_view): a device.bind
+        # span reports the difference across itself
+        self._uploads = 0
+        self._upload_bytes = 0
+        self._views_built = 0
 
     # ------------------------------------------------------------------ API
 
@@ -732,8 +738,7 @@ class DeviceExecutor:
             self._evict_query_state(victim)
 
     def _dispatch_traced(self, planned, orig, key, tracer, qspan):
-        import time as _time
-        with tracer.attach(qspan):
+        with tracer.attach(qspan), tracer.span("device.dispatch"):
             planned = self._staged_effective(planned, key)
             from nds_tpu.analysis import plan_verify
             if plan_verify.verify_enabled():
@@ -755,8 +760,7 @@ class DeviceExecutor:
             self._bound_compiled(key)
             if "compiled" not in entry:
                 self._compile_or_load(planned, entry, timings, tracer)
-            bufs = self._collect_buffers(planned)
-            pvals = self._collect_params(planned)
+            bufs, pvals = self._bind(planned, tracer)
             # bytes the query reads from HBM-resident scan buffers: the
             # roofline denominator (achieved GB/s lands in scan_gbps at
             # _finish) so wins/losses are judged against memory
@@ -777,22 +781,60 @@ class DeviceExecutor:
             memwatch.add_live(timings["bytes_scanned"])
             timings["__live_bytes"] = timings["bytes_scanned"]
             memwatch.sample_device()
-            # compiler-truth cost billing (obs/costs): per dispatch,
-            # before the execute bracket opens so the memoized
-            # extraction never inflates device.run
-            obs_costs.record_program(type(self).__name__,
-                                     entry["compiled"])
+            # bufs/pvals are device-resident by the bind above
+            t1, devs = self._launch(
+                tracer, type(self).__name__, entry["compiled"],
+                *((bufs, pvals) if pvals is not None else (bufs,)))
+        return _AsyncResult(self, planned, key, entry, timings, t1,
+                            devs, qspan)
+
+    def _bind(self, planned, tracer) -> tuple:
+        """``device.bind``: the (buffers, parameters) one call of the
+        plan's program takes.  Every repeat finds its scan views and
+        buffers cached; the first builds the views on the host and
+        uploads them, which the span says (``first``, ``uploads``,
+        ``upload_bytes``)."""
+        with tracer.span("device.bind") as span:
+            u0, b0, v0 = (self._uploads, self._upload_bytes,
+                          self._views_built)
+            bufs = self._collect_buffers(planned)
+            pvals = self._collect_params(planned)
+            uploads = self._uploads - u0
+            if uploads:
+                obs_metrics.counter("device_uploads_total").inc(uploads)
+                obs_metrics.counter("upload_bytes_total").inc(
+                    self._upload_bytes - b0)
+            span.set(first=bool(uploads or self._views_built - v0),
+                     uploads=uploads,
+                     upload_bytes=self._upload_bytes - b0)
+        return bufs, pvals
+
+    def _to_device(self, arr, place=jnp.asarray):
+        """One host->device placement, counted where it is made."""
+        self._uploads += 1
+        self._upload_bytes += int(getattr(arr, "nbytes", 0))
+        return place(arr)
+
+    def _launch(self, tracer, kind: str, compiled, *args) -> tuple:
+        """``device.launch``: bill the executable's compiler-truth cost
+        (obs/costs; memoized, so before the execute bracket opens and
+        never inside ``device.run``) and make the compiled call.
+        -> (perf_counter at the call, what it returned)."""
+        import time as _time
+        cost = obs_costs.record_program(kind, compiled) or {}
+        attrs = {k: cost[k] for k in ("bytes_accessed", "flops")
+                 if k in cost}
+        if "bytes_accessed" in attrs:
+            obs_metrics.counter("program_bytes_accessed_total").inc(
+                attrs["bytes_accessed"])
+        with tracer.span("device.launch", **attrs):
             # ndslint: waive[NDS102] -- execute bracket opens here; _finish_traced closes it after device_get
             t1 = _time.perf_counter()
             # jitsan dispatch scope (analysis/jitsan): armed windows
-            # count the crossing and forbid implicit h2d — bufs/pvals
-            # are device-resident by the staging above
-            with jitsan.dispatch(type(self).__name__):
-                row, outs, overflow = (entry["compiled"](bufs, pvals)
-                                       if pvals is not None
-                                       else entry["compiled"](bufs))
-        return _AsyncResult(self, planned, key, entry, timings, t1,
-                            (row, outs, overflow), qspan)
+            # count the crossing and forbid implicit h2d
+            with jitsan.dispatch(kind):
+                out = compiled(*args)
+        return t1, out
 
     def _attach_compression(self, timings: dict, bufs: dict) -> None:
         """Per-query compression accounting (nds_tpu/columnar/):
@@ -886,8 +928,7 @@ class DeviceExecutor:
         pc, fp = self._plan_fingerprint(planned, entry["slack"])
         if fp:
             with tracer.span("cache.load", fp=fp[:12]):
-                bufs = self._collect_buffers(planned)
-                pvals = self._collect_params(planned)
+                bufs, pvals = self._bind(planned, tracer)
                 hit = cache_aot.load_cached(
                     pc, fp, type(self).__name__, timings,
                     args=((bufs, pvals) if pvals is not None
@@ -905,8 +946,7 @@ class DeviceExecutor:
         t0 = _time.perf_counter()
         with tracer.span("device.compile", slack=entry["slack"]):
             jitted, side = self._compile(planned, entry["slack"])
-            bufs = self._collect_buffers(planned)
-            pvals = self._collect_params(planned)
+            bufs, pvals = self._bind(planned, tracer)
             # AOT-compile now so compile cost is attributed
             # separately from steady-state execution (fresh when the
             # blob will persist: see lower_and_compile)
@@ -1049,50 +1089,69 @@ class DeviceExecutor:
         import time as _time
         row_d, outs_d, overflow_d = devs
         n = row_d.shape[0]
-        if n >= self.COMPACT_MIN_ROWS and outs_d:
-            cf = self._compactor(row_d, outs_d, timings)
-            # first-use compactor compile must not count as execution
-            t1 += timings.pop("__compact_compile_ms", 0.0) / 1000
-            obs_costs.record_program("compact", cf)
-            with jitsan.dispatch("compact"):
-                cnt_d, row2, outs2 = cf(row_d, outs_d)
-            cnt_h, overflow_h = jax.device_get((cnt_d, overflow_d))
+        compact = n >= self.COMPACT_MIN_ROWS and bool(outs_d)
+        with tracer.attach(span):
+            if compact:
+                cf = self._compactor(row_d, outs_d, timings)
+                # first-use compactor compile must not count as execution
+                t1 += timings.pop("__compact_compile_ms", 0.0) / 1000
+                _t, (cnt_d, row2, outs2) = self._launch(
+                    tracer, "compact", cf, row_d, outs_d)
+            # every blocking device->host transfer of the statement is
+            # made, and counted, here
+            with tracer.span("device.readback") as rb:
+                if compact:
+                    cnt_h, overflow_h = jax.device_get((cnt_d, overflow_d))
+                    syncs, nbytes = 1, cnt_h.nbytes
+                    row_h = outs_h = None
+                    if int(overflow_h) == 0:
+                        C = 1
+                        while C < max(int(cnt_h), 1):
+                            C <<= 1
+                        C = min(C, n)
+                        row_h, outs_h = jax.device_get(
+                            (row2[:C], [(a[:C], v[:C]) for a, v in outs2]))
+                        syncs = 2
+                else:
+                    row_h, outs_h, overflow_h = jax.device_get(devs)
+                    syncs, nbytes = 1, 0
+                nbytes += overflow_h.nbytes
+                if row_h is not None:
+                    nbytes += row_h.nbytes + sum(
+                        a.nbytes + v.nbytes for a, v in outs_h)
+                rb.set(syncs=syncs, bytes=nbytes)
+            # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span via begin(t0=t1).end(t=t2)
+            t2 = _time.perf_counter()
+            obs_metrics.counter("device_readbacks_total").inc(syncs)
+            obs_metrics.counter("readback_bytes_total").inc(nbytes)
             if int(overflow_h) == 0:
-                C = 1
-                while C < max(int(cnt_h), 1):
-                    C <<= 1
-                C = min(C, n)
-                row_h, outs_h = jax.device_get(
-                    (row2[:C], [(a[:C], v[:C]) for a, v in outs2]))
-            else:
-                row_h = outs_h = None
-        else:
-            row_h, outs_h, overflow_h = jax.device_get(devs)
-        # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span via begin(t0=t1).end(t=t2)
-        t2 = _time.perf_counter()
+                # the execute bracket closed at t2 (device_get blocks
+                # until ready); record it as a span with the measured
+                # endpoints
+                tracer.begin("device.run", parent=span, t0=t1).end(t=t2)
+                with tracer.span("device.materialize"):
+                    out = self._materialize(planned, row_h, outs_h,
+                                            entry["side"])
+                # ndslint: waive[NDS102] -- host materialize endpoint; the device.materialize span brackets the same region
+                t3 = _time.perf_counter()
+                with tracer.span("device.finish"):
+                    # post-materialize allocator sample: results + scan
+                    # buffers are all resident here, the per-query
+                    # memory peak
+                    memwatch.sample_device()
+                    timings["execute_ms"] = (t2 - t1) * 1000
+                    timings["materialize_ms"] = (t3 - t2) * 1000
+                    side = entry.get("side") or {}
+                    if side.get("ops_est"):
+                        timings["ops_est"] = float(side["ops_est"])
+                    if side.get("kernels"):
+                        # dunder: a dict, not part of the numeric
+                        # timings vocabulary (engineTimings strips it;
+                        # report.py publishes it as the summary's
+                        # "kernels" block)
+                        timings["__kernels"] = dict(side["kernels"])
+                    self._finalize_timings(timings, key)
         if int(overflow_h) == 0:
-            # the execute bracket closed at t2 (device_get blocks until
-            # ready); record it as a span with the measured endpoints
-            tracer.begin("device.run", parent=span, t0=t1).end(t=t2)
-            with tracer.attach(span), tracer.span("device.materialize"):
-                out = self._materialize(planned, row_h, outs_h,
-                                        entry["side"])
-            # ndslint: waive[NDS102] -- host materialize endpoint; the device.materialize span brackets the same region
-            t3 = _time.perf_counter()
-            # post-materialize allocator sample: results + scan buffers
-            # are all resident here, the per-query memory peak
-            memwatch.sample_device()
-            timings["execute_ms"] = (t2 - t1) * 1000
-            timings["materialize_ms"] = (t3 - t2) * 1000
-            side = entry.get("side") or {}
-            if side.get("ops_est"):
-                timings["ops_est"] = float(side["ops_est"])
-            if side.get("kernels"):
-                # dunder: a dict, not part of the numeric timings
-                # vocabulary (engineTimings strips it; report.py
-                # publishes it as the summary's "kernels" block)
-                timings["__kernels"] = dict(side["kernels"])
-            self._finalize_timings(timings, key)
             if span:
                 # dunder keys are internal accounting state (e.g. the
                 # __live_bytes release token), not part of the
@@ -1155,7 +1214,7 @@ class DeviceExecutor:
         from nds_tpu.sql import params as sqlparams
         if not sqlparams.has_params(planned):
             return None
-        return {k: jnp.asarray(v) for k, v in
+        return {k: self._to_device(v) for k, v in
                 sqlparams.bind_params(planned, self.tables).items()}
 
     # -------------------------------------------------------------- buffers
@@ -1186,7 +1245,7 @@ class DeviceExecutor:
             return
         key = f"{table}.__live"
         if key not in self._buffers:
-            self._buffers[key] = jnp.asarray(live)
+            self._buffers[key] = self._to_device(live)
         bufs[key] = self._buffers[key]
 
     # ------------------------------------------- filtered scan reduction
@@ -1225,7 +1284,10 @@ class DeviceExecutor:
         ck = (node.table, sig)
         hit = self._scan_views.get(ck)
         if hit is not None:
+            obs_metrics.counter("scan_view_hits_total").inc()
             return hit if isinstance(hit, _ReducedScan) else None
+        obs_metrics.counter("scan_view_misses_total").inc()
+        self._views_built += 1
         keep = self._host_keep_mask(node, t)
         s = 0 if keep is None else int(keep.sum())
         if keep is None or s > t.nrows * self.REDUCE_MAX_FRAC:
@@ -1329,16 +1391,17 @@ class DeviceExecutor:
             if spec is not None:
                 for sfx, arr in columnar.encode_values(
                         spec, vals, nulls, nrows=rv.nrows).items():
-                    self._buffers[key + sfx] = self._reduced_to_device(
-                        arr)
+                    self._buffers[key + sfx] = self._to_device(
+                        arr, self._reduced_to_device)
                 self._enc_specs[key] = spec
                 self._raw_nbytes[key] = float(
                     columnar.raw_nbytes(vals, nulls))
             else:
-                self._buffers[key] = self._reduced_to_device(vals)
+                self._buffers[key] = self._to_device(
+                    vals, self._reduced_to_device)
                 if nulls is not None:
-                    self._buffers[key + "#v"] = self._reduced_to_device(
-                        nulls)
+                    self._buffers[key + "#v"] = self._to_device(
+                        nulls, self._reduced_to_device)
         for sfx in ("", "#v", "#x"):
             if key + sfx in self._buffers:
                 bufs[key + sfx] = self._buffers[key + sfx]
@@ -1366,11 +1429,11 @@ class DeviceExecutor:
             if spec is not None:
                 for sfx, arr in columnar.encode_column(
                         spec, col).items():
-                    pool[key + sfx] = jnp.asarray(arr)
+                    pool[key + sfx] = self._to_device(arr)
             else:
-                pool[key] = jnp.asarray(col.values)
+                pool[key] = self._to_device(col.values)
                 if col.null_mask is not None:
-                    pool[key + "#v"] = jnp.asarray(col.null_mask)
+                    pool[key + "#v"] = self._to_device(col.null_mask)
         if spec is not None:
             self._enc_specs[key] = spec
             self._raw_nbytes[key] = float(

@@ -64,6 +64,7 @@ import os
 
 from nds_tpu.obs import memwatch
 from nds_tpu.obs import metrics as obs_metrics
+from nds_tpu.obs.trace import get_tracer
 from nds_tpu.resilience import faults, watchdog
 from nds_tpu.resilience.retry import (
     DETERMINISTIC, QueryDeadlineExceeded, RetryPolicy, RetryStats,
@@ -387,7 +388,8 @@ class _PipelineHandle:
         self.stats.attempts += 1
         pipe._adopt_executor_state(self.placement)
         self.sched["placement"] = self.placement
-        pipe._note_success(rescheduled=False)
+        with get_tracer().span("sched.note"):
+            pipe._note_success(rescheduled=False)
         return out
 
 
@@ -750,13 +752,21 @@ class ExecutionPipeline:
                                       catalog=catalog, qname=qname,
                                       est=est)
 
+    def _place(self, planned) -> tuple:
+        """(placement, stats, schedule) for one query: the cost model's
+        initial placement, the memory governor and the prefetch depth
+        admission, under the ``sched.place`` span."""
+        with get_tracer().span("sched.place"):
+            placement, why = self._initial_placement(
+                planned, self._current_query())
+            stats, sched = self._new_schedule(placement, why)
+            self._apply_governor(sched, placement)
+            self._apply_prefetch(sched, placement)
+            self.last_stats, self.last_schedule = stats, sched
+        return placement, stats, sched
+
     def execute(self, planned, key: object = None):
-        qname = self._current_query()
-        placement, why = self._initial_placement(planned, qname)
-        stats, sched = self._new_schedule(placement, why)
-        self._apply_governor(sched, placement)
-        self._apply_prefetch(sched, placement)
-        self.last_stats, self.last_schedule = stats, sched
+        placement, stats, sched = self._place(planned)
         return self._run_ladder(planned, key=key, placement=placement,
                                 stats=stats, sched=sched)
 
@@ -768,11 +778,7 @@ class ExecutionPipeline:
         handle carries its own stats/schedule, so interleaved dispatch
         (engine.concurrent_tasks) keeps per-query accounting intact."""
         qname = self._current_query()
-        placement, why = self._initial_placement(planned, qname)
-        stats, sched = self._new_schedule(placement, why)
-        self._apply_governor(sched, placement)
-        self._apply_prefetch(sched, placement)
-        self.last_stats, self.last_schedule = stats, sched
+        placement, stats, sched = self._place(planned)
         ex = self._executor(placement)
         dispatch = getattr(ex, "execute_async", None)
         # multi-rank worlds run synchronously: the per-query boundary
@@ -791,9 +797,12 @@ class ExecutionPipeline:
                                    stats=stats, sched=sched)
             return _CompletedHandle(out, self, stats, sched)
         try:
-            self._predispatch(placement, qname, stats)
-            inner = (dispatch(planned, key) if key is not None
-                     else dispatch(planned))
+            # the dispatch half of the walk's first rung; the blocking
+            # half hangs from the caller's root at result()
+            with get_tracer().span("sched.run", placement=placement):
+                self._predispatch(placement, qname, stats)
+                inner = (dispatch(planned, key) if key is not None
+                         else dispatch(planned))
         except Exception as exc:  # noqa: BLE001 - classified in rerun
             stats.attempts += 1
             stats.errors.append(f"{type(exc).__name__}: {exc}")
@@ -866,10 +875,23 @@ class ExecutionPipeline:
                 obs_metrics.counter(
                     "query_deadline_exceeded_total").inc()
 
+        tracer = get_tracer()
         try:
-            return self._walk(planned, key, rungs, stats, sched,
-                              pending, qname, unit, deadline_s, start,
-                              overrun, flag_deadline)
+            with tracer.span("sched.run", placement=placement):
+                out = self._walk(planned, key, rungs, stats, sched,
+                                 pending, qname, unit, deadline_s, start,
+                                 overrun, flag_deadline)
+            with tracer.span("sched.note"):
+                self._note_success(rescheduled=sched["reschedules"] > 0,
+                                   qname=qname)
+            return out
+        except BaseException:
+            if sched.pop("_gave_up", False):
+                # the walk exhausted what it could try (not a
+                # deterministic failure): counts toward the demotion
+                with tracer.span("sched.note", failed=True):
+                    self._note_failure()
+            raise
         finally:
             # per-query executor tweaks (the ladder's chunk halving /
             # stream-threshold lowering / prefetch-depth admission)
@@ -930,9 +952,6 @@ class ExecutionPipeline:
                         self._adopt_executor_state(rung)
                         sched["placement"] = rung
                         sched["_succeeded"] = True
-                        self._note_success(
-                            rescheduled=sched["reschedules"] > 0,
-                            qname=qname)
                         return out
                 # ---- failure handling at this rung
                 if classify(exc) != "transient":
@@ -954,7 +973,7 @@ class ExecutionPipeline:
                         # no agreement: keep placement, fail the query
                         # rather than diverge from the other ranks
                         stats.gave_up_reason = "consensus"
-                        self._note_failure()
+                        sched["_gave_up"] = True
                         raise exc
                     if agreed == i and replan:
                         self._apply_replan(sched)
@@ -980,14 +999,14 @@ class ExecutionPipeline:
                         f"attempts_exhausted({stats.attempts})")
                     if overrun():
                         flag_deadline()
-                    self._note_failure()
+                    sched["_gave_up"] = True
                     raise exc
                 d = self.policy.delay_for(stats.retries)
                 if (deadline_s is not None
                         and self._clock() - start + d > deadline_s):
                     stats.gave_up_reason = "deadline"
                     flag_deadline()
-                    self._note_failure()
+                    sched["_gave_up"] = True
                     raise exc
                 stats.retries += 1
                 stats.backoff_s += d

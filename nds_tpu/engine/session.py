@@ -8,8 +8,12 @@ engine) the way templates choose cpu/gpu in the reference.
 
 from __future__ import annotations
 
+import itertools
+
 from nds_tpu.engine.cpu_exec import CpuExecutor, ResultTable
 from nds_tpu.io.host_table import HostTable
+from nds_tpu.obs import metrics as obs_metrics
+from nds_tpu.obs.trace import get_tracer
 from nds_tpu.sql import plan as P
 from nds_tpu.sql.parser import parse
 from nds_tpu.sql.planner import CatalogInfo, Planner
@@ -20,6 +24,11 @@ TPCH_SIZES = {
     "part": 200_000, "customer": 150_000, "supplier": 10_000,
     "nation": 25, "region": 5,
 }
+
+
+# process-wide statement sequence: the ``stmt_id`` of every ``stmt``
+# root span, so a profile's statements can be told apart and counted
+_STMT_IDS = itertools.count(1)
 
 
 class Session:
@@ -89,7 +98,6 @@ class Session:
         self.tables[table.name] = table
 
     def plan(self, sql_text: str):
-        from nds_tpu.obs.trace import get_tracer
         from nds_tpu.resilience import faults
         # chaos site: deterministic plan-time faults must fail fast
         # (the retry classifier never retries this class)
@@ -161,13 +169,18 @@ class Session:
             self.tables[name] = dml.apply_delete(table, keep)
         self.invalidate(tables=[name])
 
-    def _planned_for(self, key: tuple, sql_text: str):
+    def _planned_for(self, key: tuple, sql_text: str, root=None):
         """Plan-cache lookup that keeps the 'plan' chaos site firing
         exactly once per query submission: a cache MISS fires inside
         plan(); a HIT fires here (warmup passes populate the cache —
         a scheduled plan fault must still reach the timed pass)."""
         planned = self._plan_cache.get(key)
-        if planned is None:
+        hit = planned is not None
+        obs_metrics.counter("plan_cache_hits_total" if hit
+                            else "plan_cache_misses_total").inc()
+        if root is not None:      # the statement's own ``stmt`` root
+            root.set(plan_cache_hit=hit)
+        if not hit:
             planned = self.plan(sql_text)
             self._plan_cache[key] = planned
             while len(self._plan_cache) > self.PLAN_CACHE_MAX:
@@ -182,8 +195,19 @@ class Session:
         return planned
 
     def sql(self, sql_text: str) -> ResultTable | None:
+        """One statement, call to host rows, under ONE root span: the
+        caller's where it has one open on this thread (the power
+        loop's ``query``, a statement nested in DML), else a ``stmt``
+        root of its own that every phase below hangs from."""
+        tracer = get_tracer()
+        if tracer.current() is not None:
+            return self._sql(sql_text)
+        with tracer.span("stmt", stmt_id=next(_STMT_IDS)) as root:
+            return self._sql(sql_text, root or None)
+
+    def _sql(self, sql_text: str, root=None) -> ResultTable | None:
         key = (sql_text, self._views_signature())
-        planned = self._planned_for(key, sql_text)
+        planned = self._planned_for(key, sql_text, root)
         return self._run_planned(key, sql_text, planned)
 
     def _run_planned(self, key: tuple, sql_text: str, planned):
@@ -215,8 +239,23 @@ class Session:
         device engine) overlap with the caller's other work
         (`engine.concurrent_tasks` pipelining); everything else runs
         synchronously and returns an already-completed handle."""
+        tracer = get_tracer()
+        if tracer.current() is not None or not tracer.enabled:
+            return self._sql_async(sql_text)
+        # no caller's root: the statement's own ``stmt`` root is owned
+        # (it outlives this call, so it cannot be a ``with`` span nor a
+        # profiler annotation) and ends when the handle resolves
+        root = tracer.begin("stmt", parent=None, stmt_id=next(_STMT_IDS))
+        try:
+            with tracer.attach(root):
+                return _Rooted(self._sql_async(sql_text, root), root)
+        except BaseException as exc:
+            root.set(error=f"{type(exc).__name__}: {exc}").end()
+            raise
+
+    def _sql_async(self, sql_text: str, root=None):
         key = (sql_text, self._views_signature())
-        planned = self._planned_for(key, sql_text)
+        planned = self._planned_for(key, sql_text, root)
         if not isinstance(planned, tuple):
             executor = self._executor_factory(self.tables)
             dispatch = getattr(executor, "execute_async", None)
@@ -233,3 +272,27 @@ class _Completed:
 
     def result(self):
         return self._value
+
+
+class _Rooted:
+    """An async handle under the ``stmt`` root ``sql_async`` opened for
+    it: ``result()`` runs the blocking half under that root and ends
+    it.  Everything else is the inner handle's."""
+
+    def __init__(self, inner, root):
+        self._inner = inner
+        self._root = root
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def result(self):
+        root = self._root
+        try:
+            with get_tracer().attach(root):
+                return self._inner.result()
+        except BaseException as exc:
+            root.set(error=f"{type(exc).__name__}: {exc}")
+            raise
+        finally:
+            root.end()

@@ -21,6 +21,7 @@ from nds_tpu.engine.types import (
     DateType, DecimalType, FloatType, IntType, Schema, StringType,
 )
 from nds_tpu.io.host_table import HostColumn, HostTable, encode_strings
+from nds_tpu.obs.trace import get_tracer
 
 _EPOCH = np.datetime64("1970-01-01", "D")
 
@@ -57,32 +58,45 @@ def read_tbl(paths: list[str] | str, name: str, schema: Schema,
         types["_trailing"] = pa.string()
     from nds_tpu.resilience import watchdog
     tables = []
-    for p in paths:
-        # per-chunk heartbeat: multi-chunk fact reads on a loaded box
-        # must not look like a hang to the watchdog
-        watchdog.beat("engine", phase="io.read", table=name)
-        if os.path.getsize(p) == 0:
-            continue  # zero-row chunks are legitimate (fixed tables)
-        t = pacsv.read_csv(
-            p,
-            read_options=pacsv.ReadOptions(column_names=names),
-            parse_options=pacsv.ParseOptions(delimiter="|"),
-            convert_options=pacsv.ConvertOptions(column_types=types),
-        )
-        if trailing_delimiter:
-            t = t.drop(["_trailing"])
-        tables.append(t)
-    if not tables:
-        empty = pa.table(
-            {f.name: pa.array([], type=_arrow_read_type(f.dtype)) for f in schema})
-        return from_arrow(name, schema, empty)
-    return from_arrow(name, schema, pa.concat_tables(tables))
+    with _reading(name, paths):
+        for p in paths:
+            # per-chunk heartbeat: multi-chunk fact reads on a loaded
+            # box must not look like a hang to the watchdog
+            watchdog.beat("engine", phase="io.read", table=name)
+            if os.path.getsize(p) == 0:
+                continue  # zero-row chunks are legitimate (fixed tables)
+            t = pacsv.read_csv(
+                p,
+                read_options=pacsv.ReadOptions(column_names=names),
+                parse_options=pacsv.ParseOptions(delimiter="|"),
+                convert_options=pacsv.ConvertOptions(column_types=types),
+            )
+            if trailing_delimiter:
+                t = t.drop(["_trailing"])
+            tables.append(t)
+        whole = pa.concat_tables(tables) if tables else pa.table(
+            {f.name: pa.array([], type=_arrow_read_type(f.dtype))
+             for f in schema})
+    return from_arrow(name, schema, whole)
+
+
+def _reading(name: str, paths: list):
+    """``load.read``: files to one Arrow table.  Every reader below
+    ends in ``from_arrow``, which is ``load.build``."""
+    return get_tracer().span("load.read", table=name, files=len(paths))
 
 
 def from_arrow(name: str, schema: Schema, t: pa.Table) -> HostTable:
     """Arrow table -> HostTable, carrying arrow validity bitmaps over as
     engine null masks (True = valid). Null slots are filled with 0/"" in
     the value arrays so downstream numpy code never sees NaN."""
+    # load.build: decimals to scaled int64, string dictionaries, dates,
+    # null masks
+    with get_tracer().span("load.build", table=name, rows=t.num_rows):
+        return _from_arrow(name, schema, t)
+
+
+def _from_arrow(name: str, schema: Schema, t: pa.Table) -> HostTable:
     cols: dict[str, HostColumn] = {}
     for f in schema:
         arr = t.column(f.name).combine_chunks()
@@ -181,8 +195,10 @@ def read_parquet(paths: list[str] | str, name: str, schema: Schema) -> HostTable
     # int32 column and the schema merge fails (ArrowTypeError). Reading
     # the file directly skips path inference entirely — partition
     # columns come from the file bytes, which the writer guarantees.
-    tables = [pq.ParquetFile(p).read() for p in paths]
-    return from_arrow(name, schema, pa.concat_tables(tables, promote_options="permissive"))
+    with _reading(name, paths):
+        whole = pa.concat_tables([pq.ParquetFile(p).read() for p in paths],
+                                 promote_options="permissive")
+    return from_arrow(name, schema, whole)
 
 
 # warehouse output formats beyond parquet (the reference's transcode
@@ -305,10 +321,10 @@ def read_table_fmt(paths: list[str] | str, name: str, schema: Schema,
         paths = [paths]
     if fmt == "orc":
         import pyarrow.orc as paorc
-        tables = [paorc.read_table(p) for p in paths]
-        return from_arrow(name, schema,
-                          pa.concat_tables(tables,
-                                           promote_options="permissive"))
+        with _reading(name, paths):
+            whole = pa.concat_tables([paorc.read_table(p) for p in paths],
+                                     promote_options="permissive")
+        return from_arrow(name, schema, whole)
     if fmt == "json":
         import pyarrow.json as pajson
         # dates and decimals are ISO/decimal STRINGS in the json lines
@@ -323,21 +339,22 @@ def read_table_fmt(paths: list[str] | str, name: str, schema: Schema,
                 read_types[f.name] = t
         want = pa.schema(read_types)
         tables = []
-        for p in paths:
-            t = pajson.read_json(
-                p, parse_options=pajson.ParseOptions(
-                    explicit_schema=want))
-            cols = []
-            for i, fld in enumerate(t.schema):
-                c = t.column(i)
-                if fld.name in casts:
-                    c = c.cast(casts[fld.name])
-                cols.append(c)
-            tables.append(pa.Table.from_arrays(
-                cols, names=t.column_names))
-        return from_arrow(name, schema,
-                          pa.concat_tables(tables,
-                                           promote_options="permissive"))
+        with _reading(name, paths):
+            for p in paths:
+                t = pajson.read_json(
+                    p, parse_options=pajson.ParseOptions(
+                        explicit_schema=want))
+                cols = []
+                for i, fld in enumerate(t.schema):
+                    c = t.column(i)
+                    if fld.name in casts:
+                        c = c.cast(casts[fld.name])
+                    cols.append(c)
+                tables.append(pa.Table.from_arrays(
+                    cols, names=t.column_names))
+            whole = pa.concat_tables(tables,
+                                     promote_options="permissive")
+        return from_arrow(name, schema, whole)
     raise ValueError(f"unknown input format {fmt!r}")
 
 

@@ -240,11 +240,15 @@ def record(kind: str, cost: "dict | None") -> None:
     LEDGER.record(kind, cost)
 
 
-def record_program(kind: str, compiled) -> None:
-    """The executor dispatch hook: extract (memoized) + bill."""
+def record_program(kind: str, compiled) -> "dict | None":
+    """The executor dispatch hook: extract (memoized) + bill.  Returns
+    the cost dict it billed (the ``device.launch`` span's attributes),
+    None with the ledger off or no analysis on this backend."""
     if not _ENABLED:
-        return
-    LEDGER.record(kind, extract(compiled))
+        return None
+    cost = extract(compiled)
+    LEDGER.record(kind, cost)
+    return cost
 
 
 def query_block() -> "dict | None":

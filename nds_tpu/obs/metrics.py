@@ -16,6 +16,15 @@ Metric names in use across the stack (documented in README
 - ``device_executions_total`` / ``compiles_total`` /
   ``recompiles_total`` / ``slack_retries_total`` /
   ``bytes_scanned_total`` — device executors
+- ``device_readbacks_total`` / ``readback_bytes_total`` /
+  ``device_uploads_total`` / ``upload_bytes_total`` /
+  ``scan_view_hits_total`` / ``scan_view_misses_total`` /
+  ``program_bytes_accessed_total`` — the base device executor's
+  host<->device crossings, counted where it makes them (the same
+  numbers ride the ``device.readback`` / ``device.bind`` /
+  ``device.launch`` spans as attributes)
+- ``plan_cache_hits_total`` / ``plan_cache_misses_total`` — the
+  Session's text -> plan cache
 - ``staged_subprograms_total`` — host-staged plan splitting
 - ``exchanges_traced_total`` / ``exchange_overflow_retries_total`` /
   ``exchange_overflow_rows_total`` — distributed exchange

@@ -23,8 +23,25 @@ Design constraints, in order:
   (no parent) ends, its whole tree appends to the Chrome trace-event
   JSONL named by ``NDS_TPU_TRACE`` (one JSON object per line, "X"
   complete events — Perfetto-loadable after wrapping in ``[...]``, see
-  README "Observability"), and the root is retained on
-  ``Tracer.last_roots`` for the BenchReport JSON.
+  README "Observability").  Whoever needs the tree afterwards (the
+  power loop, for the BenchReport JSON) keeps the root it was handed;
+  the tracer keeps no trees, only ``totals()``: count, total and self
+  seconds per span name.
+- **A tree only where it is read.** ``Span``s link into a tree under
+  a root that is *kept*: the Chrome export is on, a profile is live,
+  the root's owner asked (``begin(keep=True)``), or the tracer was made
+  by hand.  Under any other root the ``with`` spans are ``_TimedSpan``s:
+  timed into ``totals()`` under the same names and gone.  The statement
+  path opens ten spans a statement; in a run nobody traces they cost a
+  third this way.
+- **One mechanism, two sinks.** A context-managed span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``nds.<span name>`` while a
+  profile is live (whoever started it), with its numeric attributes
+  as the annotation's metadata: the program's spans then sit in the
+  ``.xplane.pb`` on the calling thread's host line, on the clock of
+  the PJRT launch events and beside the device's planes.  ``jax`` is
+  never imported from here; owned spans (``begin``/``end``, explicit
+  timestamps) cannot be annotations and stay Chrome-only.
 
 The span catalogue and the event schema are documented in the README
 and enforced by ``tools/check_trace_schema.py``.
@@ -35,9 +52,9 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
-from collections import deque
 
 from nds_tpu.analysis import locksan
 
@@ -50,6 +67,52 @@ _OBS_ENV = "NDS_TPU_OBS"
 _EPOCH_OFFSET = time.time() - time.perf_counter()
 
 _EXPORT_LOCK = locksan.lock("obs.trace._EXPORT_LOCK")
+_TOTALS_LOCK = locksan.lock("obs.trace._TOTALS_LOCK")
+
+# profiler-annotation name prefix: what benchmarks/span_reduce.py and
+# any other .xplane.pb reader selects the program's spans by
+ANNOTATION_PREFIX = "nds."
+# suffix of the second totals() row a span with a truthy ``first``
+# attribute is summed under (``device.bind:first``: the binds that
+# built a scan view or uploaded, i.e. set-up work, apart from the
+# window's cached binds of the same name)
+FIRST_SUFFIX = ":first"
+
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` once jax is imported, else
+    None.  Never INITIATES the import (memwatch's rule: a host-only
+    phase must not pull jax in, and a thread-side first import races
+    the main thread's)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None or getattr(getattr(mod, "__spec__", None),
+                                  "_initializing", False):
+            return None
+        _ANNOTATION = getattr(mod, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+def _bump(totals: dict, name: str, dur: float, own: float) -> None:
+    """One more span of ``name`` in a totals table; the caller holds
+    ``_TOTALS_LOCK``."""
+    row = totals.get(name)
+    if row is None:
+        row = totals[name] = [0, 0.0, 0.0]
+    row[0] += 1
+    row[1] += dur
+    row[2] += own
+
+
+def _numeric(attrs: dict) -> dict:
+    """The attributes a profiler annotation can carry: numbers (bools
+    as 0/1); strings and dicts stay Chrome-only."""
+    return {k: (int(v) if v is True or v is False else v)
+            for k, v in attrs.items()
+            if isinstance(v, (int, float))}
 
 # deterministic export identity (obs/fleet.py): multi-process fleets
 # export with pid=rank and supervised throughput streams with
@@ -110,18 +173,29 @@ class Span:
     thread turn)."""
 
     __slots__ = ("name", "attrs", "parent", "children", "t0", "t1",
-                 "tid", "_tracer")
+                 "tid", "kept", "kids_s", "_tracer", "_ann",
+                 "_totalled", "_under")
 
     def __init__(self, tracer: "Tracer", name: str, parent: "Span | None",
-                 attrs: dict, t0: float | None = None):
+                 attrs: dict, t0: float | None = None, kept: bool = True):
         self.name = name
         self.attrs = attrs
         self.parent = parent
         self.children: list[Span] = []
         self.t0 = time.perf_counter() if t0 is None else t0
         self.t1: float | None = None
+        # kept: somebody will read this tree (a profile is live, the
+        # Chrome export is on, or the root's owner keeps it), so the
+        # ``with`` spans under it are Spans too and link in.  Under a
+        # span that is not kept they are _TimedSpans: timed into the
+        # totals (their seconds add up in ``kids_s`` here) and gone.
+        self.kept = kept
+        self.kids_s = 0.0
+        self._under = None
         self.tid = threading.get_ident()
         self._tracer = tracer
+        self._ann = None
+        self._totalled = False
         if parent is not None:
             parent.children.append(self)
 
@@ -130,6 +204,8 @@ class Span:
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_numeric(attrs))
         return self
 
     def end(self, t: float | None = None) -> "Span":
@@ -138,9 +214,34 @@ class Span:
         caller's own perf_counter reads."""
         if self.t1 is None:
             self.t1 = time.perf_counter() if t is None else t
+            # totals are taken a tree at a time, when its root ends (one
+            # pass, one lock); a span that outlives that brings its own
+            # subtree in now
             if self.parent is None:
+                if self._under is not None:
+                    self._under.kids_s += self.t1 - self.t0
                 self._tracer._finish_root(self)
+            elif self.parent._totalled:
+                self._tracer._add_totals(self)
         return self
+
+    def self_s(self) -> float:
+        """Seconds of this (ended) span that no child covers: children
+        may overlap (``device.run`` is given the launch-to-read-back
+        bracket after the fact), so their union is what is taken off.
+        A span that is not kept takes off what its ``with`` spans
+        summed up instead: the brackets handed to it after the fact lie
+        over them."""
+        lo, hi = self.t0, self.t1
+        if not self.kept:
+            return (hi - lo) - self.kids_s
+        covered, upto = 0.0, lo
+        for c in sorted(self.children, key=lambda c: c.t0):
+            e = hi if c.t1 is None or c.t1 > hi else c.t1
+            if e > upto:
+                covered += e - max(c.t0, upto)
+                upto = e
+        return (hi - lo) - covered
 
     @property
     def dur_ms(self) -> float:
@@ -190,9 +291,19 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        ann = _ANNOTATION or _annotation_cls()
+        if ann is not None and ann.is_enabled():
+            # a profile is live: the span is also an event of the
+            # .xplane.pb, on this thread's host line
+            self._ann = ann(ANNOTATION_PREFIX + self.name,
+                            **_numeric(self.attrs))
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         self._tracer._pop(self)
         if exc is not None:
             self.attrs.setdefault("error", f"{type(exc).__name__}: {exc}")
@@ -222,6 +333,58 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+
+
+class _TimedSpan:
+    """A ``with`` span under a root nobody will read as a tree: no
+    profile is live, the Chrome export is off and no owner keeps the
+    root (`Span.kept`).  It is timed into ``Tracer.totals()``, count,
+    seconds and self seconds like any other, and leaves nothing else
+    behind: no attributes, no links, no annotation.  This is the
+    statement path of a run nobody traces, ten spans a statement, and
+    the reason it costs a third of a `Span` there (ISSUE 25)."""
+
+    __slots__ = ("name", "t0", "kids_s", "first", "_tracer", "_st")
+    kept = False
+
+    def __init__(self, tracer: "Tracer", name: str, stack: list):
+        self.name = name
+        self.kids_s = 0.0
+        self.first = False
+        self._tracer = tracer
+        self._st = stack
+
+    def __bool__(self) -> bool:
+        return True
+
+    def set(self, **attrs) -> "_TimedSpan":
+        if attrs.get("first"):           # `<name>:first` in the totals
+            self.first = True
+        return self
+
+    def end(self, t=None) -> "_TimedSpan":
+        return self
+
+    def __enter__(self) -> "_TimedSpan":
+        self._st.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur = time.perf_counter() - self.t0
+        st = self._st
+        if st and st[-1] is self:
+            st.pop()
+        else:
+            self._tracer._pop(self)
+        if st:
+            st[-1].kids_s += dur
+        totals = self._tracer._totals
+        with _TOTALS_LOCK:
+            _bump(totals, self.name, dur, dur - self.kids_s)
+            if self.first:
+                _bump(totals, self.name + FIRST_SUFFIX, dur,
+                      dur - self.kids_s)
 
 
 def _json_safe(obj):
@@ -257,19 +420,24 @@ class _Attach:
 
 
 class Tracer:
-    """Owns the thread-local span stack, finished-root retention, and
+    """Owns the thread-local span stack, the totals by span name, and
     the Chrome-trace export."""
 
-    MAX_ROOTS = 64
-
-    def __init__(self, enabled: bool | None = None):
+    def __init__(self, enabled: bool | None = None,
+                 keep_trees: "bool | None" = True):
         if enabled is None:
             enabled = os.environ.get(_OBS_ENV, "1") != "0"
         self.enabled = enabled
+        # whether the spans under a new root are kept as a tree: a
+        # tracer made by hand keeps them (its maker holds the roots);
+        # the process's own (None) only where something reads them,
+        # see _tree_wanted
+        self.keep_trees = keep_trees
         self._tls = threading.local()
-        # finished root spans, oldest first (bounded: a 99-query power
-        # run must not retain every tree forever)
-        self.last_roots: deque = deque(maxlen=self.MAX_ROOTS)
+        # span name -> [count, total seconds, self seconds] over every
+        # span ended so far, under _TOTALS_LOCK (warm-ups run sessions
+        # in threads): what outlives the trees, which nobody keeps
+        self._totals: dict = {}
         # defer_exports=True parks finished roots on _pending instead
         # of writing them inline: the power loop's root spans end
         # INSIDE the timed bracket, and even a ~ms export skews the
@@ -292,14 +460,18 @@ class Tracer:
     def _push(self, span: Span) -> None:
         self._stack().append(span)
 
-    def _pop(self, span: Span) -> None:
+    def _pop(self, span) -> None:
         st = self._stack()
-        if span in st:
+        if st and st[-1] is span:
+            st.pop()
+        elif span in st:
             # tolerate mismatched exits: drop through to the span
             while st and st.pop() is not span:
                 pass
 
-    def current(self) -> "Span | None":
+    def current(self):
+        """The innermost open span of this thread (a ``Span``, or the
+        ``_TimedSpan`` standing in for one that nobody will read)."""
         st = self._stack()
         return st[-1] if st else None
 
@@ -310,23 +482,52 @@ class Tracer:
         span."""
         if not self.enabled:
             return NOOP_SPAN
-        s = Span(self, name, self.current(), attrs)
-        if s.parent is None:
-            self._open_roots.add(s)
+        st = getattr(self._tls, "stack", None)
+        if st:
+            if st[-1].kept:
+                return Span(self, name, st[-1], attrs)
+            return _TimedSpan(self, name, st)
+        if not self._tree_wanted():
+            return _TimedSpan(self, name, self._stack())
+        s = Span(self, name, None, attrs)
+        self._open_roots.add(s)
         return s
 
+    def _tree_wanted(self) -> bool:
+        """Whether a new root's tree has a reader: the Chrome export is
+        on, or a profile is live and wants the annotations."""
+        if self.keep_trees is not None:
+            return self.keep_trees
+        if os.environ.get(TRACE_ENV):
+            return True
+        ann = _ANNOTATION or _annotation_cls()
+        return ann is not None and ann.is_enabled()
+
     def begin(self, name: str, parent: "Span | None | object" = _CURRENT,
-              t0: float | None = None, **attrs):
+              t0: float | None = None, keep: "bool | None" = None,
+              **attrs):
         """Explicitly-owned span (caller must ``end()`` it). ``parent``
         defaults to the thread's current span; pass ``None`` to force a
-        root."""
+        root.  ``keep=True`` on a root says its owner will read the
+        tree (the power loop, for the BenchReport ``spans`` field): the
+        spans under it are kept whatever else is on."""
         if not self.enabled:
             return NOOP_SPAN
         if parent is _CURRENT:
             parent = self.current()
         elif isinstance(parent, _NoopSpan):
             parent = None
-        s = Span(self, name, parent, attrs, t0=t0)
+        under = None
+        if parent is None:
+            kept = self._tree_wanted() if keep is None else keep
+        elif type(parent) is _TimedSpan:
+            # nobody reads the tree this would hang in: a root of its
+            # own, whose seconds the span it runs under still takes off
+            under, parent, kept = parent, None, False
+        else:
+            kept = parent.kept
+        s = Span(self, name, parent, attrs, t0=t0, kept=kept)
+        s._under = under
         if s.parent is None:
             self._open_roots.add(s)
         return s
@@ -336,11 +537,40 @@ class Tracer:
         exit). Accepts the no-op span and does nothing."""
         return _Attach(self, span if isinstance(span, Span) else None)
 
+    # ------------------------------------------------------------ totals
+
+    def _add_totals(self, top: Span) -> None:
+        """Take the ended spans of ``top``'s tree into the totals.  A
+        span still open is left out with everything under it: it comes
+        in when it ends (``Span.end``)."""
+        todo = [top]
+        with _TOTALS_LOCK:
+            while todo:
+                span = todo.pop()
+                if span.t1 is None or span._totalled:
+                    continue
+                span._totalled = True
+                todo += span.children
+                dur, own = span.t1 - span.t0, span.self_s()
+                _bump(self._totals, span.name, dur, own)
+                if span.attrs.get("first"):
+                    _bump(self._totals, span.name + FIRST_SUFFIX, dur, own)
+
+    def totals(self) -> dict:
+        """``{span name: {"count", "total_s", "self_s"}}`` over every
+        ended span of every tree whose root has ended (self = the span
+        minus what its children cover).  Spans ended with a truthy
+        ``first`` attribute are summed a second time under
+        ``<name>:first``."""
+        with _TOTALS_LOCK:
+            return {name: {"count": n, "total_s": total, "self_s": own}
+                    for name, (n, total, own) in self._totals.items()}
+
     # ------------------------------------------------------------ export
 
     def _finish_root(self, root: Span) -> None:
         self._open_roots.discard(root)
-        self.last_roots.append(root)
+        self._add_totals(root)
         path = os.environ.get(TRACE_ENV)
         if not path:
             return
@@ -462,7 +692,7 @@ def timings_from_span(root) -> dict:
     return out
 
 
-_TRACER = Tracer()
+_TRACER = Tracer(keep_trees=None)
 
 # exit-time flush for the GLOBAL tracer only (per-instance registration
 # would pin every test-constructed tracer and its span trees forever):

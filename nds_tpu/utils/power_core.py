@@ -267,9 +267,13 @@ def make_session(suite: Suite, config: EngineConfig) -> Session:
     degradation ladder schedule each query within it."""
     backend = config.get("engine.backend", "cpu")
     kwargs = schema_kwargs_for(suite, config)
-    prepare_engine(config)
-    from nds_tpu.engine.scheduler import make_pipeline
-    return suite.session_for(make_pipeline(config, backend), **kwargs)
+    # engine.init: engine-wide activation and, in a process that has
+    # not yet asked jax for its devices, the backend's start
+    with get_tracer().span("engine.init", backend=backend):
+        prepare_engine(config)
+        from nds_tpu.engine.scheduler import make_pipeline
+        pipeline = make_pipeline(config, backend)
+    return suite.session_for(pipeline, **kwargs)
 
 
 def load_warehouse(suite: Suite, session: Session, data_dir: str,
@@ -279,13 +283,13 @@ def load_warehouse(suite: Suite, session: Session, data_dir: str,
     """Register every table from a warehouse directory; returns
     {table: seconds} setup timings (the CreateTempView analog,
     `nds/nds_power.py:95-105`)."""
-    from nds_tpu.io import csv_io
     from nds_tpu.io.snapshots import MANIFEST, SnapshotLog
     if schemas is None:
         schemas = suite.get_schemas(**suite.schema_kwargs)
     log = (SnapshotLog(data_dir)
            if os.path.exists(os.path.join(data_dir, MANIFEST)) else None)
     timings = {}
+    tracer = get_tracer()
     for name, schema in schemas.items():
         if tables is not None and name not in tables:
             continue
@@ -293,52 +297,57 @@ def load_warehouse(suite: Suite, session: Session, data_dir: str,
         # read as a hang to the watchdog (resilience/watchdog.py)
         watchdog.beat("engine", phase="load_warehouse", table=name)
         t0 = time.perf_counter()
-        tdir = os.path.join(data_dir, name)
-        if fmt in csv_io.FORMAT_EXT:
-            ext = csv_io.FORMAT_EXT[fmt]
-            if log is not None and os.path.isdir(tdir):
-                # versioned warehouse: the snapshot manifest names the
-                # live files (maintenance commits new versions, always
-                # as parquet — formats may mix, so read per-extension).
-                # Delta lineages (files under <table>/_v<N>/) replay
-                # through columnar.delta: base files load normally,
-                # then each committed version's segments/bitmask apply
-                # in order — rebuilding the same content digests and
-                # merged-stats encoding specs the writer had
-                paths = log.current([name]).get(name, [])
-                from nds_tpu.columnar import delta
-                if delta.has_delta_paths(paths):
-                    table = delta.load_versioned(name, schema, paths,
-                                                 fmt)
-                else:
-                    table = csv_io.read_paths_auto(paths, name, schema,
-                                                   fmt)
-                session.register_table(table)
-                timings[name] = time.perf_counter() - t0
-                continue
-            elif os.path.isdir(tdir):
-                # recursive: partitioned tables nest hive-style dirs
-                paths = sorted(
-                    os.path.join(root, f)
-                    for root, _dirs, files in os.walk(tdir)
-                    for f in files if f.endswith(ext))
-            else:
-                paths = [os.path.join(data_dir, f"{name}{ext}")]
-            table = csv_io.read_table_fmt(paths, name, schema, fmt)
-        elif fmt == "raw":
-            if os.path.isdir(tdir):
-                from nds_tpu.io.integrity import MANIFEST_NAME
-                paths = sorted(
-                    os.path.join(tdir, f) for f in os.listdir(tdir)
-                    if not f.startswith(".") and f != MANIFEST_NAME)
-            else:
-                paths = [os.path.join(data_dir, f"{name}{suite.raw_ext}")]
-            table = csv_io.read_tbl(paths, name, schema)
-        else:
-            raise ValueError(f"unknown input format {fmt!r}")
+        # load.table: its children load.read (files to Arrow) and
+        # load.build (Arrow to HostTable) open in io/csv_io.py
+        with tracer.span("load.table", table=name) as span:
+            table = _read_table(suite, data_dir, name, schema, fmt, log)
+            span.set(rows=table.nrows, bytes=memwatch.table_bytes(table))
         session.register_table(table)
         timings[name] = time.perf_counter() - t0
     return timings
+
+
+def _read_table(suite: Suite, data_dir: str, name: str, schema,
+                fmt: str, log):
+    """One warehouse table as a HostTable, from whichever layout
+    ``data_dir`` holds it in."""
+    from nds_tpu.io import csv_io
+    tdir = os.path.join(data_dir, name)
+    if fmt in csv_io.FORMAT_EXT:
+        ext = csv_io.FORMAT_EXT[fmt]
+        if log is not None and os.path.isdir(tdir):
+            # versioned warehouse: the snapshot manifest names the
+            # live files (maintenance commits new versions, always
+            # as parquet — formats may mix, so read per-extension).
+            # Delta lineages (files under <table>/_v<N>/) replay
+            # through columnar.delta: base files load normally,
+            # then each committed version's segments/bitmask apply
+            # in order — rebuilding the same content digests and
+            # merged-stats encoding specs the writer had
+            paths = log.current([name]).get(name, [])
+            from nds_tpu.columnar import delta
+            if delta.has_delta_paths(paths):
+                return delta.load_versioned(name, schema, paths, fmt)
+            return csv_io.read_paths_auto(paths, name, schema, fmt)
+        if os.path.isdir(tdir):
+            # recursive: partitioned tables nest hive-style dirs
+            paths = sorted(
+                os.path.join(root, f)
+                for root, _dirs, files in os.walk(tdir)
+                for f in files if f.endswith(ext))
+        else:
+            paths = [os.path.join(data_dir, f"{name}{ext}")]
+        return csv_io.read_table_fmt(paths, name, schema, fmt)
+    if fmt == "raw":
+        if os.path.isdir(tdir):
+            from nds_tpu.io.integrity import MANIFEST_NAME
+            paths = sorted(
+                os.path.join(tdir, f) for f in os.listdir(tdir)
+                if not f.startswith(".") and f != MANIFEST_NAME)
+        else:
+            paths = [os.path.join(data_dir, f"{name}{suite.raw_ext}")]
+        return csv_io.read_tbl(paths, name, schema)
+    raise ValueError(f"unknown input format {fmt!r}")
 
 
 def run_one_query(session: Session, sql: str, qname: str = "",
@@ -890,8 +899,9 @@ def _run_query_stream(suite, data_dir, stream_path, time_log_path,
             # TimeLog brackets (begin_async -> end_async), so span
             # totals and the CSV agree; forced root — under overlap
             # the next dispatch must not nest inside it
-            qspan = tracer.begin("query", parent=None, query=qname,
-                                 suite=suite.name, backend=backend)
+            qspan = tracer.begin("query", parent=None, keep=True,
+                                 query=qname, suite=suite.name,
+                                 backend=backend)
             p = {"qname": qname, "report": report, "span": qspan,
                  "out_pref": out_pref, "metrics_before": metrics_before,
                  "hwm": None, "cost": None, "telemetry": None,

@@ -47,8 +47,18 @@ class TestTracer:
         assert [s.name for s in root.walk()] == [
             "query", "sql.parse", "device.execute", "device.compile"]
         assert root.find("device.compile") == [c]
-        # root retention for BenchReport export
-        assert tr.last_roots[-1] is root
+        # the tracer keeps no tree, only the totals by name: one of
+        # each, and every self time is the span minus its children
+        totals = tr.totals()
+        assert {n: t["count"] for n, t in totals.items()} == {
+            "query": 1, "sql.parse": 1, "device.execute": 1,
+            "device.compile": 1}
+        assert totals["query"]["total_s"] == pytest.approx(
+            root.dur_ms / 1e3)
+        assert totals["query"]["self_s"] == pytest.approx(
+            (root.dur_ms - p.dur_ms - ex.dur_ms) / 1e3)
+        assert totals["device.compile"]["self_s"] == pytest.approx(
+            totals["device.compile"]["total_s"])
 
     def test_exception_closes_span_and_records_error(self):
         tr = Tracer(enabled=True)
@@ -77,7 +87,16 @@ class TestTracer:
         q.set(timings={"execute_ms": 500.0}).end()
         assert [c.name for c in q.children] == ["device.materialize",
                                                 "device.run"]
-        assert tr.last_roots[-1] is q
+        # an owned root lands in the totals when its owner ends it.
+        # device.run was handed a bracket from the root's start to
+        # half a second on: it overlaps device.materialize and outlasts
+        # the root, so the root's self time takes the children's UNION
+        # off, cut to the root: nothing is left, and never less
+        totals = tr.totals()
+        assert totals["device.execute"]["count"] == 1
+        assert totals["device.run"]["total_s"] == pytest.approx(0.5)
+        assert totals["device.execute"]["self_s"] == pytest.approx(
+            0.0, abs=1e-9)
 
     def test_disabled_mode_is_noop(self):
         tr = Tracer(enabled=False)
@@ -89,7 +108,7 @@ class TestTracer:
         assert tr.begin("device.execute") is NOOP_SPAN
         with tr.attach(s):
             assert tr.current() is None
-        assert len(tr.last_roots) == 0
+        assert tr.totals() == {}
         assert timings_from_span(s) == {}
 
     def test_threads_get_independent_stacks(self):
@@ -313,9 +332,10 @@ class TestTimingsParity:
         assert TIMING_KEYS <= set(got)
         root = ex.last_query_span
         assert root.name == "device.execute"
-        names = {c.name for c in root.children}
-        assert {"device.compile", "device.run",
-                "device.materialize"} <= names
+        # nothing reads this tree (no export, no profile, no owner), so
+        # only the executor's own spans are in it; the phases under
+        # them are in the totals (TestStatementSpans)
+        assert [c.name for c in root.children] == ["device.run"]
 
     def test_distributed_query_timings_match_last_timings(self,
                                                           tpch_raw):
@@ -335,10 +355,12 @@ class TestTimingsParity:
         assert got["execute_ms"] > 0
 
     def test_distributed_staged_bill_folds_into_timings(
-            self, tpch_raw, monkeypatch):
+            self, tpch_raw, monkeypatch, tmp_path):
         """Staged sub-programs on the multichip path must bill into
         the query's timings (the dropped-bill half of the advisor
         finding) and appear as spans."""
+        # the tree is kept where something reads it: the Chrome export
+        monkeypatch.setenv("NDS_TPU_TRACE", str(tmp_path / "t.jsonl"))
         from nds_tpu.engine import staging
         from nds_tpu.nds_h import streams
         from nds_tpu.parallel.dist_exec import (
@@ -519,8 +541,14 @@ def _check_power_artifacts(res):
         assert et["execute_ms"] > 0 and et["bytes_scanned"] > 0
         assert et.get("staged_programs", 0) >= 1
         assert s["spans"]["name"] == "query"
-        kids = [c["name"] for c in s["spans"]["children"]]
-        assert "device.execute" in kids
+        # the catalogue's phases hang from the power loop's root, no
+        # `stmt` of their own in between; the executor's span hangs
+        # from the ladder walk
+        kids = {c["name"]: c for c in s["spans"]["children"]}
+        assert {"sched.place", "sched.run", "sched.note"} <= set(kids)
+        assert "stmt" not in kids
+        assert "device.execute" in [
+            c["name"] for c in kids["sched.run"]["children"]]
         assert s["metrics"]["counters"]["queries_total"] == 1
 
 
@@ -566,3 +594,322 @@ class TestToolGates:
                               capture_output=True, text=True)
         assert fail.returncode == 1
         assert "missing key" in fail.stdout
+
+
+# ------------------------------------------- one statement, one span tree
+
+STMT_SF = 0.01
+PROFILE_STATS = ("stmt_id", "plan_cache_hit", "syncs", "bytes", "first",
+                 "uploads", "upload_bytes", "bytes_accessed")
+
+
+def _totals_since(before: dict, after: dict, key: str = "count") -> dict:
+    return {n: t[key] - before.get(n, {}).get(key, 0)
+            for n, t in after.items()
+            if t[key] != before.get(n, {}).get(key, 0)}
+
+
+def _root_of(span):
+    while span.parent is not None:
+        span = span.parent
+    return span
+
+
+@pytest.fixture(scope="module")
+def stmt_session(tmp_path_factory):
+    """NDS-H at SF0.01 as the drivers build it: a parquet warehouse
+    read by ``load_warehouse`` into a ``make_session`` session on the
+    device executor (compiled by CPU XLA here).  Yields the session,
+    the totals the load added, and the trees of q6's first (compiling)
+    and second (warm) execution, taken with the Chrome export on: a
+    tree is kept only where something reads it."""
+    from nds_tpu.datagen import tpch
+    from nds_tpu.io import csv_io
+    from nds_tpu.io.host_table import from_arrays
+    from nds_tpu.nds_h import streams
+    from nds_tpu.nds_h.power import SUITE
+    from nds_tpu.nds_h.schema import get_schemas
+    from nds_tpu.obs.trace import get_tracer
+    from nds_tpu.utils import power_core
+    from nds_tpu.utils.config import EngineConfig
+    wh = tmp_path_factory.mktemp("obs_stmt") / "wh"
+    wh.mkdir()
+    schemas = get_schemas()
+    for t, schema in schemas.items():
+        csv_io.write_parquet(
+            from_arrays(t, schema, tpch.gen_table(t, STMT_SF)),
+            str(wh / f"{t}.parquet"))
+    tracer = get_tracer()
+    before = tracer.totals()
+    sess = power_core.make_session(
+        SUITE, EngineConfig(overrides={"engine.backend": "tpu"}))
+    power_core.load_warehouse(SUITE, sess, str(wh), "parquet",
+                              schemas=schemas)
+    loaded = _totals_since(before, tracer.totals())
+    pipe = sess._executor_factory(sess.tables)
+    q6 = streams.render_query(6)
+    roots = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("NDS_TPU_TRACE", str(wh.parent / "stmt_trace.jsonl"))
+        for _ in range(2):
+            sess.sql(q6)
+            roots.append(_root_of(pipe.last_query_span))
+    return {"session": sess, "pipe": pipe, "q6": q6, "loaded": loaded,
+            "first": roots[0], "warm": roots[1],
+            "tables": len(schemas)}
+
+
+def _names(spans) -> list:
+    return [s.name for s in spans]
+
+
+class TestStatementSpans:
+    def test_one_stmt_root_with_the_catalogue_in_order(self, stmt_session):
+        first, warm = stmt_session["first"], stmt_session["warm"]
+        for root in (first, warm):
+            assert root.name == "stmt" and root.parent is None
+            assert root.t1 is not None
+            assert len(root.find("stmt")) == 1
+        # one id a statement, from one process-wide sequence
+        assert warm.attrs["stmt_id"] == first.attrs["stmt_id"] + 1
+        assert first.attrs["plan_cache_hit"] is False
+        assert warm.attrs["plan_cache_hit"] is True
+        assert _names(first.children) == [
+            "sql.parse", "sql.plan", "sched.place", "sched.run",
+            "sched.note"]
+        assert _names(warm.children) == [
+            "sched.place", "sched.run", "sched.note"]
+        (run,) = warm.find("sched.run")
+        (ex,) = run.children
+        assert ex.name == "device.execute"
+        assert _names(ex.children) == [
+            "device.dispatch", "device.readback", "device.run",
+            "device.materialize", "device.finish"]
+        assert _names(ex.children[0].children) == [
+            "device.bind", "device.launch"]
+        (bind,) = warm.find("device.bind")
+        assert bind.attrs == {"first": False, "uploads": 0,
+                              "upload_bytes": 0}
+        (rb,) = warm.find("device.readback")
+        assert rb.attrs["syncs"] == 1 and rb.attrs["bytes"] > 0
+
+    def test_first_execution_shows_the_compile_funnel(self, stmt_session):
+        first = stmt_session["first"]
+        (dispatch,) = first.find("device.dispatch")
+        assert _names(dispatch.children) == [
+            "device.compile", "device.bind", "device.launch"]
+        (compiled,) = first.find("device.compile")
+        assert _names(compiled.children) == [
+            "device.bind", "compile.lower", "compile.xla"]
+        # the bind under the compile built the scan view and uploaded;
+        # the dispatch's own bind found everything in place
+        under, own = first.find("device.bind")
+        assert under.attrs["first"] is True and under.attrs["uploads"] > 0
+        assert under.attrs["upload_bytes"] > 0
+        assert own.attrs["first"] is False and own.attrs["uploads"] == 0
+        (lower,) = first.find("compile.lower")
+        assert lower.attrs["lock_wait_ms"] >= 0
+        (xla,) = first.find("compile.xla")
+        assert xla.attrs["persistent_cache_hit"] in (True, False)
+
+    def test_self_times_add_up_to_the_root(self, stmt_session):
+        for root in (stmt_session["first"], stmt_session["warm"]):
+            (ex,) = root.find("device.execute")
+            (run,) = root.find("device.run")
+            # device.run is the launch-to-read-back bracket, handed to
+            # the tree after the fact: it overlaps its siblings, so it
+            # is left out and what it ALONE covers is added back
+            others = [c for c in ex.children if c is not run]
+            alone = (run.t1 - run.t0) - sum(
+                max(0.0, min(c.t1, run.t1) - max(c.t0, run.t0))
+                for c in others)
+            own = sum(s.self_s() for s in root.walk() if s is not run)
+            assert own + alone == pytest.approx(root.t1 - root.t0,
+                                                rel=1e-9)
+
+    def test_totals_count_the_statements_run(self, stmt_session):
+        from nds_tpu.obs.trace import get_tracer
+        sess, q6 = stmt_session["session"], stmt_session["q6"]
+        tracer = get_tracer()
+        before, m0 = tracer.totals(), obs_metrics.snapshot()["counters"]
+        for _ in range(3):
+            sess.sql(q6)
+        counts = _totals_since(before, tracer.totals())
+        assert counts == {name: 3 for name in (
+            "stmt", "sched.place", "sched.run", "sched.note",
+            "device.execute", "device.dispatch", "device.bind",
+            "device.launch", "device.readback", "device.run",
+            "device.materialize", "device.finish")}
+        seconds = _totals_since(before, tracer.totals(), "total_s")
+        own = _totals_since(before, tracer.totals(), "self_s")
+        assert 0 < own["stmt"] < seconds["stmt"]
+        assert own["device.launch"] == pytest.approx(
+            seconds["device.launch"])
+        m1 = obs_metrics.snapshot()["counters"]
+        moved = {k: m1[k] - m0.get(k, 0) for k in m1
+                 if m1[k] != m0.get(k, 0)}
+        assert moved["plan_cache_hits_total"] == 3
+        assert moved["device_readbacks_total"] == 3
+        assert moved["readback_bytes_total"] > 0
+        assert moved["scan_view_hits_total"] >= 3
+        assert "device_uploads_total" not in moved
+        assert "plan_cache_misses_total" not in moved
+
+    def test_load_and_first_bind_totals(self, stmt_session):
+        from nds_tpu.obs.trace import FIRST_SUFFIX, get_tracer
+        loaded, n = stmt_session["loaded"], stmt_session["tables"]
+        assert loaded["engine.init"] == 1
+        assert (loaded["load.table"], loaded["load.read"],
+                loaded["load.build"]) == (n, n, n)
+        totals = get_tracer().totals()
+        firsts = totals["device.bind" + FIRST_SUFFIX]
+        assert 1 <= firsts["count"] < totals["device.bind"]["count"]
+        assert 0 < firsts["total_s"] < totals["device.bind"]["total_s"]
+
+    def test_trees_are_held_to_the_catalogue(self, stmt_session):
+        sys.path.insert(0, TOOLS)
+        from check_trace_schema import SPAN_PARENTS, _validate_span_tree
+        for root in (stmt_session["first"], stmt_session["warm"]):
+            assert _validate_span_tree(root.to_dict(), "spans") == []
+            for span in root.walk():
+                if span.parent is not None and span.name in SPAN_PARENTS:
+                    assert span.parent.name in SPAN_PARENTS[span.name]
+        stray = {"name": "query", "dur_ms": 1.0, "children": [
+            {"name": "device.readback", "dur_ms": 0.5}]}
+        (err,) = _validate_span_tree(stray, "spans")
+        assert "device.readback" in err and "'query'" in err
+
+    def test_untraced_statement_is_timed_and_nothing_is_kept(
+            self, stmt_session):
+        """No profile, no export, no owner: the statement's `with`
+        spans are timed into the totals and leave no tree; the
+        executor's owned span is a root of its own, as before PR 25."""
+        from nds_tpu.obs.trace import get_tracer
+        sess, pipe = stmt_session["session"], stmt_session["pipe"]
+        tracer = get_tracer()
+        before = tracer.totals()
+        sess.sql(stmt_session["q6"])
+        ex = pipe.last_query_span
+        assert ex.name == "device.execute" and ex.parent is None
+        assert not ex.kept and _names(ex.children) == ["device.run"]
+        assert "execute_ms" in ex.attrs["timings"]
+        assert tracer.current() is None
+        after = tracer.totals()
+        total = _totals_since(before, after, "total_s")
+        own = _totals_since(before, after, "self_s")
+        assert set(total) == {
+            "stmt", "sched.place", "sched.run", "sched.note",
+            "device.execute", "device.dispatch", "device.bind",
+            "device.launch", "device.readback", "device.run",
+            "device.materialize", "device.finish"}
+        # self times still add up to the statement: device.run, the
+        # bracket over the launch and the read-back, is left out
+        assert sum(v for n, v in own.items() if n != "device.run") == \
+            pytest.approx(total["stmt"], rel=0.01)
+        assert 0 < own["sched.run"] < total["sched.run"] - \
+            total["device.execute"] * 0.99
+
+    def test_sql_async_has_one_root_too(self, stmt_session, tmp_path,
+                                        monkeypatch):
+        monkeypatch.setenv("NDS_TPU_TRACE", str(tmp_path / "t.jsonl"))
+        sess, pipe = stmt_session["session"], stmt_session["pipe"]
+        handle = sess.sql_async(stmt_session["q6"])
+        out = handle.result()
+        assert out is not None
+        root = _root_of(pipe.last_query_span)
+        assert root.name == "stmt" and root.t1 is not None
+        assert _names(root.children) == ["sched.place", "sched.run",
+                                         "sched.note"]
+        (ex,) = root.find("device.execute")
+        assert ex.parent.name == "sched.run"
+        assert "device.readback" in _names(ex.children)
+
+
+def _profile_events(tmp_path, body) -> list:
+    """[(name, start, end, {stat: value})] of the ``nds.*`` annotations
+    a CPU profile taken round ``body()`` holds, in start order."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("nds."):
+                    events.append((e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns,
+                                   dict(e.stats)))
+    return sorted(events, key=lambda e: e[1])
+
+
+class TestProfilerAnnotations:
+    def test_profile_holds_the_nested_spans_and_their_attributes(
+            self, stmt_session, tmp_path):
+        sess, q6 = stmt_session["session"], stmt_session["q6"]
+        events = _profile_events(tmp_path, lambda: sess.sql(q6))
+        by_name = {}
+        for ev in events:
+            by_name.setdefault(ev[0], []).append(ev)
+        # every `with` span of the warm tree, once; the owned
+        # device.execute / device.run cannot be annotations
+        assert sorted(by_name) == sorted("nds." + n for n in (
+            "stmt", "sched.place", "sched.run", "sched.note",
+            "device.dispatch", "device.bind", "device.launch",
+            "device.readback", "device.materialize", "device.finish"))
+        assert all(len(v) == 1 for v in by_name.values())
+
+        def inside(child: str, parent: str) -> bool:
+            (_n, c0, c1, _s), = by_name["nds." + child]
+            (_n, p0, p1, _s), = by_name["nds." + parent]
+            return p0 <= c0 and c1 <= p1
+
+        for child, parent in (
+                ("sched.place", "stmt"), ("sched.run", "stmt"),
+                ("sched.note", "stmt"), ("device.dispatch", "sched.run"),
+                ("device.bind", "device.dispatch"),
+                ("device.launch", "device.dispatch"),
+                ("device.readback", "sched.run"),
+                ("device.materialize", "sched.run"),
+                ("device.finish", "sched.run")):
+            assert inside(child, parent), (child, parent)
+        stats = {n: ev[0][3] for n, ev in by_name.items()}
+        assert stats["nds.stmt"]["stmt_id"] > 0
+        assert stats["nds.stmt"]["plan_cache_hit"] == 1
+        assert stats["nds.device.readback"]["syncs"] == 1
+        assert stats["nds.device.readback"]["bytes"] > 0
+        assert stats["nds.device.bind"] == {"first": 0, "uploads": 0,
+                                            "upload_bytes": 0}
+        # strings stay out of the annotation; numbers are all there is
+        assert all(k in PROFILE_STATS or k == "flops"
+                   for s in stats.values() for k in s)
+
+    def test_obs_off_is_the_noop_and_opens_no_annotation(
+            self, stmt_session, tmp_path, monkeypatch):
+        from nds_tpu.obs import trace
+        monkeypatch.setenv("NDS_TPU_OBS", "0")
+        off = Tracer()                     # what a process started so is
+        assert off.enabled is False
+        assert off.span("stmt", stmt_id=1) is NOOP_SPAN
+        assert off.totals() == {}
+        sess, q6 = stmt_session["session"], stmt_session["q6"]
+        tracer = trace.get_tracer()
+        before = tracer.totals()
+        trace.set_enabled(False)
+        try:
+            events = _profile_events(tmp_path, lambda: sess.sql(q6))
+        finally:
+            trace.set_enabled(True)
+        assert events == []
+        assert tracer.totals() == before
+        assert stmt_session["pipe"].last_query_span is None

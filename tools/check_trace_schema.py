@@ -17,7 +17,9 @@ Trace event schema (one event per line):
 
 BenchReport summary schema (``--summary``, README "Observability"):
   query/queryStatus/queryTimes/startTime/env required; optional blocks
-  — spans (name/dur_ms/attrs/children tree), metrics (counters/gauges/
+  — spans (name/dur_ms/attrs/children tree; a span the catalogue
+  SPAN_PARENTS names has to hang from one of the parents it lists),
+  metrics (counters/gauges/
   histograms with count+sum and optional p50/p95/p99), memory
   (device_hwm_bytes + source), retries / retry_backoff_s /
   gave_up_reason / deadline_exceeded, the scheduling fields
@@ -131,12 +133,41 @@ def _num(v) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
 
 
-def _validate_span_tree(node: object, path: str) -> list[str]:
+# The span catalogue at the layer boundaries (README "Observability";
+# duplicated by value: this validator stays runnable standalone): span
+# name -> the parents it may hang from in a statement's tree. A name
+# not listed here (sql.*, stage.*, cache.load, compile.*, load.*,
+# chunk.*, exchange.*, a tool's own) may hang anywhere.
+_ROOTS = ("stmt", "query")
+SPAN_PARENTS = {
+    "sched.place": _ROOTS,
+    "sched.run": _ROOTS,
+    "sched.note": _ROOTS,
+    "device.execute": _ROOTS + ("sched.run", "stage.sub", "chunk.reduce",
+                                "chunk.partial_agg"),
+    "device.dispatch": ("device.execute",),
+    "device.compile": ("device.dispatch", "device.execute"),
+    "device.bind": ("device.dispatch", "device.compile", "cache.load"),
+    "device.launch": ("device.dispatch", "device.execute"),
+    "device.readback": ("device.execute",),
+    "device.run": ("device.execute",),
+    "device.materialize": ("device.execute",),
+    "device.finish": ("device.execute",),
+}
+
+
+def _validate_span_tree(node: object, path: str,
+                        parent: "str | None" = None) -> list[str]:
     if not isinstance(node, dict):
         return [f"{path}: span node is {type(node).__name__}"]
     errs = []
     if not node.get("name") or not isinstance(node.get("name"), str):
         errs.append(f"{path}: missing/empty span name")
+    elif (parent is not None
+          and parent not in SPAN_PARENTS.get(node["name"], (parent,))):
+        errs.append(f"{path}: span {node['name']!r} hangs from "
+                    f"{parent!r}, the catalogue allows "
+                    f"{SPAN_PARENTS[node['name']]}")
     if not _num(node.get("dur_ms")) or node.get("dur_ms", 0) < 0:
         errs.append(f"{path}: bad dur_ms {node.get('dur_ms')!r}")
     if "attrs" in node and not isinstance(node["attrs"], dict):
@@ -146,7 +177,8 @@ def _validate_span_tree(node: object, path: str) -> list[str]:
         errs.append(f"{path}: children is not a list")
         kids = []
     for i, k in enumerate(kids):
-        errs.extend(_validate_span_tree(k, f"{path}.children[{i}]"))
+        errs.extend(_validate_span_tree(k, f"{path}.children[{i}]",
+                                        node.get("name")))
     return errs
 
 

@@ -237,14 +237,13 @@ def run_trace_schema_check() -> int:
     file and validate it against the documented event schema."""
     from nds_tpu.obs.trace import Tracer, export_chrome
     tracer = Tracer(enabled=True)
-    with tracer.span("static_checks.trace_selftest", gate="tier-1"):
+    with tracer.span("static_checks.trace_selftest", gate="tier-1") as root:
         with tracer.span("static_checks.child", n=1):
             pass
-    roots = getattr(tracer, "last_roots", None)
-    if not roots:  # tracer API drift: fail loudly, not silently
+    if not root or len(tracer.totals()) != 2:
+        # tracer API drift: fail loudly, not silently
         print("FAIL: tracer produced no root span")
         return 1
-    root = roots[-1]
     with tempfile.NamedTemporaryFile("w", suffix=".jsonl",
                                      delete=False) as f:
         path = f.name
