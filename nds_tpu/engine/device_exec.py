@@ -19,9 +19,16 @@ designed TPU-first (SURVEY.md §7):
   one int64 using value bounds computed on the host at trace time.
 - **Grouping is sort-based.** Rows sort by (presence, keys...) via a
   stable multi-operand `lax.sort`; group boundaries come from adjacent-row
-  comparison; aggregates are `segment_sum/min/max` with
-  `indices_are_sorted=True`. Output capacity = input capacity; the unused
-  tail is masked.
+  comparison; sums, counts and averages are differences of an inclusive
+  cumsum at the group's ends (`_seg_sum`), min/max a segmented scan
+  (`kernels.seg_reduce_at_ends`). Output capacity = min(input capacity,
+  product of the key domains); the unused tail is masked.
+- **A sort's permutation goes to index vectors only.** On the TPU every
+  `take` is an N-row gather per 32-bit word and the compiler pushes no
+  slice through it, so nothing the sort already returned is gathered
+  again: presence after a sort is its first operand, group keys are its
+  sorted key operands read at the groups' first rows, and a LIMIT
+  gathers each column once, at the rows it keeps (`perm[:cap]`).
 - **Strings never reach the device.** Columns are dictionary-encoded
   (sorted dictionary => code order == lexicographic order,
   `nds_tpu/io/host_table.py`); LIKE / IN / comparisons against literals are
@@ -39,6 +46,7 @@ The differential oracle for all of this is `cpu_exec.CpuExecutor`
 
 from __future__ import annotations
 
+import math
 import os
 
 import numpy as np
@@ -230,14 +238,15 @@ class DCtx:
         self.row = row
         self.cols: dict[tuple, DVal] = {}
 
-    def gather(self, idx, clear_valid=None) -> "DCtx":
+    def gather(self, idx, clear_valid=None, take=jnp.take) -> "DCtx":
         """New ctx with every column gathered at idx (same capacity as idx).
         clear_valid, if given, is ANDed into every column's validity
-        (used to null out the build side of outer joins)."""
+        (used to null out the build side of outer joins). ``take``: the
+        trace passes its tallying ``_take``."""
         out = DCtx(idx.shape[0], None)
         for k, dv in self.cols.items():
-            arr = jnp.take(dv.arr, idx, axis=0)
-            valid = None if dv.valid is None else jnp.take(dv.valid, idx)
+            arr = take(dv.arr, idx, axis=0)
+            valid = None if dv.valid is None else take(dv.valid, idx)
             if clear_valid is not None:
                 valid = clear_valid if valid is None else (valid & clear_valid)
             out.cols[k] = dv.with_arrays(arr, valid)
@@ -316,6 +325,12 @@ def _epoch_days_to_civil(days):
     m = mp + jnp.where(mp < 10, 3, -9)
     y = y + (m <= 2)
     return y.astype(jnp.int32), m.astype(jnp.int32), d.astype(jnp.int32)
+
+
+def _words_per_row(arr) -> int:
+    """32-bit words in one row of arr (a bool or int8 row still costs
+    the gather one word)."""
+    return max(arr.dtype.itemsize // 4, 1) * math.prod(arr.shape[1:])
 
 
 def _narrow_key(dv: DVal):
@@ -1192,7 +1207,7 @@ class DeviceExecutor:
             tr = _Trace(self, bufs, slack, params=params)
             row, outs, dicts = tr.run_query(planned)
             side["dicts"] = dicts
-            side["kernels"] = dict(tr.kernels)
+            side["kernels"] = tr.kernel_counts()
             side["ops_est"] = int(tr.ops_est)
             return row, outs, tr.total_overflow()
 
@@ -1552,9 +1567,30 @@ class _Trace:
         # the numerator of the per-query ops/byte model ndsreport's
         # roofline column reads
         self.ops_est: int = 0
+        # rows x 32-bit words this trace's gathers move, summed over its
+        # take sites at trace time: the TPU compiler emits one N-row
+        # gather per 32-bit word of the operand (two for an int64 column)
+        self.gather_words: int = 0
 
     def _note(self, kernel: str) -> None:
         self.kernels[kernel] = self.kernels.get(kernel, 0) + 1
+
+    def kernel_counts(self) -> dict:
+        """The per-query ``kernels`` block: trace-time kernel uses plus
+        the program's static ``gather_words``."""
+        out = dict(self.kernels)
+        if self.gather_words:
+            out["gather_words"] = self.gather_words
+        return out
+
+    def _take(self, arr, idx, **kw):
+        """``jnp.take``, tallied into ``gather_words``."""
+        self.gather_words += int(idx.size) * _words_per_row(arr)
+        return jnp.take(arr, idx, **kw)
+
+    def _gather(self, ctx: DCtx, idx, clear_valid=None) -> DCtx:
+        """``ctx.gather``, tallied into ``gather_words``."""
+        return ctx.gather(idx, clear_valid, take=self._take)
 
     def total_overflow(self):
         if not self._overflows:
@@ -1801,7 +1837,7 @@ class _Trace:
                 hi = max(len(lv.sdict) - 1, 0)
                 return lv.arr, rv.arr, 0, hi
             union, lmap, rmap = self._dict_union(lv.sdict, rv.sdict)
-            return (jnp.take(lmap, lv.arr), jnp.take(rmap, rv.arr),
+            return (self._take(lmap, lv.arr), self._take(rmap, rv.arr),
                     0, max(len(union) - 1, 0))
         la, ra = lv.arr, rv.arr
         if (lv.lo is None or lv.hi is None or rv.lo is None
@@ -1841,11 +1877,12 @@ class _Trace:
         return ks, order
 
     @staticmethod
-    def _probe(ks, order, pkey, pok):
+    def _probe(ks, order, pkey, pok, take=jnp.take):
+        """``take``: the trace passes its tallying ``_take``."""
         n = ks.shape[0]
         pos = jnp.clip(_ss(ks, pkey), 0, n - 1)
-        hit = (jnp.take(ks, pos) == pkey) & pok
-        return jnp.take(order, pos), hit
+        hit = (take(ks, pos) == pkey) & pok
+        return take(order, pos), hit
 
     def _full_join(self, node: P.Join, lctx, rctx, lkey, lok, rkey,
                    rok) -> DCtx:
@@ -1858,15 +1895,15 @@ class _Trace:
             raise DeviceExecError(
                 "FULL OUTER JOIN requires unique join keys")
         ks, order = self._build_lookup(rkey, rok)
-        ridx, hit = self._probe(ks, order, lkey, lok)
+        ridx, hit = self._probe(ks, order, lkey, lok, take=self._take)
         ks2, order2 = self._build_lookup(lkey, lok)
-        _lidx, rhit = self._probe(ks2, order2, rkey, rok)
+        _lidx, rhit = self._probe(ks2, order2, rkey, rok, take=self._take)
         unmatched_r = rctx.row & ~rhit
 
         falsev = jnp.zeros(rctx.n, dtype=bool)
         out = DCtx(lctx.n + rctx.n,
                    jnp.concatenate([lctx.row, unmatched_r]))
-        gathered = rctx.gather(ridx, clear_valid=hit)
+        gathered = self._gather(rctx, ridx, clear_valid=hit)
         for k, dv in lctx.cols.items():
             # left columns: present in block A, null in block B
             pad = jnp.zeros((rctx.n,) + dv.arr.shape[1:], dv.arr.dtype)
@@ -1929,11 +1966,12 @@ class _Trace:
                 else:
                     ks, order = self._build_lookup(rkey, rok)
                     self._note("join.sortmerge")
-                ridx, hit = self._probe(ks, order, lkey, lok)
+                ridx, hit = self._probe(ks, order, lkey, lok,
+                                        take=self._take)
             if node.kind == "left":
                 out = DCtx(lctx.n, lctx.row)
                 out.cols.update(lctx.cols)
-                gathered = rctx.gather(ridx, clear_valid=hit)
+                gathered = self._gather(rctx, ridx, clear_valid=hit)
                 out.cols.update(gathered.cols)
                 if node.residual is not None:
                     resid = self.eval(node.residual, out)
@@ -1943,12 +1981,13 @@ class _Trace:
                     keep = hit & rk
                     out2 = DCtx(lctx.n, lctx.row)
                     out2.cols.update(lctx.cols)
-                    out2.cols.update(rctx.gather(ridx, clear_valid=keep).cols)
+                    out2.cols.update(self._gather(
+                        rctx, ridx, clear_valid=keep).cols)
                     return out2
                 return out
             out = DCtx(lctx.n, lctx.row & hit)
             out.cols.update(lctx.cols)
-            out.cols.update(rctx.gather(ridx).cols)
+            out.cols.update(self._gather(rctx, ridx).cols)
             if node.residual is not None:
                 out = self._apply_filter(out, node.residual)
             return out
@@ -1969,8 +2008,8 @@ class _Trace:
                 self._overflows.append(over)
                 self._note("join.partitioned")
                 out = DCtx(int(lidx2.shape[0]), present)
-                out.cols.update(lctx.gather(lidx2).cols)
-                out.cols.update(rctx.gather(ridx).cols)
+                out.cols.update(self._gather(lctx, lidx2).cols)
+                out.cols.update(self._gather(rctx, ridx).cols)
                 if node.residual is not None:
                     out = self._apply_filter(out, node.residual)
                 return out
@@ -1999,15 +2038,15 @@ class _Trace:
             offs32 = jnp.minimum(offs, K + 1).astype(jnp.int32)
             ridx = jnp.clip(_ss(offs32, slots, side="right"),
                             0, rctx.n - 1)
-            prev = jnp.where(ridx > 0, jnp.take(offs32, ridx - 1), 0)
+            prev = jnp.where(ridx > 0, self._take(offs32, ridx - 1), 0)
             within = slots - prev
-            lpos = jnp.clip(jnp.take(lo, ridx) + within, 0, lctx.n - 1)
-            lidx2 = jnp.take(order, lpos)
+            lpos = jnp.clip(self._take(lo, ridx) + within, 0, lctx.n - 1)
+            lidx2 = self._take(order, lpos)
             present = slots < jnp.minimum(total, K)
             self._overflows.append(jnp.maximum(total - K, 0))
             out = DCtx(K, present)
-            out.cols.update(lctx.gather(lidx2).cols)
-            out.cols.update(rctx.gather(ridx).cols)
+            out.cols.update(self._gather(lctx, lidx2).cols)
+            out.cols.update(self._gather(rctx, ridx).cols)
             if node.residual is not None:
                 out = self._apply_filter(out, node.residual)
             return out
@@ -2016,14 +2055,14 @@ class _Trace:
         # way, this path serves customer LEFT JOIN orders plans, q13)
         self._note("join.sortmerge")
         ks, order = self._build_lookup(lkey, lok)
-        lidx, hit = self._probe(ks, order, rkey, rok)
+        lidx, hit = self._probe(ks, order, rkey, rok, take=self._take)
         # left outer with expansion: block A = matched right rows with
         # gathered left columns; block B = left rows with no surviving match
         presentA = rctx.row & hit
         if node.residual is not None:
             combined = DCtx(rctx.n, presentA)
             combined.cols.update(rctx.cols)
-            combined.cols.update(lctx.gather(lidx).cols)
+            combined.cols.update(self._gather(lctx, lidx).cols)
             resid = self.eval(node.residual, combined)
             rk = resid.arr.astype(bool)
             if resid.valid is not None:
@@ -2035,7 +2074,7 @@ class _Trace:
         n_out = rctx.n + lctx.n
         out = DCtx(n_out, jnp.concatenate(
             [presentA, lctx.row & ~matched]))
-        gatheredA = lctx.gather(lidx)
+        gatheredA = self._gather(lctx, lidx)
         for k, dv in lctx.cols.items():
             ga = gatheredA.cols[k]
             arr = jnp.concatenate([ga.arr, dv.arr])
@@ -2061,8 +2100,8 @@ class _Trace:
                 f"cross join too large: {lctx.n} x {rctx.n}")
         li = jnp.repeat(jnp.arange(lctx.n, dtype=jnp.int32), rctx.n)
         ri = jnp.tile(jnp.arange(rctx.n, dtype=jnp.int32), lctx.n)
-        out = lctx.gather(li).merge(rctx.gather(ri))
-        out.row = jnp.take(lctx.row, li) & jnp.take(rctx.row, ri)
+        out = self._gather(lctx, li).merge(self._gather(rctx, ri))
+        out.row = self._take(lctx.row, li) & self._take(rctx.row, ri)
         if node.residual is not None:
             out = self._apply_filter(out, node.residual)
         return out
@@ -2087,7 +2126,8 @@ class _Trace:
                 self._note("semi.bitmask")
             else:
                 ks, order = self._build_lookup(rkey, rok)
-                _idx, hit = self._probe(ks, order, lkey, lok)
+                _idx, hit = self._probe(ks, order, lkey, lok,
+                                        take=self._take)
                 exists = hit
                 self._note("semi.sortmerge")
         else:
@@ -2151,8 +2191,8 @@ class _Trace:
         pos_l = _ss(sk, lkey, side="left")
         pos_r = _ss(sk, lkey, side="right")
         n = sk.shape[0]
-        cmin = jnp.take(sc, jnp.clip(pos_l, 0, n - 1))
-        cmax = jnp.take(sc, jnp.clip(pos_r - 1, 0, n - 1))
+        cmin = self._take(sc, jnp.clip(pos_l, 0, n - 1))
+        cmax = self._take(sc, jnp.clip(pos_r - 1, 0, n - 1))
         has_key = pos_r > pos_l
         differs = (cmin != lcol_n) | (cmax != lcol_n)
         return lok & lok2 & has_key & differs
@@ -2170,7 +2210,7 @@ class _Trace:
                 out.cols[(b, name)] = DVal(arr, valid, sdict, lo, hi)
             return out
         keyvals = [self.eval(e, ctx) for _, e in node.group_keys]
-        perm, gid, first_s, present_s, ngroups = self._group_ids(ctx, keyvals)
+        perm, gid, present_s, ngroups, keys_s = self._group_ids(ctx, keyvals)
         G = self._group_capacity(ctx.n, keyvals)
         gid = jnp.minimum(gid, G - 1)
         out_row = jnp.arange(G, dtype=jnp.int32) < ngroups
@@ -2179,13 +2219,9 @@ class _Trace:
         # sorted, so this is a sorted search, not a segment_min scatter
         starts2 = _ss(gid, jnp.arange(G, dtype=gid.dtype))
         starts = jnp.clip(starts2, 0, ctx.n - 1)
-        for (kname, _kexpr), kv in zip(node.group_keys, keyvals):
-            arr_s = jnp.take(kv.arr, perm)
-            arr_g = jnp.take(arr_s, starts)
-            valid_g = None
-            if kv.valid is not None:
-                valid_g = jnp.take(jnp.take(kv.valid, perm), starts)
-            out.cols[(b, kname)] = kv.with_arrays(arr_g, valid_g)
+        for (kname, _kexpr), dv in zip(
+                node.group_keys, self._group_keys(keyvals, keys_s, starts)):
+            out.cols[(b, kname)] = dv
         for name, spec in node.aggs:
             arr, valid, sdict = self._agg_grouped(
                 spec, ctx, perm, gid, present_s, G, starts2,
@@ -2194,8 +2230,7 @@ class _Trace:
             out.cols[(b, name)] = DVal(arr, valid, sdict, lo, hi)
         return out
 
-    @staticmethod
-    def _seg_sum(data, starts2, G):
+    def _seg_sum(self, data, starts2, G):
         """Per-segment sum over the SORTED row space via inclusive-cumsum
         differences. segment_sum lowers to scatter-add (~160ms for i64 at
         1.8M rows on TPU, measured); cumsum runs at memory speed.
@@ -2214,9 +2249,9 @@ class _Trace:
         nxt = jnp.concatenate(
             [starts2[1:], jnp.full((1,), n, starts2.dtype)])
         end = jnp.clip(nxt - 1, 0, n - 1)
-        hi = jnp.take(csum, end)
+        hi = self._take(csum, end)
         lo = jnp.where(starts2 > 0,
-                       jnp.take(csum, jnp.clip(starts2 - 1, 0, n - 1)),
+                       self._take(csum, jnp.clip(starts2 - 1, 0, n - 1)),
                        jnp.zeros((), csum.dtype))
         return hi - lo
 
@@ -2260,36 +2295,56 @@ class _Trace:
 
     def _group_ids(self, ctx: DCtx, keyvals):
         """Stable sort rows by (presence, key validity+values...); returns
-        (perm, gid per sorted row, first-flag, presence per sorted row,
-        ngroups). Present rows sort to the front."""
+        (perm, gid per sorted row, presence per sorted row, ngroups,
+        sorted key operands). Present rows sort to the front. The sorted
+        key operands, one (values, NULL flags or None) pair a key, are
+        what _group_keys reads the output keys from."""
         n = ctx.n
         ops = [jnp.where(ctx.row, 0, 1).astype(jnp.int32)]
         key_ops = []
         for kv in keyvals:
+            null_op = None
             if kv.valid is not None:
                 vop = jnp.where(kv.valid, 0, 1).astype(jnp.int32)
                 ops.append(vop)
-                key_ops.append(len(ops) - 1)
+                null_op = len(ops) - 1
             arr = _narrow_key(kv)
             filled = jnp.where(_ok(kv, ctx.row), arr,
                                jnp.zeros((), dtype=arr.dtype))
             ops.append(filled)
-            key_ops.append(len(ops) - 1)
+            key_ops.append((len(ops) - 1, null_op))
         ops.append(jnp.arange(n, dtype=jnp.int32))
         sorted_ops = lax.sort(ops, num_keys=len(ops) - 1, is_stable=True)
         perm = sorted_ops[-1]
-        present_s = jnp.take(ctx.row, perm)
+        # the presence flag is the sort's first operand
+        present_s = sorted_ops[0] == 0
         iota = jnp.arange(n, dtype=jnp.int32)
         diff = jnp.zeros(n, dtype=bool).at[0].set(True)
-        for i in key_ops:
-            o = sorted_ops[i]
+        keys_s = [(sorted_ops[i], None if j is None else sorted_ops[j])
+                  for i, j in key_ops]
+        for o in (o for pair in keys_s for o in pair if o is not None):
             diff = diff | jnp.concatenate(
                 [jnp.ones(1, bool), o[1:] != o[:-1]])
         first_s = present_s & (diff | (iota == 0))
         gid = jnp.cumsum(first_s.astype(jnp.int32)) - 1
         gid = jnp.clip(gid, 0, n - 1)
         ngroups = jnp.sum(first_s)
-        return perm, gid, first_s, present_s, ngroups
+        return perm, gid, present_s, ngroups, keys_s
+
+    def _group_keys(self, keyvals, keys_s, starts) -> list:
+        """Each group's key columns, read from the group sort's own
+        sorted key operands at the group's first sorted row: the column
+        is not gathered through the permutation. A sorted operand is the
+        column as _narrow_key narrowed it (cast back here) with 0 under
+        NULL and absent rows, which validity and the row mask cover."""
+        out = []
+        for kv, (val_s, null_s) in zip(keyvals, keys_s):
+            arr_g = self._take(val_s, starts).astype(kv.arr.dtype)
+            valid_g = (None if null_s is None
+                       else self._take(null_s, starts) == 0)
+            out.append(kv.with_arrays(arr_g, valid_g))
+        self._note("agg.sorted_keys")
+        return out
 
     def _agg_arg(self, spec: P.AggSpec, ctx: DCtx):
         if spec.arg is None:
@@ -2365,10 +2420,10 @@ class _Trace:
             cnt = self._seg_sum(present_s.astype(jnp.int32), starts2,
                                 G).astype(jnp.int64)
             return cnt, None, None
-        arr_s = jnp.take(dv.arr, perm)
+        arr_s = self._take(dv.arr, perm)
         w = present_s
         if dv.valid is not None:
-            w = w & jnp.take(dv.valid, perm)
+            w = w & self._take(dv.valid, perm)
         # counts fit int32 (<= capacity); widen only the G-sized result
         cnt = self._seg_sum(w.astype(jnp.int32), starts2,
                             G).astype(jnp.int64)
@@ -2453,10 +2508,9 @@ class _Trace:
                jnp.where(w0, 0, 1).astype(jnp.int32),
                jnp.where(w0, val, 0), jnp.arange(n, dtype=jnp.int32)]
         sorted_ops = lax.sort(ops, num_keys=4, is_stable=True)
-        perm2 = sorted_ops[-1]
         g2 = sorted_ops[1]
         v2 = sorted_ops[3]
-        w2 = jnp.take(w0, perm2)
+        w2 = sorted_ops[2] == 0
         newpair = jnp.concatenate(
             [jnp.ones(1, bool), (g2[1:] != g2[:-1]) | (v2[1:] != v2[:-1])])
         flag = w2 & newpair
@@ -2514,7 +2568,7 @@ class _Trace:
         ops.append(iota)
         sorted_ops = lax.sort(ops, num_keys=len(ops) - 1, is_stable=True)
         perm = sorted_ops[-1]
-        present_s = jnp.take(ctx.row, perm)
+        present_s = sorted_ops[0] == 0
         part_start = jnp.zeros(n, dtype=bool).at[0].set(True)
         for i in part_ops:
             o = sorted_ops[i]
@@ -2550,8 +2604,8 @@ class _Trace:
         # aggregate windows
         if spec.arg is not None:
             dv = self.eval(spec.arg, ctx)
-            w = jnp.take(_ok(dv, ctx.row), perm)
-            vals = jnp.take(dv.arr, perm)
+            w = self._take(_ok(dv, ctx.row), perm)
+            vals = self._take(dv.arr, perm)
         else:  # count(*)
             w = present_s
             vals = jnp.ones(n, dtype=jnp.int64)
@@ -2577,9 +2631,9 @@ class _Trace:
 
         def part_total(data):
             csum = jnp.cumsum(data)
-            hi = jnp.take(csum, pend)
+            hi = self._take(csum, pend)
             lo = jnp.where(start_pos > 0,
-                           jnp.take(csum, jnp.clip(start_pos - 1, 0, n - 1)),
+                           self._take(csum, jnp.clip(start_pos - 1, 0, n - 1)),
                            jnp.zeros((), csum.dtype))
             return hi - lo
 
@@ -2642,14 +2696,18 @@ class _Trace:
             # each peer group's last row via a reversed running-min
             # over future change positions — no segment_max scatter
             last = KX.last_of_group(change, n)
-            res = jnp.take(res, last)
+            res = self._take(res, last)
             if valid is not None:
-                valid = jnp.take(valid, last)
+                valid = self._take(valid, last)
         return scatter(res, valid)
 
     # ------------------------------------------------------- sort and misc
 
-    def _run_sort(self, node: P.Sort) -> DCtx:
+    def _sort_perm(self, node: P.Sort):
+        """The Sort's input, its stable full sort as (permutation,
+        presence per sorted row), present rows first: what _run_sort
+        gathers every row through and a Limit above only the rows it
+        keeps."""
         ctx = self.run(node.child)
         n = ctx.n
         ops = [jnp.where(ctx.row, 0, 1).astype(jnp.int32)]
@@ -2668,49 +2726,53 @@ class _Trace:
             ops.append(key)
         ops.append(jnp.arange(n, dtype=jnp.int32))
         sorted_ops = lax.sort(ops, num_keys=len(ops) - 1, is_stable=True)
-        perm = sorted_ops[-1]
-        out = ctx.gather(perm)
-        out.row = jnp.take(ctx.row, perm)
-        return out
+        return ctx, sorted_ops[-1], sorted_ops[0] == 0
 
-    def _compact(self, ctx: DCtx) -> DCtx:
-        """Stable-sort present rows to the front (needed before Limit when
-        the child didn't already order them)."""
-        ops = [jnp.where(ctx.row, 0, 1).astype(jnp.int32),
-               jnp.arange(ctx.n, dtype=jnp.int32)]
-        sorted_ops = lax.sort(ops, num_keys=1, is_stable=True)
-        perm = sorted_ops[-1]
-        out = ctx.gather(perm)
-        out.row = jnp.take(ctx.row, perm)
+    def _run_sort(self, node: P.Sort) -> DCtx:
+        ctx, perm, present_s = self._sort_perm(node)
+        out = self._gather(ctx, perm)
+        out.row = present_s
         return out
 
     def _run_limit(self, node: P.Limit) -> DCtx:
-        ctx = self.run(node.child)
-        if not isinstance(node.child, P.Sort):
-            ctx = self._compact(ctx)
+        """Top-N: the columns are gathered at the rows LIMIT keeps, not
+        through the whole permutation and sliced (the TPU compiler does
+        not push a slice through a gather)."""
+        if isinstance(node.child, P.Sort):
+            ctx, perm, present_s = self._sort_perm(node.child)
+            self.ops_est += ctx.n  # the Sort node, which run() did not see
+        else:
+            # stable-sort present rows to the front: the child did not
+            # order them
+            ctx = self.run(node.child)
+            ops = [jnp.where(ctx.row, 0, 1).astype(jnp.int32),
+                   jnp.arange(ctx.n, dtype=jnp.int32)]
+            sorted_ops = lax.sort(ops, num_keys=1, is_stable=True)
+            perm, present_s = sorted_ops[-1], sorted_ops[0] == 0
         cap = min(node.count, ctx.n)
-        out = DCtx(cap, ctx.row[:cap])
-        for k, dv in ctx.cols.items():
-            out.cols[k] = dv.with_arrays(
-                dv.arr[:cap],
-                None if dv.valid is None else dv.valid[:cap])
+        out = self._gather(ctx, perm[:cap])
+        out.row = present_s[:cap]
+        self._note("sort.topn")
         return out
 
     def _run_distinct(self, node: P.Distinct) -> DCtx:
         ctx = self.run(node.child)
         b = node.binding
-        keyvals = [ctx.cols[(b, name)] for name, _ in node.output]
-        perm, gid, first_s, present_s, ngroups = self._group_ids(ctx, keyvals)
+        return self._distinct_rows(
+            ctx, [(b, name) for name, _ in node.output])
+
+    def _distinct_rows(self, ctx: DCtx, keys: list) -> DCtx:
+        """One row a distinct combination of ctx's columns ``keys``,
+        capacity ctx.n."""
+        keyvals = [ctx.cols[k] for k in keys]
+        _perm, gid, _present_s, ngroups, keys_s = self._group_ids(
+            ctx, keyvals)
         G = ctx.n
         starts = jnp.clip(_ss(gid, jnp.arange(G, dtype=gid.dtype)),
-                          0, ctx.n - 1)
+                          0, G - 1)
         out = DCtx(G, jnp.arange(G, dtype=jnp.int32) < ngroups)
-        for (name, _dt), kv in zip(node.output, keyvals):
-            arr_g = jnp.take(jnp.take(kv.arr, perm), starts)
-            valid_g = None
-            if kv.valid is not None:
-                valid_g = jnp.take(jnp.take(kv.valid, perm), starts)
-            out.cols[(b, name)] = kv.with_arrays(arr_g, valid_g)
+        out.cols = dict(zip(keys, self._group_keys(keyvals, keys_s,
+                                                   starts)))
         return out
 
     def _run_setop(self, node: P.SetOp) -> DCtx:
@@ -2746,21 +2808,8 @@ class _Trace:
                     else max(lv.hi, rv.hi))
             if node.kind == "union":
                 # distinct over the concatenated context, inline
-                keyvals = [out.cols[(lb, name)]
-                           for name, _ in node.left.output]
-                perm, gid, first_s, present_s, ngroups = self._group_ids(
-                    out, keyvals)
-                G = out.n
-                starts = jnp.clip(_ss(gid, jnp.arange(G, dtype=gid.dtype)),
-                                  0, G - 1)
-                dctx = DCtx(G, jnp.arange(G, dtype=jnp.int32) < ngroups)
-                for (name, _dt), kv in zip(node.left.output, keyvals):
-                    arr_g = jnp.take(jnp.take(kv.arr, perm), starts)
-                    valid_g = None
-                    if kv.valid is not None:
-                        valid_g = jnp.take(jnp.take(kv.valid, perm), starts)
-                    dctx.cols[(lb, name)] = kv.with_arrays(arr_g, valid_g)
-                return dctx
+                return self._distinct_rows(
+                    out, [(lb, name) for name, _ in node.left.output])
             return out
         # INTERSECT / EXCEPT: whole-row membership against the right
         # side. Rows pack into one int64 (pair-aligned per column, plus a
@@ -2804,7 +2853,7 @@ class _Trace:
         # ndslint: waive[NDS112] -- keys narrow to int32 above whenever the pack fits 30 bits; wider whole-row packs genuinely need int64
         ks = jnp.sort(jnp.where(rctx.row, rkey, sent))
         pos = jnp.clip(_ss(ks, lkey), 0, rctx.n - 1)
-        hit = jnp.take(ks, pos) == lkey
+        hit = self._take(ks, pos) == lkey
         keep = hit if node.kind == "intersect" else ~hit
         out = DCtx(lctx.n, lctx.row & keep)
         out.cols = lctx.cols
@@ -2818,7 +2867,7 @@ class _Trace:
                 and np.array_equal(lv.sdict, rv.sdict)):
             return lv.arr, rv.arr, lv.sdict
         union, lmap, rmap = self._dict_union(lv.sdict, rv.sdict)
-        return (jnp.take(lmap, lv.arr), jnp.take(rmap, rv.arr),
+        return (self._take(lmap, lv.arr), self._take(rmap, rv.arr),
                 union.astype(object))
 
     # ---------------------------------------------------------- expressions
@@ -2870,7 +2919,7 @@ class _Trace:
             table = like_mask(dv.sdict, e.pattern)
             if e.negated:
                 table = ~table
-            return DVal(jnp.take(jnp.asarray(table), dv.arr), dv.valid)
+            return DVal(self._take(jnp.asarray(table), dv.arr), dv.valid)
         if isinstance(e, ir.InListIR):
             return self._eval_inlist(e, ctx)
         if isinstance(e, ir.IsNullIR):
@@ -2927,7 +2976,7 @@ class _Trace:
                 f"{e.table}.{e.column}")
         if e.negated:
             tab = ~tab
-        return DVal(jnp.take(tab, dv.arr), dv.valid)
+        return DVal(self._take(tab, dv.arr), dv.valid)
 
     def _eval_inlist_param(self, e: ir.InListParamIR, ctx: DCtx) -> DVal:
         """A hoisted numeric IN-list: fixed-width vector input, any-of
@@ -3040,7 +3089,7 @@ class _Trace:
             if flipped:
                 op = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}.get(op, op)
             table = _np_cmp(op, vals, str(lit))
-            return DVal(jnp.take(jnp.asarray(table), dv.arr), dv.valid)
+            return DVal(self._take(jnp.asarray(table), dv.arr), dv.valid)
         l = self.eval(e.left, ctx)
         r = self.eval(e.right, ctx)
         valid = _and_valid(l.valid, r.valid)
@@ -3110,7 +3159,7 @@ class _Trace:
         for dv in dvals:
             table = jnp.asarray(np.searchsorted(
                 union.astype(str), dv.sdict.astype(str)).astype(np.int32))
-            arr = jnp.take(table, dv.arr)
+            arr = self._take(table, dv.arr)
             if arr.ndim == 0:
                 arr = jnp.broadcast_to(arr, (ctx.n,))
             remapped.append(arr)
@@ -3142,7 +3191,7 @@ class _Trace:
                             np.array([str(v) for v in e.values]))
             if e.negated:
                 table = ~table
-            return DVal(jnp.take(jnp.asarray(table), dv.arr), dv.valid)
+            return DVal(self._take(jnp.asarray(table), dv.arr), dv.valid)
         vals = e.values
         if isinstance(e.operand.dtype, DecimalType):
             s = e.operand.dtype.scale
@@ -3165,7 +3214,7 @@ class _Trace:
         uniq, inverse = np.unique(newvals.astype(str),
                                   return_inverse=True)
         table = jnp.asarray(inverse.astype(np.int32))
-        return DVal(jnp.take(table, dv.arr), dv.valid,
+        return DVal(self._take(table, dv.arr), dv.valid,
                     uniq.astype(object), 0, max(len(uniq) - 1, 0))
 
     def _eval_strmap(self, e: ir.StrMapIR, ctx: DCtx) -> DVal:
@@ -3190,7 +3239,7 @@ class _Trace:
                         dtype=object)
         newdict, remap = np.unique(subs.astype(str), return_inverse=True)
         table = jnp.asarray(remap.astype(np.int32))
-        return DVal(jnp.take(table, dv.arr), dv.valid,
+        return DVal(self._take(table, dv.arr), dv.valid,
                     newdict.astype(object), 0, max(len(newdict) - 1, 0))
 
     def _eval_cast(self, e: ir.CastIR, ctx: DCtx) -> DVal:

@@ -228,7 +228,7 @@ class DistributedExecutor(dx.DeviceExecutor):
                 with skew_trace() as skews:
                     row, outs, dicts = tr.run_query(planned)
                 side["dicts"] = dicts
-                side["kernels"] = dict(tr.kernels)
+                side["kernels"] = tr.kernel_counts()
                 side["ops_est"] = int(tr.ops_est)
                 overflow = tr.total_overflow()
                 if skews:
@@ -921,9 +921,13 @@ class _DistTrace(dx._Trace):
         return out
 
     def _run_limit(self, node: P.Limit) -> DCtx:
-        child = self.run(node.child)
+        # over a Sort the base applies the Sort's permutation itself, at
+        # the rows LIMIT keeps: what it reads is the Sort's input
+        src = (node.child.child if isinstance(node.child, P.Sort)
+               else node.child)
+        child = self.run(src)
         if getattr(child, "sharded", False):
-            self.stash(node.child, self._replicate(child))
+            self.stash(src, self._replicate(child))
             self._cache.pop(id(node), None)
         out = super()._run_limit(node)
         out.sharded = False

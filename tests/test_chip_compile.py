@@ -210,3 +210,51 @@ def test_q6_whole_program_compiles_for_v5e(one_chip, no_persistent_cache):
     compiled = jitted.lower(avatars).compile()
     assert " sort(" not in compiled.as_text()
     assert side["ops_est"] > 0
+
+
+
+@pytest.mark.parametrize("sliced_first", [True, False],
+                         ids=["gather-kept-rows", "gather-then-slice"])
+def test_topn_gather_shape_on_v5e(sliced_first, one_chip,
+                                  no_persistent_cache):
+    """What follows the sort of ORDER BY ... LIMIT 100 over an SF1-sized
+    lineitem. As ``_Trace._run_limit`` emits it, every column is
+    gathered at ``perm[:100]``: no 6M-row ``kCustom`` fusion (the name
+    the TPU compiler gives a gather). The shape it replaced,
+    ``take(x, perm)[:100]``, keeps them, two for an int64 column: the
+    compiler does not push a slice through a gather, which is the rule
+    the executor's top-N rests on. The sort is left out of both: it
+    costs the compiler 70-90 s at any size."""
+    import re
+    import types
+
+    import jax
+
+    from nds_tpu.engine.device_exec import DCtx, DVal, _Trace
+
+    def after_sort(perm, present_s, okey, price, date, date_ok):
+        tr = _Trace(types.SimpleNamespace(), {})
+        ctx = DCtx(LINEITEM, None)
+        ctx.cols = {("l", "okey"): DVal(okey), ("l", "price"): DVal(price),
+                    ("l", "date"): DVal(date, date_ok)}
+        if sliced_first:
+            out = tr._gather(ctx, perm[:100])
+        else:
+            out = tr._gather(ctx, perm)
+        return present_s[:100], [
+            (dv.arr[:100], None if dv.valid is None else dv.valid[:100])
+            for dv in out.cols.values()]
+
+    n = LINEITEM
+    args = [_sds((n,), "int32", one_chip), _sds((n,), "bool", one_chip),
+            _sds((n,), "int64", one_chip), _sds((n,), "int64", one_chip),
+            _sds((n,), "int32", one_chip), _sds((n,), "bool", one_chip)]
+    text = jax.jit(after_sort).lower(*args).compile().as_text()
+    custom = [int(r) for r in re.findall(
+        r"= \w+\[(\d+)\]\S* fusion\(.*kind=kCustom", text)]
+    assert custom, "no kCustom fusion: the compiler's naming has moved"
+    if sliced_first:
+        assert max(custom) <= 100, custom
+    else:
+        # int64 okey and price: two 32-bit gathers each; date; its flags
+        assert custom.count(n) >= 5, custom
