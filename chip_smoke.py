@@ -467,7 +467,6 @@ def multichip_phase(devices) -> None:
     dist_cfg = EngineConfig(
         os.path.join(HERE, "configs", "power_run_distributed.template"),
         None, {"engine.backend": "distributed",
-               "engine.mesh.shards": str(len(devices)),
                # a sharded query must END sharded: no ladder below it
                "engine.placement.floor": "sharded"})
     with phase("load"):

@@ -830,15 +830,20 @@ class DeviceExecutor:
         self._upload_bytes += int(getattr(arr, "nbytes", 0))
         return place(arr)
 
-    def _launch(self, tracer, kind: str, compiled, *args) -> tuple:
+    def _launch(self, tracer, kind: str, compiled, *args,
+                attrs: "dict | None" = None) -> tuple:
         """``device.launch``: bill the executable's compiler-truth cost
         (obs/costs; memoized, so before the execute bracket opens and
         never inside ``device.run``) and make the compiled call.
+        ``attrs``: what else the caller knows of the program (the
+        sharded executor's exchange totals).
         -> (perf_counter at the call, what it returned)."""
         import time as _time
         cost = obs_costs.record_program(kind, compiled) or {}
-        attrs = {k: cost[k] for k in ("bytes_accessed", "flops")
-                 if k in cost}
+        attrs = dict(attrs) if attrs else {}
+        for k in ("bytes_accessed", "flops"):
+            if k in cost:
+                attrs[k] = cost[k]
         if "bytes_accessed" in attrs:
             obs_metrics.counter("program_bytes_accessed_total").inc(
                 attrs["bytes_accessed"])
@@ -850,6 +855,20 @@ class DeviceExecutor:
             with jitsan.dispatch(kind):
                 out = compiled(*args)
         return t1, out
+
+    def _readback(self, tracer, devs, describe=None):
+        """``device.readback``: ONE blocking device->host transfer of a
+        program's outputs, counted where it is made. ``describe(host)``
+        gives further attributes read off what came back (the sharded
+        program's overflow and skew scalars)."""
+        with tracer.span("device.readback") as rb:
+            host = jax.device_get(devs)
+            nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(host))
+            rb.set(syncs=1, bytes=nbytes,
+                   **(describe(host) if describe else {}))
+        obs_metrics.counter("device_readbacks_total").inc()
+        obs_metrics.counter("readback_bytes_total").inc(nbytes)
+        return host
 
     def _attach_compression(self, timings: dict, bufs: dict) -> None:
         """Per-query compression accounting (nds_tpu/columnar/):
@@ -1114,10 +1133,10 @@ class DeviceExecutor:
                     tracer, "compact", cf, row_d, outs_d)
             # every blocking device->host transfer of the statement is
             # made, and counted, here
-            with tracer.span("device.readback") as rb:
-                if compact:
+            if compact:
+                with tracer.span("device.readback") as rb:
                     cnt_h, overflow_h = jax.device_get((cnt_d, overflow_d))
-                    syncs, nbytes = 1, cnt_h.nbytes
+                    syncs, nbytes = 1, cnt_h.nbytes + overflow_h.nbytes
                     row_h = outs_h = None
                     if int(overflow_h) == 0:
                         C = 1
@@ -1127,18 +1146,15 @@ class DeviceExecutor:
                         row_h, outs_h = jax.device_get(
                             (row2[:C], [(a[:C], v[:C]) for a, v in outs2]))
                         syncs = 2
-                else:
-                    row_h, outs_h, overflow_h = jax.device_get(devs)
-                    syncs, nbytes = 1, 0
-                nbytes += overflow_h.nbytes
-                if row_h is not None:
-                    nbytes += row_h.nbytes + sum(
-                        a.nbytes + v.nbytes for a, v in outs_h)
-                rb.set(syncs=syncs, bytes=nbytes)
+                        nbytes += row_h.nbytes + sum(
+                            a.nbytes + v.nbytes for a, v in outs_h)
+                    rb.set(syncs=syncs, bytes=nbytes)
+                obs_metrics.counter("device_readbacks_total").inc(syncs)
+                obs_metrics.counter("readback_bytes_total").inc(nbytes)
+            else:
+                row_h, outs_h, overflow_h = self._readback(tracer, devs)
             # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span via begin(t0=t1).end(t=t2)
             t2 = _time.perf_counter()
-            obs_metrics.counter("device_readbacks_total").inc(syncs)
-            obs_metrics.counter("readback_bytes_total").inc(nbytes)
             if int(overflow_h) == 0:
                 # the execute bracket closed at t2 (device_get blocks
                 # until ready); record it as a span with the measured

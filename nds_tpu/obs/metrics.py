@@ -27,7 +27,10 @@ Metric names in use across the stack (documented in README
   Session's text -> plan cache
 - ``staged_subprograms_total`` — host-staged plan splitting
 - ``exchanges_traced_total`` / ``exchange_overflow_retries_total`` /
-  ``exchange_overflow_rows_total`` — distributed exchange
+  ``exchange_overflow_rows_total`` — distributed exchange;
+  ``exchange_rows_total`` / ``exchange_bytes_total`` — bucket capacity
+  and bytes one chip hands to all_to_all, added at every launch of a
+  sharded program (the same numbers ride its ``device.launch`` span)
 - ``chunk_scans_total`` / ``chunk_fallbacks_total`` /
   ``chunk_shrink_total`` — out-of-core executor
 - ``task_failures_total`` — TaskFailureCollector bridge

@@ -23,6 +23,7 @@ silent (utils.report.TaskFailureCollector records the retry).
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 import numpy as np
@@ -32,15 +33,16 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P_
 
-from nds_tpu.analysis import jitsan, locksan
+from nds_tpu.analysis import locksan
 from nds_tpu.engine import device_exec as dx
 from nds_tpu.engine.device_exec import DCtx, DVal, DeviceExecError, _ok
 from nds_tpu.io.host_table import HostTable
-from nds_tpu.obs import costs as obs_costs
 from nds_tpu.obs import memwatch
 from nds_tpu.obs import metrics as obs_metrics
 from nds_tpu.obs.trace import get_tracer
-from nds_tpu.parallel.exchange import exchange, exchange_hierarchical
+from nds_tpu.parallel.exchange import (
+    exchange, exchange_hierarchical, exchange_trace,
+)
 from nds_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, make_mesh, pad_to_multiple,
 )
@@ -65,6 +67,14 @@ def _pextreme(op, x, axes):
     scaled-int64 decimals and the overflow count are all s64). Same
     value on every platform."""
     return op(lax.all_gather(x, axes))
+
+# what a program's trace leaves beside its executable, persisted with
+# it (plan cache) so that a loaded program says the same of itself
+_SIDE_KEYS = ("dicts", "kernels", "ops_est", "exchange")
+
+# a group-by exchange whose key can take fewer values than this many a
+# device is sized at the local row count (`_DistTrace._run_aggregate`)
+FEW_KEYS_A_DEVICE = 4
 
 # tables at or above this row count shard across the mesh; smaller ones
 # replicate (the Spark broadcast threshold analog, but by rows)
@@ -164,6 +174,21 @@ class DistributedExecutor(dx.DeviceExecutor):
         from nds_tpu.parallel.multihost import make_global_array
         return make_global_array(self.mesh, spec, np.asarray(arr))
 
+    def _place(self, arr, sharded: bool):
+        """One counted host->device placement on the mesh (the
+        ``device.bind`` span's ``uploads`` / ``upload_bytes``)."""
+        return self._to_device(arr, lambda a: self._dev(a, sharded))
+
+    def _launch(self, tracer, kind, compiled, *args, attrs=None):
+        """``device.launch`` of a sharded program: its exchange totals
+        ride the span, and count."""
+        if attrs:
+            obs_metrics.counter("exchange_rows_total").inc(
+                attrs["exchange_rows"])
+            obs_metrics.counter("exchange_bytes_total").inc(
+                attrs["exchange_bytes"])
+        return super()._launch(tracer, kind, compiled, *args, attrs=attrs)
+
     # buffers: sharded tables pad to a multiple of n_dev
     def _upload(self, bufs: dict, table: str, name: str) -> None:
         key = f"{table}.{name}"
@@ -181,11 +206,11 @@ class DistributedExecutor(dx.DeviceExecutor):
                 if col.null_mask is not None:
                     m = np.concatenate(
                         [col.null_mask, np.zeros(pad, dtype=bool)])
-                    self._buffers[key + "#v"] = self._dev(m, True)
+                    self._buffers[key + "#v"] = self._place(m, True)
             elif col.null_mask is not None:
-                self._buffers[key + "#v"] = self._dev(
+                self._buffers[key + "#v"] = self._place(
                     col.null_mask, False)
-            self._buffers[key] = self._dev(vals, sharded)
+            self._buffers[key] = self._place(vals, sharded)
         bufs[key] = self._buffers[key]
         if key + "#v" in self._buffers:
             bufs[key + "#v"] = self._buffers[key + "#v"]
@@ -209,7 +234,7 @@ class DistributedExecutor(dx.DeviceExecutor):
                 if pad:
                     live = np.concatenate(
                         [live, np.zeros(pad, dtype=bool)])
-            self._buffers[key] = self._dev(live, sharded)
+            self._buffers[key] = self._place(live, sharded)
         bufs[key] = self._buffers[key]
 
     def _compile(self, planned: P.PlannedQuery):
@@ -218,22 +243,23 @@ class DistributedExecutor(dx.DeviceExecutor):
         def make(slack):
             def fn(shard_bufs, repl_bufs):
                 tr = _DistTrace(self, {**shard_bufs, **repl_bufs}, slack)
-                # collect per-shuffle destination-skew ratios at trace
-                # time (parallel/exchange.skew_trace): the program
-                # returns the worst one so the executor can publish
-                # the exchange_skew_ratio gauge host-side — an output,
-                # not a debug callback, so the executable still
-                # serializes into the AOT plan cache
-                from nds_tpu.parallel.exchange import skew_trace
-                with skew_trace() as skews:
+                # what the program's shuffles are is collected at trace
+                # time (parallel/exchange.exchange_trace): the static
+                # totals ride `side` to the device.launch span, and the
+                # program returns the worst destination skew so the
+                # executor can publish the exchange_skew_ratio gauge
+                # host-side — an output, not a debug callback, so the
+                # executable still serializes into the AOT plan cache
+                with exchange_trace() as xt:
                     row, outs, dicts = tr.run_query(planned)
                 side["dicts"] = dicts
                 side["kernels"] = tr.kernel_counts()
                 side["ops_est"] = int(tr.ops_est)
+                side["exchange"] = xt.stats()
                 overflow = tr.total_overflow()
-                if skews:
-                    skew = skews[0]
-                    for s in skews[1:]:
+                if xt.skews:
+                    skew = xt.skews[0]
+                    for s in xt.skews[1:]:
                         skew = jnp.maximum(skew, s)
                     # every device sees every exchange; the fleet-wide
                     # worst is the gauge's value
@@ -296,7 +322,7 @@ class DistributedExecutor(dx.DeviceExecutor):
         # the hit/miss verdict is counted HERE, after the sharded
         # key-split compat check load_cached cannot run itself
         with tracer.span("cache.load", fp=fp[:12]):
-            bufs = self._collect_buffers(planned)
+            bufs, _pvals = self._bind(planned, tracer)
             hit = cache_aot.load_cached(
                 pc, fp, type(self).__name__, timings, count=False,
                 devices=self.mesh.devices.flat)
@@ -319,9 +345,8 @@ class DistributedExecutor(dx.DeviceExecutor):
         if not ok:
             return False
         state["jitted"], state["sk"], state["rk"] = compiled, sk, rk
-        side["dicts"] = extra.get("dicts")
-        side["kernels"] = extra.get("kernels")
-        side["ops_est"] = extra.get("ops_est")
+        for k in _SIDE_KEYS:
+            side[k] = extra.get(k)
         return True
 
     def _persist_sharded(self, planned, slack, state, side) -> None:
@@ -331,9 +356,7 @@ class DistributedExecutor(dx.DeviceExecutor):
             cache_aot.persist(pc, fp, type(self).__name__,
                               state["jitted"],
                               {"sk": state["sk"], "rk": state["rk"],
-                               "dicts": side.get("dicts"),
-                               "kernels": side.get("kernels"),
-                               "ops_est": side.get("ops_est")},
+                               **{k: side.get(k) for k in _SIDE_KEYS}},
                               meta={"slack": slack},
                               devices=self.mesh.devices.flat)
 
@@ -443,11 +466,8 @@ class DistributedExecutor(dx.DeviceExecutor):
         self.last_query_span = qspan or None
         return out
 
-    def _execute_traced(self, planned, orig, key, tracer):
-        import time as _time
-        planned = self._staged_effective(planned, key)
-        timings = {"compile_ms": 0.0}
-        self.last_timings = timings
+    def _entry(self, planned, orig, key) -> tuple:
+        """The compile-cache entry of ``key`` (LRU, bounded)."""
         if key not in self._compiled:
             while len(self._compiled) >= self.MAX_COMPILED:
                 old = next(iter(self._compiled))
@@ -464,116 +484,140 @@ class DistributedExecutor(dx.DeviceExecutor):
         else:
             # LRU refresh: move the hit to the back of the dict order
             self._compiled[key] = self._compiled.pop(key)
-        (build, side), state, _ref = self._compiled[key]
-        slack = state.get("slack", self.slack)
-        # the ad-hoc `for attempt in range(3)` slack loop, generalized
-        # onto the shared resilience policy (no backoff sleep: each
-        # retry already pays a full recompile; policy built by the
-        # pipeline module — the single home of engine retry wiring)
+        return self._compiled[key]
+
+    def _compile_or_load_sharded(self, planned, slack, build, state, side,
+                         timings, tracer, retry: bool) -> None:
+        """Fill ``state['jitted'/'sk'/'rk']`` for this slack: a verified
+        plan-cache hit (zero compiles this process, ``cache_load_ms``
+        carries the deserialize cost) or a compile through the one
+        funnel, persisted for the next process."""
+        import gc
+        import time as _time
+        from nds_tpu.cache import aot as cache_aot
+        # free the previous slack's executable BEFORE compiling the
+        # bigger one: the 8-way compiled forms of wide plans are GBs
+        # each, and holding both was the difference between fitting
+        # and OOM on the virtual mesh (q72's slack-2 -> slack-4 retry)
+        state.pop("jitted", None)
+        gc.collect()
+        if not self._load_cached_sharded(planned, slack, state, side,
+                                         timings, tracer):
+            # ndslint: waive[NDS102] -- raw bracket feeds compile_ms; the span records it too
+            t0 = _time.perf_counter()
+            with tracer.span("device.compile", slack=slack):
+                jitted, state["sk"], state["rk"] = build(slack)
+                bufs, _pvals = self._bind(planned, tracer)
+                # AOT-compile (single-chip contract): compile cost is
+                # attributed apart from the execute bracket, not hidden
+                # in the first timed call
+                state["jitted"] = cache_aot.lower_and_compile(
+                    jitted,
+                    {k: bufs[k] for k in state["sk"]},
+                    {k: bufs[k] for k in state["rk"]},
+                    fresh=cache_aot.fresh_for(*state.get(
+                        "cache_handle", (None, None))),
+                    kind=type(self).__name__)
+            timings["compile_ms"] += (
+                # ndslint: waive[NDS102,NDS103] -- .compile() is synchronous; bracket ends when it returns, no device work is in flight here
+                _time.perf_counter() - t0) * 1000
+            obs_metrics.counter("recompiles_total" if retry
+                                else "compiles_total").inc()
+            self._persist_sharded(planned, slack, state, side)
+        state["slack"] = slack
+
+    def _execute_traced(self, planned, orig, key, tracer):
+        """The statement's span tree is the single-device one
+        (``device.dispatch`` > ``device.bind`` / ``device.launch``,
+        then ``device.readback``, ``device.materialize``,
+        ``device.finish``), through the same helpers; an exchange
+        overflow goes round again at doubled slack, a recompile."""
+        import time as _time
+        # the slack loop rides the shared resilience policy (no backoff
+        # sleep: each retry already pays a full recompile; policy built
+        # by the pipeline module — the single home of engine retry
+        # wiring)
         from nds_tpu.engine.scheduler import adaptive_policy
+        kind = type(self).__name__
+        timings = {"compile_ms": 0.0}
+        self.last_timings = timings
+        entry = None
         for attempt in adaptive_policy(3).attempts():
-            if "jitted" not in state or state.get("slack") != slack:
-                # free the previous slack's executable BEFORE compiling
-                # the bigger one: the 8-way compiled forms of wide
-                # plans are GBs each, and holding both was the
-                # difference between fitting and OOM on the virtual
-                # mesh (q72's slack-2 -> slack-4 retry)
-                state.pop("jitted", None)
-                import gc
-                gc.collect()
-                if self._load_cached_sharded(planned, slack, state,
-                                             side, timings, tracer):
-                    # persisted AOT hit: zero compiles this process
-                    # (compile_ms stays 0; cache_load_ms carries the
-                    # deserialize cost)
-                    state["slack"] = slack
-                else:
-                    from nds_tpu.cache import aot as cache_aot
-                    # ndslint: waive[NDS102] -- raw bracket feeds compile_ms; the span records it too
-                    t0 = _time.perf_counter()
-                    with tracer.span("device.compile", slack=slack):
-                        jitted, state["sk"], state["rk"] = build(slack)
-                        bufs = self._collect_buffers(planned)
-                        # AOT-compile (single-chip contract): compile
-                        # cost must be attributed separately from the
-                        # execute bracket, not hidden in the first
-                        # timed call
-                        state["jitted"] = cache_aot.lower_and_compile(
-                            jitted,
-                            {k: bufs[k] for k in state["sk"]},
-                            {k: bufs[k] for k in state["rk"]},
-                            fresh=cache_aot.fresh_for(*state.get(
-                                "cache_handle", (None, None))),
-                            kind=type(self).__name__)
-                    state["slack"] = slack
-                    timings["compile_ms"] += (
-                        # ndslint: waive[NDS102] -- .compile() is synchronous; bracket ends when it returns
-                        _time.perf_counter() - t0) * 1000
-                    obs_metrics.counter(
-                        "compiles_total" if attempt == 0
-                        else "recompiles_total").inc()
-                    self._persist_sharded(planned, slack, state, side)
-            bufs = self._collect_buffers(planned)
-            shard_bufs = {k: bufs[k] for k in state["sk"]}
-            repl_bufs = {k: bufs[k] for k in state["rk"]}
-            timings["bytes_scanned"] = float(
-                sum(b.nbytes for b in bufs.values()))
-            self._attach_delta(timings, planned)
-            obs_metrics.counter("device_executions_total").inc()
-            obs_metrics.counter("bytes_scanned_total").inc(
-                timings["bytes_scanned"])
-            # memory HWM (obs/memwatch): accounted scan bytes go live
-            # for this attempt; device stats dominate when available.
-            # __live_bytes is the pop-once release token (a failure
-            # after an inline release must not release twice)
-            memwatch.add_live(timings["bytes_scanned"])
-            timings["__live_bytes"] = timings["bytes_scanned"]
-            memwatch.sample_device()
-            # compiler-truth cost billing (obs/costs): per dispatch,
-            # outside the execute bracket
-            obs_costs.record_program(type(self).__name__,
-                                     state["jitted"])
-            # ndslint: waive[NDS102] -- execute bracket start; closed below after device_get
-            t1 = _time.perf_counter()
-            # one collective program in flight per process: two host
-            # threads launching multi-device programs can enqueue them
-            # on the devices in different orders, and the collectives
-            # then wait on each other for ever
-            with _DISPATCH_LOCK:
-                with jitsan.dispatch(type(self).__name__):
-                    row, outs, overflow, skew = state["jitted"](
-                        shard_bufs, repl_bufs)
+            with contextlib.ExitStack() as in_flight:
+                with tracer.span("device.dispatch"):
+                    if entry is None:
+                        planned = self._staged_effective(planned, key)
+                        entry = self._entry(planned, orig, key)
+                        (build, side), state, _ref = entry
+                        slack = state.get("slack", self.slack)
+                    if ("jitted" not in state
+                            or state.get("slack") != slack):
+                        self._compile_or_load_sharded(
+                            planned, slack, build, state, side, timings,
+                            tracer, retry=attempt > 0)
+                    bufs, _pvals = self._bind(planned, tracer)
+                    timings["bytes_scanned"] = float(
+                        sum(b.nbytes for b in bufs.values()))
+                    self._attach_delta(timings, planned)
+                    obs_metrics.counter("device_executions_total").inc()
+                    obs_metrics.counter("bytes_scanned_total").inc(
+                        timings["bytes_scanned"])
+                    # memory HWM (obs/memwatch): accounted scan bytes go
+                    # live for this attempt; device stats dominate when
+                    # available. __live_bytes is the pop-once release
+                    # token (a failure after an inline release must not
+                    # release twice)
+                    memwatch.add_live(timings["bytes_scanned"])
+                    timings["__live_bytes"] = timings["bytes_scanned"]
+                    memwatch.sample_device()
+                    # one collective program in flight per process: two
+                    # host threads launching multi-device programs can
+                    # enqueue them on the devices in different orders,
+                    # and the collectives then wait on each other for
+                    # ever. Held from the launch to the end of the
+                    # read-back, the one arrangement with a record on
+                    # four chips (PERF.md section 7); compiles and binds
+                    # stay outside it
+                    in_flight.enter_context(_DISPATCH_LOCK)
+                    t1, devs = self._launch(
+                        tracer, kind, state["jitted"],
+                        {k: bufs[k] for k in state["sk"]},
+                        {k: bufs[k] for k in state["rk"]},
+                        attrs=side.get("exchange"))
                 # one batched device->host round trip (see
                 # DeviceExecutor)
-                row_h, outs_h, overflow_h, skew_h = jax.device_get(
-                    (row, outs, overflow, skew))
+                row_h, outs_h, overflow_h, skew_h = self._readback(
+                    tracer, devs, describe=lambda host: {
+                        "overflow_rows": int(host[2]),
+                        "skew": float(host[3])})
+            # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span
+            t2 = _time.perf_counter()
             if float(skew_h) > 0:
                 # worst per-shuffle destination skew this program saw:
                 # visible in live snapshots before it becomes a
                 # straggler (README "Fleet & profiling")
                 obs_metrics.gauge("exchange_skew_ratio").set(
                     round(float(skew_h), 4))
-            # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span
-            t2 = _time.perf_counter()
-            if int(overflow_h) == 0:
+            n_over = int(overflow_h)
+            if n_over == 0:
                 tracer.begin("device.run", t0=t1).end(t=t2)
                 with tracer.span("device.materialize"):
                     out = self._materialize(planned, row_h, outs_h,
                                             side)
                 # ndslint: waive[NDS102] -- host materialize endpoint bracketed by the device.materialize span
                 t3 = _time.perf_counter()
-                memwatch.sample_device()
-                memwatch.sub_live(timings.pop("__live_bytes", 0.0))
-                timings["execute_ms"] = (t2 - t1) * 1000
-                timings["materialize_ms"] = (t3 - t2) * 1000
-                if side.get("ops_est"):
-                    timings["ops_est"] = float(side["ops_est"])
-                if side.get("kernels"):
-                    timings["__kernels"] = dict(side["kernels"])
-                self._finalize_timings(timings, key)
+                with tracer.span("device.finish"):
+                    memwatch.sample_device()
+                    memwatch.sub_live(timings.pop("__live_bytes", 0.0))
+                    timings["execute_ms"] = (t2 - t1) * 1000
+                    timings["materialize_ms"] = (t3 - t2) * 1000
+                    if side.get("ops_est"):
+                        timings["ops_est"] = float(side["ops_est"])
+                    if side.get("kernels"):
+                        timings["__kernels"] = dict(side["kernels"])
+                    self._finalize_timings(timings, key)
                 return out, timings
             memwatch.sub_live(timings.pop("__live_bytes", 0.0))
-            n_over = int(overflow_h)
             TaskFailureCollector.notify(
                 f"exchange overflow ({n_over} rows) at slack="
                 f"{slack}; retrying with slack={slack * 2}")
@@ -621,9 +665,13 @@ class _DistTrace(dx._Trace):
         out.sharded = False
         return out
 
-    def _exchange_ctx(self, ctx: DCtx, key, kok) -> tuple[DCtx, object]:
+    def _exchange_ctx(self, ctx: DCtx, key, kok,
+                      slack: "float | None" = None) -> tuple[DCtx, object]:
         """Repartition a sharded ctx by an int64 key; returns (ctx', key')
-        both with capacity ctx.n * slack (rows colocated by key hash)."""
+        both with capacity ctx.n * slack (rows colocated by key hash).
+        ``slack``: this exchange's own, where the program's would not
+        do (`_run_aggregate`)."""
+        slack = self.slack if slack is None else slack
         names = list(ctx.cols)
         arrays = [ctx.cols[k].arr for k in names]
         valids = [ctx.cols[k].valid for k in names]
@@ -633,11 +681,11 @@ class _DistTrace(dx._Trace):
         if self.ex.mesh_2d:
             outs, out_ok, n_over = exchange_hierarchical(
                 payload, key, ok, self.ex.n_hosts, self.ex.n_lanes,
-                self.slack, HOST_AXIS, DATA_AXIS,
+                slack, HOST_AXIS, DATA_AXIS,
                 key_index=len(payload) - 1)
         else:
             outs, out_ok, n_over = exchange(payload, key, ok,
-                                            self.n_dev, self.slack)
+                                            self.n_dev, slack)
         self._overflows.append(n_over)
         out_arrays = outs[:len(names)]
         vout = outs[len(names):-1]
@@ -656,19 +704,24 @@ class _DistTrace(dx._Trace):
 
     def _key_of(self, ctx: DCtx, exprs) -> tuple:
         """Pack a list of key exprs into one int64 per row (bounds
-        required beyond the first key), plus validity."""
+        required beyond the first key), plus validity, plus how many
+        distinct values the key can take by its static bounds (None:
+        not known)."""
         vals = [self.eval(e, ctx) for e in exprs]
         ok = ctx.row
         for v in vals:
             ok = _ok(v, ok)
+        bounds = [(0, max(len(v.sdict) - 1, 0)) if v.sdict is not None
+                  else (v.lo, v.hi) for v in vals]
+        card = 1
+        for lo, hi in bounds:
+            card = (None if card is None or lo is None or hi is None
+                    else card * (hi - lo + 1))
         if len(vals) == 1:
-            return vals[0].arr.astype(jnp.int64), ok
+            return vals[0].arr.astype(jnp.int64), ok, card
         parts = []
         widths = []
-        for v in vals:
-            lo, hi = v.lo, v.hi
-            if v.sdict is not None:
-                lo, hi = 0, max(len(v.sdict) - 1, 0)
+        for v, (lo, hi) in zip(vals, bounds):
             if lo is None or hi is None:
                 raise DeviceExecError("cannot pack key without bounds")
             parts.append((v.arr, lo, hi))
@@ -679,7 +732,7 @@ class _DistTrace(dx._Trace):
         for (arr, lo, hi), w in zip(parts, widths):
             norm = jnp.clip(arr.astype(jnp.int64) - lo, 0, hi - lo)
             acc = norm if acc is None else ((acc << w) | norm)
-        return acc, ok
+        return acc, ok, card
 
     # ---------------------------------------------------------- plan nodes
 
@@ -843,7 +896,8 @@ class _DistTrace(dx._Trace):
         # repartition by group key so each group is wholly local, then the
         # single-device aggregate is exact (distinct/avg included)
         try:
-            key, kok = self._key_of(ctx, [e for _, e in node.group_keys])
+            key, kok, card = self._key_of(
+                ctx, [e for _, e in node.group_keys])
         except DeviceExecError:
             self.stash(node.child, self._replicate(ctx))
             self._cache.pop(id(node), None)
@@ -853,7 +907,17 @@ class _DistTrace(dx._Trace):
         # NULL group keys: kok False would keep rows home — fine, they
         # still form their own (local) group only if all-null; TPC group
         # keys are non-null so route by key, keep row presence as-is
-        new, _ = self._exchange_ctx(ctx, key, ctx.row)
+        slack = None
+        if card is not None and card < FEW_KEYS_A_DEVICE * self.n_dev:
+            # hashing cannot balance a handful of keys: every row of a
+            # key goes to one chip, and with fewer keys than a few a
+            # chip one destination can be sent most of a chip's rows
+            # (NDS-H q1 at SF1: four groups on four chips, 99 % of the
+            # rows to one). A bucket of slack 2 then overflows by
+            # construction and the program compiles twice; a bucket of
+            # the local row count cannot overflow
+            slack = max(self.slack, float(self.n_dev))
+        new, _ = self._exchange_ctx(ctx, key, ctx.row, slack)
         self.stash(node.child, new)
         self._cache.pop(id(node), None)
         out = super()._run_aggregate(node)

@@ -17,6 +17,7 @@ recompile, never silent).
 from __future__ import annotations
 
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -25,27 +26,46 @@ from jax import lax
 from nds_tpu.obs import metrics as obs_metrics
 from nds_tpu.parallel.mesh import DATA_AXIS
 
-# trace-time skew collector: while a sink is active (the distributed
+# trace-time collector: while a sink is active (the distributed
 # executor's program build opens one around run_query), every
 # exchange_by_dest appends its per-shuffle destination-skew ratio
 # (max/mean destination rows, a TRACED scalar) so the program can
 # return the worst skew alongside the overflow count and the executor
-# can publish the ``exchange_skew_ratio`` gauge host-side. NOT a
-# debug callback on purpose: callback-bearing executables cannot
-# serialize into the persistent AOT plan cache (PyCapsule pickling).
-_SKEW_SINK: "list | None" = None
+# can publish the ``exchange_skew_ratio`` gauge host-side, and adds
+# what the shuffle moves (static, from its shapes) to the program's
+# totals. NOT a debug callback on purpose: callback-bearing executables
+# cannot serialize into the persistent AOT plan cache (PyCapsule
+# pickling).
+_SINK: "ExchangeTrace | None" = None
+
+
+class ExchangeTrace:
+    """What one program's trace says of its exchanges: the traced skew
+    scalars, and three static totals. ``rows`` is the bucket capacity a
+    chip sends (``n_dev x bucket``) and ``nbytes`` the bytes it hands
+    to ``all_to_all`` (every payload array and the ``ok`` mask at that
+    capacity), each summed over the program's exchanges: what ONE chip
+    moves in ONE run of the program."""
+
+    def __init__(self):
+        self.skews: list = []
+        self.count = self.rows = self.nbytes = 0
+
+    def stats(self) -> dict:
+        """The ``device.launch`` attributes of a sharded program."""
+        return {"exchanges": self.count, "exchange_rows": self.rows,
+                "exchange_bytes": self.nbytes}
 
 
 @contextlib.contextmanager
-def skew_trace():
-    """Collect per-shuffle skew ratios appended during one program
-    trace; yields the list the traced scalars land in."""
-    global _SKEW_SINK
-    prev, _SKEW_SINK = _SKEW_SINK, []
+def exchange_trace():
+    """Collect the exchanges traced during one program build."""
+    global _SINK
+    prev, _SINK = _SINK, ExchangeTrace()
     try:
-        yield _SKEW_SINK
+        yield _SINK
     finally:
-        _SKEW_SINK = prev
+        _SINK = prev
 
 
 def _mix64(x):
@@ -112,7 +132,12 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     bounds = jnp.searchsorted(dest_s,
                               jnp.arange(n_dev + 1, dtype=jnp.int32))
     first_of_dest = bounds[:-1]
-    if _SKEW_SINK is not None:
+    if _SINK is not None:
+        capacity = n_dev * bucket
+        _SINK.count += 1
+        _SINK.rows += capacity
+        _SINK.nbytes += capacity * (1 + sum(    # 1: the bool ok mask
+            a.dtype.itemsize * math.prod(a.shape[1:]) for a in arrays))
         # partition-skew visibility (README "Fleet & profiling"):
         # max/mean valid rows per destination for THIS shuffle — the
         # signal that a key distribution is loading one device before
@@ -125,7 +150,7 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
             total > 0,
             jnp.max(counts) / jnp.maximum(total / n_dev, 1e-9),
             jnp.float32(1.0))
-        _SKEW_SINK.append(ratio)
+        _SINK.skews.append(ratio)
     rank = iota - jnp.take(first_of_dest,
                            jnp.clip(dest_s, 0, n_dev - 1))
     overflow = ok_s & (rank >= bucket)

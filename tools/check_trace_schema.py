@@ -137,7 +137,12 @@ def _num(v) -> bool:
 # duplicated by value: this validator stays runnable standalone): span
 # name -> the parents it may hang from in a statement's tree. A name
 # not listed here (sql.*, stage.*, cache.load, compile.*, load.*,
-# chunk.*, exchange.*, a tool's own) may hang anywhere.
+# chunk.*, exchange.*, a tool's own) may hang anywhere. A sharded
+# statement (dist_exec) has the single-device tree: its retry at doubled
+# slack adds a second device.dispatch / device.readback pair under the
+# same device.execute. Attributes the catalogue names beyond the README's
+# table: device.launch carries exchanges / exchange_rows / exchange_bytes
+# for a sharded program, device.readback overflow_rows / skew.
 _ROOTS = ("stmt", "query")
 SPAN_PARENTS = {
     "sched.place": _ROOTS,
