@@ -41,7 +41,7 @@ from nds_tpu.obs import memwatch
 from nds_tpu.obs import metrics as obs_metrics
 from nds_tpu.obs.trace import get_tracer
 from nds_tpu.parallel.exchange import (
-    exchange, exchange_hierarchical, exchange_trace,
+    exchange, exchange_hierarchical, exchange_trace, hash_columns,
 )
 from nds_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, make_mesh, pad_to_multiple,
@@ -629,12 +629,38 @@ class DistributedExecutor(dx.DeviceExecutor):
         raise DeviceExecError("exchange overflow persisted after retries")
 
 
+def _rows(ctx: DCtx) -> int:
+    """The static bound a relation carries on the rows ONE device holds
+    of it (``_DistTrace._stamp``); its capacity where nothing set one."""
+    return getattr(ctx, "rows", ctx.n)
+
+
 class _DistTrace(dx._Trace):
+    """The sharded trace. Beside ``sharded`` every relation it makes
+    carries ``rows``: how many rows a device is expected to hold of it,
+    whatever buffers it came through. A scan sets it to the shard's
+    local row count; filters, projections, semi-joins and joins against
+    a unique build side keep the probe's; an exchange keeps it too (a
+    hash partition moves rows, it makes none) and sizes its buckets
+    from it, so capacity stays ``slack x rows`` after any number of
+    exchanges. Only this class reads the attribute."""
+
     def __init__(self, ex: DistributedExecutor, bufs: dict,
                  slack: float):
         super().__init__(ex, bufs, slack)
         self.n_dev = ex.n_dev
         self.axes = ex.axes
+
+    @staticmethod
+    def _stamp(out: DCtx, sharded: bool, rows: int) -> DCtx:
+        out.sharded = sharded
+        out.rows = min(rows, out.n)
+        return out
+
+    def _carry(self, out: DCtx, src: DCtx) -> DCtx:
+        """``out`` holds (a subset of) ``src``'s rows, laid out as they
+        are."""
+        return self._stamp(out, getattr(src, "sharded", False), _rows(src))
 
     def total_overflow(self):
         """Join-expansion + exchange overflow total (both append to
@@ -662,16 +688,21 @@ class _DistTrace(dx._Trace):
             valid = (None if dv.valid is None
                      else lax.all_gather(dv.valid, self.axes, tiled=True))
             out.cols[k] = dv.with_arrays(arr, valid)
-        out.sharded = False
-        return out
+        return self._stamp(out, False, _rows(ctx) * self.n_dev)
 
     def _exchange_ctx(self, ctx: DCtx, key, kok,
-                      slack: "float | None" = None) -> tuple[DCtx, object]:
+                      few_keys: bool = False) -> tuple[DCtx, object]:
         """Repartition a sharded ctx by an int64 key; returns (ctx', key')
-        both with capacity ctx.n * slack (rows colocated by key hash).
-        ``slack``: this exchange's own, where the program's would not
-        do (`_run_aggregate`)."""
-        slack = self.slack if slack is None else slack
+        both with capacity ``slack x rows`` of ctx (rows colocated by key
+        hash): the buckets are sized from the rows a device holds, not
+        from the buffer they sit in. ``few_keys``: the exchange of
+        `_run_aggregate` that must not overflow, sized so that a device
+        can send ALL its slots to one peer."""
+        slack, rows = self.slack, _rows(ctx)
+        if few_keys:
+            slack, rows = max(self.slack, float(self.n_dev)), ctx.n
+        elif rows < ctx.n:
+            self._note("exchange.by_rows")
         names = list(ctx.cols)
         arrays = [ctx.cols[k].arr for k in names]
         valids = [ctx.cols[k].valid for k in names]
@@ -682,10 +713,10 @@ class _DistTrace(dx._Trace):
             outs, out_ok, n_over = exchange_hierarchical(
                 payload, key, ok, self.ex.n_hosts, self.ex.n_lanes,
                 slack, HOST_AXIS, DATA_AXIS,
-                key_index=len(payload) - 1)
+                key_index=len(payload) - 1, rows=rows)
         else:
             outs, out_ok, n_over = exchange(payload, key, ok,
-                                            self.n_dev, slack)
+                                            self.n_dev, slack, rows=rows)
         self._overflows.append(n_over)
         out_arrays = outs[:len(names)]
         vout = outs[len(names):-1]
@@ -699,18 +730,24 @@ class _DistTrace(dx._Trace):
                 valid = vout[vi]
                 vi += 1
             new.cols[k] = dv.with_arrays(out_arrays[i], valid)
-        new.sharded = True
-        return new, out_key
+        # after a few-keys exchange one device may hold every row
+        return self._stamp(new, True, new.n if few_keys else rows), out_key
 
     def _key_of(self, ctx: DCtx, exprs) -> tuple:
-        """Pack a list of key exprs into one int64 per row (bounds
-        required beyond the first key), plus validity, plus how many
-        distinct values the key can take by its static bounds (None:
-        not known)."""
+        """One int64 routing key per row from a list of key exprs, plus
+        validity, plus how many distinct values the key can take by its
+        static bounds (None: not known). Equal keys get equal routing
+        keys, which is all colocation needs: the key columns packed
+        into 62 bits where their bounds are known and fit, else a hash
+        of them (``card`` None). A NULL reads 0 whatever its slot holds,
+        so NULL keys colocate too. Raises for a key that does neither."""
         vals = [self.eval(e, ctx) for e in exprs]
         ok = ctx.row
         for v in vals:
             ok = _ok(v, ok)
+        arrs = [v.arr if v.valid is None
+                else jnp.where(v.valid, v.arr, jnp.zeros((), v.arr.dtype))
+                for v in vals]
         bounds = [(0, max(len(v.sdict) - 1, 0)) if v.sdict is not None
                   else (v.lo, v.hi) for v in vals]
         card = 1
@@ -718,18 +755,20 @@ class _DistTrace(dx._Trace):
             card = (None if card is None or lo is None or hi is None
                     else card * (hi - lo + 1))
         if len(vals) == 1:
-            return vals[0].arr.astype(jnp.int64), ok, card
-        parts = []
-        widths = []
-        for v, (lo, hi) in zip(vals, bounds):
-            if lo is None or hi is None:
-                raise DeviceExecError("cannot pack key without bounds")
-            parts.append((v.arr, lo, hi))
-            widths.append(max((hi - lo).bit_length(), 1))
-        if sum(widths) > 62:
-            raise DeviceExecError("distributed key too wide")
+            return arrs[0].astype(jnp.int64), ok, card
+        widths = None if card is None else [
+            max((hi - lo).bit_length(), 1) for lo, hi in bounds]
+        if widths is None or sum(widths) > 62:
+            if any(jnp.issubdtype(a.dtype, jnp.floating) for a in arrs):
+                raise DeviceExecError(
+                    "a floating-point key column does not hash exactly")
+            # a string column hashes by its dictionary code: the
+            # dictionaries are the host table's, the same on every device
+            self._note("agg.hash_routed")
+            return hash_columns(
+                [(a, v.valid) for a, v in zip(arrs, vals)]), ok, None
         acc = None
-        for (arr, lo, hi), w in zip(parts, widths):
+        for arr, (lo, hi), w in zip(arrs, bounds, widths):
             norm = jnp.clip(arr.astype(jnp.int64) - lo, 0, hi - lo)
             acc = norm if acc is None else ((acc << w) | norm)
         return acc, ok, card
@@ -758,8 +797,7 @@ class _DistTrace(dx._Trace):
             # delta deleted-row bitmask (local shard slice, padded
             # False): deleted rows leave the shard's row population
             row = row & live
-        ctx = DCtx(local, row)
-        ctx.sharded = True
+        ctx = self._stamp(DCtx(local, row), True, local)
         for name, _dt in node.output:
             col = t.columns[name]
             arr = self.bufs[f"{node.table}.{name}"]
@@ -768,27 +806,21 @@ class _DistTrace(dx._Trace):
             sdict = col.dictionary if col.is_string else None
             ctx.cols[(node.binding, name)] = DVal(arr, valid, sdict, lo, hi)
         for pred in node.filters:
-            ctx2 = self._apply_filter(ctx, pred)
-            ctx2.sharded = True
-            ctx = ctx2
+            ctx = self._carry(self._apply_filter(ctx, pred), ctx)
         return ctx
 
     def _run_derivedscan(self, node: P.DerivedScan) -> DCtx:
         ctx = super()._run_derivedscan(node)
-        ctx.sharded = getattr(self.run(node.child), "sharded", False)
-        return ctx
+        return self._carry(ctx, self.run(node.child))
 
     def _run_filter(self, node: P.Filter) -> DCtx:
         child = self.run(node.child)
-        ctx = self._apply_filter(child, node.predicate)
-        ctx.sharded = getattr(child, "sharded", False)
-        return ctx
+        return self._carry(self._apply_filter(child, node.predicate),
+                           child)
 
     def _run_project(self, node: P.Project) -> DCtx:
         child = self.run(node.child)
-        ctx = super()._run_project(node)
-        ctx.sharded = getattr(child, "sharded", False)
-        return ctx
+        return self._carry(super()._run_project(node), child)
 
     def _run_join(self, node: P.Join) -> DCtx:
         lctx, rctx = self.run(node.left), self.run(node.right)
@@ -820,9 +852,9 @@ class _DistTrace(dx._Trace):
                 rctx, _rk = self._exchange_ctx(rctx, rkey, rok)
             elif rs:
                 rctx = self._replicate(rctx)
+            # every probe row comes out once at most: the probe's bound
             out = self._join_cached(node, lctx, rctx)
-            out.sharded = probe_sharded
-            return out
+            return self._stamp(out, probe_sharded, _rows(lctx))
         # probe side is the right: left must be visible in full
         if ls and rs:
             lkey, lok, rkey, rok, _span = self._join_key_arrays(
@@ -840,9 +872,10 @@ class _DistTrace(dx._Trace):
             # after the exchange all matches are device-local, so the
             # base expanding join (incl. left-outer block B) is exact:
             # exchanged shards are disjoint across devices
+            # an expanding join can fill its output: the bound is the
+            # capacity it chose
             out = self._join_cached(node, lctx, rctx)
-            out.sharded = True
-            return out
+            return self._stamp(out, True, out.n)
         if ls:
             lctx = self._replicate(lctx)
         if rs and node.kind == "left":
@@ -854,8 +887,7 @@ class _DistTrace(dx._Trace):
             rctx = self._replicate(rctx)
             rs = False
         out = self._join_cached(node, lctx, rctx)
-        out.sharded = rs
-        return out
+        return self._stamp(out, rs, out.n)
 
     def _join_cached(self, node, lctx, rctx):
         """Run the single-device join logic on prepared child contexts."""
@@ -881,9 +913,7 @@ class _DistTrace(dx._Trace):
         self.stash(node.left, lctx)
         self.stash(node.right, rctx)
         self._cache.pop(id(node), None)
-        out = super()._run_semijoin(node)
-        out.sharded = ls
-        return out
+        return self._carry(super()._run_semijoin(node), lctx)
 
     def _run_aggregate(self, node: P.Aggregate) -> DCtx:
         ctx = self.run(node.child)
@@ -894,9 +924,11 @@ class _DistTrace(dx._Trace):
         if not node.group_keys:
             return self._global_agg_sharded(node, ctx)
         # repartition by group key so each group is wholly local, then the
-        # single-device aggregate is exact (distinct/avg included)
+        # single-device aggregate is exact (distinct/avg included). A
+        # key that neither packs nor hashes exactly (a floating-point
+        # column) leaves only the whole relation on every device
         try:
-            key, kok, card = self._key_of(
+            key, _kok, card = self._key_of(
                 ctx, [e for _, e in node.group_keys])
         except DeviceExecError:
             self.stash(node.child, self._replicate(ctx))
@@ -904,25 +936,22 @@ class _DistTrace(dx._Trace):
             out = super()._run_aggregate(node)
             out.sharded = False
             return out
-        # NULL group keys: kok False would keep rows home — fine, they
-        # still form their own (local) group only if all-null; TPC group
-        # keys are non-null so route by key, keep row presence as-is
-        slack = None
-        if card is not None and card < FEW_KEYS_A_DEVICE * self.n_dev:
-            # hashing cannot balance a handful of keys: every row of a
-            # key goes to one chip, and with fewer keys than a few a
-            # chip one destination can be sent most of a chip's rows
-            # (NDS-H q1 at SF1: four groups on four chips, 99 % of the
-            # rows to one). A bucket of slack 2 then overflows by
-            # construction and the program compiles twice; a bucket of
-            # the local row count cannot overflow
-            slack = max(self.slack, float(self.n_dev))
-        new, _ = self._exchange_ctx(ctx, key, ctx.row, slack)
+        # hashing cannot balance a handful of keys: every row of a key
+        # goes to one chip, and with fewer keys than a few a chip one
+        # destination can be sent most of a chip's rows (NDS-H q1 at
+        # SF1: four groups on four chips, 99 % of the rows to one). A
+        # bucket of slack 2 then overflows by construction and the
+        # program compiles twice; a bucket of the local capacity cannot
+        # overflow, and only the capacity promises that
+        few_keys = (card is not None
+                    and card < FEW_KEYS_A_DEVICE * self.n_dev)
+        # rows travel whatever their key's validity: NULL keys route by
+        # the 0 `_key_of` reads them as and form their group where they
+        # land
+        new, _ = self._exchange_ctx(ctx, key, ctx.row, few_keys)
         self.stash(node.child, new)
         self._cache.pop(id(node), None)
-        out = super()._run_aggregate(node)
-        out.sharded = True
-        return out
+        return self._carry(super()._run_aggregate(node), new)
 
     def _global_agg_sharded(self, node: P.Aggregate, ctx: DCtx) -> DCtx:
         b = node.binding

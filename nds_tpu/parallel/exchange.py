@@ -7,11 +7,17 @@ destination device and move in ONE `lax.all_to_all` across the mesh axis
 (ICI within a pod, DCN across slices; XLA picks the transport).
 
 Static-shape contract: each device sends a fixed-capacity bucket of
-``ceil(local_rows / n_dev * slack)`` rows to every peer. Hash
-partitioning spreads keys uniformly, so slack=2 covers real skew; rows
-that overflow a bucket are dropped AND counted — the executor surfaces
-the count so the host can retry with a bigger slack (adaptive, one
-recompile, never silent).
+``ceil(min(n, rows) * slack / n_dev)`` rows to every peer, where ``n``
+is the local buffer's capacity and ``rows`` the static bound the
+relation carries on how many rows a device holds of it (``bucket_for``;
+``rows`` None: the capacity). A hash partition moves rows and makes
+none, so a relation that has been through an exchange still holds
+``rows`` a device, in a buffer ``slack`` times as large: sized from
+``rows``, k exchanges in a row leave capacity ``slack x rows``, not
+``slack**k x rows``. Hash partitioning spreads keys uniformly, so
+slack=2 covers real skew; rows that overflow a bucket are dropped AND
+counted — the executor surfaces the count so the host can retry with a
+bigger slack (adaptive, one recompile, never silent).
 """
 
 from __future__ import annotations
@@ -41,20 +47,22 @@ _SINK: "ExchangeTrace | None" = None
 
 class ExchangeTrace:
     """What one program's trace says of its exchanges: the traced skew
-    scalars, and three static totals. ``rows`` is the bucket capacity a
+    scalars, and four static totals. ``rows`` is the bucket capacity a
     chip sends (``n_dev x bucket``) and ``nbytes`` the bytes it hands
     to ``all_to_all`` (every payload array and the ``ok`` mask at that
     capacity), each summed over the program's exchanges: what ONE chip
-    moves in ONE run of the program."""
+    moves in ONE run of the program. ``resized`` counts the exchanges
+    whose bucket is smaller than the buffer's capacity would make it:
+    sized from the relation's row bound."""
 
     def __init__(self):
         self.skews: list = []
-        self.count = self.rows = self.nbytes = 0
+        self.count = self.resized = self.rows = self.nbytes = 0
 
     def stats(self) -> dict:
         """The ``device.launch`` attributes of a sharded program."""
-        return {"exchanges": self.count, "exchange_rows": self.rows,
-                "exchange_bytes": self.nbytes}
+        return {"exchanges": self.count, "resized": self.resized,
+                "exchange_rows": self.rows, "exchange_bytes": self.nbytes}
 
 
 @contextlib.contextmanager
@@ -78,17 +86,46 @@ def _mix64(x):
     return x
 
 
+def hash_columns(columns: list):
+    """One int64 routing key per row from several key columns, for a
+    key too wide to pack: ``columns`` are (values, validity or None)
+    pairs of integer (or dictionary-code) arrays, values already 0
+    under NULL. Equal rows get equal keys; unequal rows may collide,
+    which costs balance and nothing else (the aggregate behind the
+    exchange compares the columns themselves)."""
+    acc = None
+    for arr, valid in columns:
+        x = arr.astype(jnp.int64).astype(jnp.uint64)
+        if valid is not None:
+            # NULL and 0 are different keys
+            x = (x << jnp.uint64(1)) | (~valid).astype(jnp.uint64)
+        acc = _mix64(x if acc is None else acc ^ x)
+    return acc.astype(jnp.int64)
+
+
+def bucket_for(n: int, rows: "int | None", slack: float,
+               n_dev: int) -> int:
+    """Per-peer bucket of an exchange of a buffer of capacity ``n``
+    that holds at most ``rows`` rows a device (None: not known, the
+    capacity)."""
+    live = n if rows is None else min(n, rows)
+    return max(1, int(-(-live * slack // n_dev)))
+
+
 def exchange(arrays: list, key, ok, n_dev: int, slack: float = 2.0,
-             axis: str = DATA_AXIS):
+             axis: str = DATA_AXIS, rows: "int | None" = None):
     """Repartition rows by hash(key) across the mesh axis.
 
     arrays: per-row payload arrays (local shard). key: int64 per row.
-    ok: bool per row (invalid rows don't travel).
+    ok: bool per row (invalid rows don't travel). rows: the static
+    bound on the rows a device holds (``bucket_for``).
     Returns (out_arrays, out_ok, overflow_count) where out_* have
-    capacity n_dev * bucket ( = local_n * slack rounded up).
+    capacity n_dev * bucket ( = min(local_n, rows) * slack rounded up).
     """
     dest = (_mix64(key) % jnp.uint64(n_dev)).astype(jnp.int32)
-    return exchange_by_dest(arrays, dest, ok, n_dev, slack, axis)
+    return exchange_by_dest(
+        arrays, dest, ok, n_dev, slack, axis,
+        bucket=bucket_for(dest.shape[0], rows, slack, n_dev))
 
 
 def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
@@ -97,8 +134,9 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     """Exchange core routed by an explicit per-row destination index in
     [0, n_dev) along ``axis`` (the hierarchical DCN/ICI exchange routes
     each stage with a different destination derivation). ``bucket``
-    overrides the per-peer capacity — hierarchical stage 2 sizes it
-    from the LOGICAL row count, not the stage-1 padded length."""
+    overrides the per-peer capacity: the caller's ``bucket_for`` of the
+    relation's row bound, where the buffer's length says more slots
+    than rows (it came through an exchange)."""
     n = dest.shape[0]
     # chaos site (trace time, like the counter below): an injected
     # fault here surfaces during compile, where the executor's retry
@@ -112,8 +150,9 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     # contain (runtime executions multiply by program runs; in-program
     # counting would cost a collective per query for a vanity number)
     obs_metrics.counter("exchanges_traced_total").inc()
+    by_capacity = bucket_for(n, None, slack, n_dev)
     if bucket is None:
-        bucket = max(1, int(-(-n * slack // n_dev)))
+        bucket = by_capacity
     # dead rows get a sentinel dest PAST every real bucket so they never
     # consume rank slots (a heavily filtered shard must not overflow its
     # own bucket with corpses)
@@ -135,6 +174,7 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     if _SINK is not None:
         capacity = n_dev * bucket
         _SINK.count += 1
+        _SINK.resized += bucket < by_capacity
         _SINK.rows += capacity
         _SINK.nbytes += capacity * (1 + sum(    # 1: the bool ok mask
             a.dtype.itemsize * math.prod(a.shape[1:]) for a in arrays))
@@ -183,7 +223,8 @@ def exchange_hierarchical(arrays: list, key, ok, n_hosts: int,
                           n_lanes: int, slack: float = 2.0,
                           host_axis: str = "h",
                           lane_axis: str = DATA_AXIS,
-                          key_index: int | None = None):
+                          key_index: int | None = None,
+                          rows: "int | None" = None):
     """Two-stage shuffle for multi-host meshes (SURVEY.md §7 hard part
     4: the ICI-instead-of-UCX deliverable at DCN scale): rows first move
     to their destination HOST over the ``host_axis`` (DCN — one
@@ -193,6 +234,11 @@ def exchange_hierarchical(arrays: list, key, ok, n_hosts: int,
     g = hash(key) % (hosts * lanes); host = g // lanes; lane = g %
     lanes — so downstream grouped operators see the same colocation
     contract as the flat 1-D exchange.
+
+    Both stages size their buckets from ``rows``, the static bound on
+    the rows a device holds (``bucket_for``; None: the input's
+    capacity), never from the padded length a stage was handed —
+    otherwise every downstream operator pays n * slack^2.
 
     Returns (out_arrays, out_ok, overflow_count) with the overflow
     counts of both stages summed (the executor's retry-with-bigger-slack
@@ -210,16 +256,13 @@ def exchange_hierarchical(arrays: list, key, ok, n_hosts: int,
         payload = payload + [key]
         key_index = len(payload) - 1
     outs1, ok1, over1 = exchange_by_dest(
-        payload, dest_h, ok, n_hosts, slack, host_axis)
+        payload, dest_h, ok, n_hosts, slack, host_axis,
+        bucket=bucket_for(n, rows, slack, n_hosts))
     key1 = outs1[key_index]
-    # stage 2 (ICI): recompute the lane from the carried key. Bucket is
-    # sized from the LOGICAL rows (expected ~n per device after a
-    # uniform hash), not the stage-1 padded capacity — otherwise every
-    # downstream operator pays n * slack^2
+    # stage 2 (ICI): recompute the lane from the carried key
     g1 = (_mix64(key1) % jnp.uint64(n_hosts * n_lanes)).astype(jnp.int32)
     dest_d = g1 % n_lanes
-    bucket2 = max(1, int(-(-n * slack // n_lanes)))
     outs2, ok2, over2 = exchange_by_dest(
         outs1[:-1] if appended else outs1, dest_d, ok1, n_lanes, slack,
-        lane_axis, bucket=bucket2)
+        lane_axis, bucket=bucket_for(n, rows, slack, n_lanes))
     return outs2, ok2, over1 + over2

@@ -141,8 +141,9 @@ def _num(v) -> bool:
 # statement (dist_exec) has the single-device tree: its retry at doubled
 # slack adds a second device.dispatch / device.readback pair under the
 # same device.execute. Attributes the catalogue names beyond the README's
-# table: device.launch carries exchanges / exchange_rows / exchange_bytes
-# for a sharded program, device.readback overflow_rows / skew.
+# table: device.launch carries exchanges / resized / exchange_rows /
+# exchange_bytes for a sharded program, device.readback overflow_rows /
+# skew.
 _ROOTS = ("stmt", "query")
 SPAN_PARENTS = {
     "sched.place": _ROOTS,
