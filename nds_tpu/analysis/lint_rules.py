@@ -1086,7 +1086,8 @@ class BlockingTransferInStreamLoopRule(Rule):
     engine's phase-A stream loops or the prefetch worker. The pipelined
     executor (``engine/pipeline_io.py``; README "Pipelined execution")
     exists so host staging overlaps device compute; a stray
-    ``jax.device_get(...)``, ``.block_until_ready()``, or
+    ``jax.device_get(...)`` (bare, or through the executors'
+    ``_readback``), ``.block_until_ready()``, or
     ``np.asarray(<device result>)`` inside a chunk loop serializes the
     pipeline right back to the pre-overlap behavior — silently, since
     results stay correct and only occupancy collapses. The two
@@ -1115,6 +1116,10 @@ class BlockingTransferInStreamLoopRule(Rule):
                         hit = "jax.device_get(...)"
                     elif f.attr == "block_until_ready":
                         hit = ".block_until_ready()"
+                    elif f.attr == "_readback":
+                        # the executors' device.readback helper: one
+                        # jax.device_get, counted
+                        hit = "._readback(...)"
                     elif (f.attr == "asarray"
                           and isinstance(f.value, ast.Name)
                           and f.value.id in ("np", "numpy")

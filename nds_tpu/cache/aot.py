@@ -235,17 +235,16 @@ def call_compatible(compiled, *args) -> bool:
 
 
 def load_cached(cache, fp: str, kind: str,
-                timings: "dict | None" = None,
-                args: "tuple | None" = None, count: bool = True,
+                timings: "dict | None" = None, args=None,
                 devices=None):
     """Cache consult: -> (compiled, extra) on a verified hit, else
     None. Deserialize failures and signature-incompatible executables
     degrade to a miss (warned + counted); ``timings`` gains
-    ``cache_load_ms`` on the hit path. ``count=False`` skips the hit
-    increment for callers that still have their own verification to
-    run (the sharded path's key-split compat check) and count the
-    final verdict themselves. ``devices``: see
-    :func:`deserialize_compiled`."""
+    ``cache_load_ms`` on the hit path. ``args``: what the caller would
+    call the executable with, or a function giving that from what was
+    persisted beside it (the sharded executor's key split rides
+    there): an entry they cannot be built from, or do not fit, is a
+    miss. ``devices``: see :func:`deserialize_compiled`."""
     from nds_tpu.cache.store import _warn, obs_metrics
     t0 = time.perf_counter()
     payload = cache.get(fp, expect_kind=kind)
@@ -261,15 +260,15 @@ def load_cached(cache, fp: str, kind: str,
         cache._quarantine(fp)
         obs_metrics.counter("compile_cache_misses_total").inc()
         return None
-    if args is not None and not call_compatible(compiled, *args):
+    extra = payload.get("extra", {})
+    if args is not None and not _fits(compiled, args, extra):
         _warn(f"entry {fp[:12]}… is signature-incompatible with this "
               f"query's buffers; recompiling fresh")
         obs_metrics.counter("compile_cache_misses_total").inc()
         return None
     # the hit counts HERE, after the executable proved loadable and
     # signature-compatible — store.get alone is not a served program
-    if count:
-        obs_metrics.counter("compile_cache_hits_total").inc()
+    obs_metrics.counter("compile_cache_hits_total").inc()
     if timings is not None:
         timings["cache_load_ms"] = (
             timings.get("cache_load_ms", 0.0)
@@ -279,7 +278,16 @@ def load_cached(cache, fp: str, kind: str,
     # (obs/costs.record_program) is a dict read, not a re-analysis
     from nds_tpu.obs import costs as obs_costs
     obs_costs.attach(compiled, payload.get("cost"))
-    return compiled, payload.get("extra", {})
+    return compiled, extra
+
+
+def _fits(compiled, args, extra: dict) -> bool:
+    if callable(args):
+        try:
+            args = args(extra)
+        except (KeyError, TypeError):  # extra lacks what the call is built from
+            return False
+    return call_compatible(compiled, *args)
 
 
 def persist(cache, fp: str, kind: str, compiled,

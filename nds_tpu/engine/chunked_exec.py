@@ -44,7 +44,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from nds_tpu.analysis import jitsan
 from nds_tpu.engine import device_exec as dx
 from nds_tpu.engine import pipeline_io
 from nds_tpu.engine.device_exec import DCtx, DVal
@@ -52,7 +51,6 @@ from nds_tpu.engine.types import (
     INT64, DecimalType, FloatType, Schema, StringType,
 )
 from nds_tpu.io.host_table import HostColumn, HostTable, encode_strings
-from nds_tpu.obs import costs as obs_costs
 from nds_tpu.obs import memwatch
 from nds_tpu.obs import metrics as obs_metrics
 from nds_tpu.obs.trace import get_tracer
@@ -623,6 +621,7 @@ class ChunkedExecutor(dx.DeviceExecutor):
             pf = pipeline_io.ChunkPrefetcher(
                 group[1:], _stage_swap, self.prefetch_depth,
                 table=table)
+            tracer = get_tracer()
             try:
                 for staged in pf:
                     s, e = staged.item
@@ -649,16 +648,15 @@ class ChunkedExecutor(dx.DeviceExecutor):
                         )
                         overflow_policy = adaptive_policy(4)
                         for attempt in overflow_policy.attempts():
-                            # per-dispatch cost billing: each chunk
-                            # (and each overflow retry) bills its
-                            # program's compiler cost once
-                            obs_costs.record_program(
-                                type(ex).__name__, compiled)
-                            with jitsan.dispatch(type(ex).__name__):
-                                row, outs, overflow = compiled(bufs)
+                            # each chunk (and each overflow retry)
+                            # bills its program's compiler cost once,
+                            # under its own device.launch
+                            _t, devs = ex._launch(
+                                tracer, type(ex).__name__, compiled,
+                                bufs)
                             # ndslint: waive[NDS117] -- sanctioned per-chunk sync point: the overflow verdict gates the slack-doubling retry, and the partials must land on host before the next chunk swaps buffers
-                            row_h, outs_h, over_h = jax.device_get(
-                                (row, outs, overflow))
+                            row_h, outs_h, over_h = ex._readback(
+                                tracer, devs)
                             if int(over_h) == 0:
                                 break
                             if attempt == overflow_policy.max_attempts - 1:
@@ -881,6 +879,7 @@ class ChunkedExecutor(dx.DeviceExecutor):
                        for start in range(0, n, C)]
         pf = pipeline_io.ChunkPrefetcher(
             chunk_spans, _stage_chunk, self.prefetch_depth, table=table)
+        tracer = get_tracer()
         try:
             compiled = None
             keep_np = np.empty(n, dtype=bool)
@@ -902,19 +901,15 @@ class ChunkedExecutor(dx.DeviceExecutor):
                         compiled = self._keep_mask_compiled(
                             table, scans, need_cols, C, fn, bufs,
                             chunk_specs)
-                    obs_costs.record_program("chunkscan", compiled)
                     # the chunk-length scalar stages BEFORE the
                     # dispatch scope: its tiny h2d is control-plane,
                     # not a buffer leaking into the guarded hot path
                     nchunk = jnp.int32(stop - start)
-                    with jitsan.dispatch("chunkscan"):
-                        mask_d = compiled(bufs, nchunk)
-                    with jitsan.declared("keep-mask readback"):
-                        # sanctioned per-chunk sync point: the keep
-                        # mask IS phase A's product and must land on
-                        # host before the survivor gather
-                        keep_np[start:stop] = np.asarray(  # ndsjit: waive[NDSJ303] -- the declared() scope above attributes this sync; it is phase A's product, not a hidden stall
-                            mask_d)[:stop - start]
+                    _t, mask_d = self._launch(
+                        tracer, "chunkscan", compiled, bufs, nchunk)
+                    # ndslint: waive[NDS117] -- sanctioned per-chunk sync point: the keep mask IS phase A's product and must land on host before the survivor gather
+                    keep_np[start:stop] = self._readback(
+                        tracer, mask_d)[:stop - start]
                 finally:
                     staged.release()
             if skipped:

@@ -418,6 +418,12 @@ class DeviceExecutor:
         self._uploads = 0
         self._upload_bytes = 0
         self._views_built = 0
+        # the slack a statement's first program compiles at (the
+        # sharded executor's grows: grow_slack)
+        self.slack = self.DEFAULT_SLACK
+        # the device.execute span's attributes (the sharded executor
+        # adds its device count)
+        self._execute_attrs = {"executor": type(self).__name__}
 
     # ------------------------------------------------------------------ API
 
@@ -620,35 +626,15 @@ class DeviceExecutor:
     def _merge_stage_timings(self, timings: dict,
                              key: object = None) -> None:
         """Fold the accumulated sub-program bill into the main
-        program's timings and recompute the bandwidth-derived metrics
-        over the WHOLE query (staging targets exactly the queries where
+        program's timings (staging targets exactly the queries where
         dropping the sub bill would misreport the roofline)."""
-        agg = self._stage_timings.pop(key, None)
-        if not agg:
-            return
-        for k, v in agg.items():
+        for k, v in (self._stage_timings.pop(key, None) or {}).items():
             if k == "__kernels":
                 kacc = timings.setdefault("__kernels", {})
                 for kn, cnt in v.items():
                     kacc[kn] = kacc.get(kn, 0) + cnt
             else:
                 timings[k] = timings.get(k, 0.0) + v
-        bs = timings.get("bytes_scanned", 0.0)
-        if bs and timings.get("execute_ms", 0) > 0:
-            timings["scan_gbps"] = bs / (timings["execute_ms"] / 1000) / 1e9
-            peak = _peak_mem_gbps()
-            if peak:
-                timings["roofline_frac"] = round(
-                    timings["scan_gbps"] / peak, 4)
-                timings["roofline_peak_gbps"] = peak
-        if bs and timings.get("ops_est"):
-            timings["ops_per_byte"] = round(
-                timings["ops_est"] / bs, 4)
-        if bs and timings.get("bytes_scanned_raw"):
-            # whole-query ratio: the folded sub-programs' raw bytes
-            # count too (staging targets exactly the big queries)
-            timings["compression_ratio"] = round(
-                timings["bytes_scanned_raw"] / bs, 4)
 
     def execute(self, planned: P.PlannedQuery, key: object = None):
         return self.execute_async(planned, key).result()
@@ -661,40 +647,42 @@ class DeviceExecutor:
         analog of spark.rapids.sql.concurrentGpuTasks,
         `nds/power_run_gpu.template:38`) and overlap device execution
         with host-side materialization of earlier results."""
-        from nds_tpu.resilience import faults, watchdog
+        from nds_tpu.resilience import faults
         faults.fault_point("device.execute",
                            executor=type(self).__name__)
-        # engine-side heartbeat: a query inside compile/execute still
-        # shows liveness to the hang watchdog at every dispatch
-        watchdog.beat("engine", phase="device.execute",
-                      executor=type(self).__name__)
         planned = self._plan_for_dispatch(planned)
         key = key if key is not None else self._plan_key(planned)
-        orig = planned
         tracer = get_tracer()
         # a failed query must never inherit the previous query's span
         # (query_timings would serve stale numbers into its summary)
         self.last_query_span = None
         # explicitly-owned query span: the async half (_finish) may run
         # after other queries dispatched, so stack discipline can't own it
-        qspan = tracer.begin("device.execute",
-                             executor=type(self).__name__)
+        qspan = tracer.begin("device.execute", **self._execute_attrs)
+        timings = {"compile_ms": 0.0}
         try:
-            return self._dispatch_traced(planned, orig, key, tracer,
+            return self._dispatch_traced(planned, key, timings, tracer,
                                          qspan)
         except BaseException as exc:
             # nested staged sub-programs set last_query_span on THEIR
             # success; a failing main program must not leave a sub's
             # span masquerading as the whole query's
             self.last_query_span = None
-            # release the accounted scan bytes a failed dispatch added
-            # (pop: a pre-upload failure — or a stale dict from the
-            # previous, already-released query — releases 0)
-            memwatch.sub_live(
-                (self.last_timings or {}).pop("__live_bytes", 0.0))
+            # what the failed dispatch had taken (a pre-upload failure
+            # releases nothing)
+            self._dispatch_over(timings)
             if qspan and qspan.t1 is None:
                 qspan.set(error=f"{type(exc).__name__}: {exc}").end()
             raise
+
+    def _dispatch_over(self, timings: dict) -> None:
+        """Clean up after a dispatch whose program has been read back,
+        has overflowed or has raised: give back the scan bytes it
+        accounted live (obs/memwatch). ``__live_bytes`` is the release
+        token: every release POPS it, so the success, overflow and
+        failure paths can never double-release (stripped from all
+        published timings)."""
+        memwatch.sub_live(timings.pop("__live_bytes", 0.0))
 
     def _plan_for_dispatch(self, planned):
         """Pre-dispatch plan normalization hook. Base rule:
@@ -730,11 +718,32 @@ class DeviceExecutor:
         from nds_tpu.sql import params as sqlparams
         return sqlparams.plan_key(planned) or id(planned)
 
+    def _entry(self, planned, orig, key) -> dict:
+        """The compile-cache entry of ``key``, the one shape every
+        executor keeps: ``slack`` (doubled by an overflow), ``ref``,
+        and — once compiled or loaded — ``compiled`` and ``side``
+        (what the program's trace left beside it). ``ref`` holds strong
+        refs: id()-keyed entries must keep THE CALLER'S plan object
+        alive (its id is the key — a recycled address could serve
+        another query's compiled program), plus the staged main plan
+        actually compiled. Bounded: least recently dispatched first,
+        an order kept only once the bound is reached (a hit below it
+        touches nothing)."""
+        entry = self._compiled.get(key)
+        if entry is None:
+            entry = self._compiled[key] = {"slack": self.slack,
+                                           "ref": (orig, planned)}
+            self._bound_compiled(key)
+        elif len(self._compiled) >= self.MAX_COMPILED:
+            # LRU refresh: move the hit to the back of the dict order
+            self._compiled[key] = self._compiled.pop(key)
+        return entry
+
     def _bound_compiled(self, active_key) -> None:
-        """FIFO-evict query-level entries past MAX_COMPILED (and their
-        staged state, via _evict_query_state). Never evicts the entry
-        being dispatched, compactor programs, or staged sub-entries
-        (those die with their base key)."""
+        """Evict query-level entries past MAX_COMPILED, oldest first
+        (and their staged state, via _evict_query_state). Never evicts
+        the entry being dispatched, compactor programs, or staged
+        sub-entries (those die with their base key)."""
         def evictable(k) -> bool:
             if k == active_key:
                 return False
@@ -752,7 +761,17 @@ class DeviceExecutor:
                 return
             self._evict_query_state(victim)
 
-    def _dispatch_traced(self, planned, orig, key, tracer, qspan):
+    def _dispatch_traced(self, planned, key, timings, tracer, qspan):
+        """``device.dispatch``: everything up to and including the
+        launch of ``planned``'s program, under the statement's span.
+        The overflow loop (_finish) comes through here again with the
+        same ``timings``: the compile bill adds up across attempts."""
+        from nds_tpu.resilience import watchdog
+        # engine-side heartbeat: a query inside compile/execute still
+        # shows liveness to the hang watchdog at every dispatch
+        watchdog.beat("engine", phase="device.execute",
+                      executor=type(self).__name__)
+        orig = planned
         with tracer.attach(qspan), tracer.span("device.dispatch"):
             planned = self._staged_effective(planned, key)
             from nds_tpu.analysis import plan_verify
@@ -763,16 +782,8 @@ class DeviceExecutor:
                 # this executor's table registry
                 plan_verify.assert_valid(planned, tables=self.tables,
                                          label="staged plan")
-            timings = {"compile_ms": 0.0}
             self.last_timings = timings
-            # the cache entry holds a strong ref to the plan: id()-keyed
-            # entries must keep THE CALLER'S plan object alive (its id is
-            # the key — a recycled address could serve another query's
-            # compiled program), plus the staged main plan actually
-            # compiled
-            entry = self._compiled.setdefault(
-                key, {"slack": self.DEFAULT_SLACK, "ref": (orig, planned)})
-            self._bound_compiled(key)
+            entry = self._entry(planned, orig, key)
             if "compiled" not in entry:
                 self._compile_or_load(planned, entry, timings, tracer)
             bufs, pvals = self._bind(planned, tracer)
@@ -788,20 +799,28 @@ class DeviceExecutor:
             obs_metrics.counter("bytes_scanned_total").inc(
                 timings["bytes_scanned"])
             # memory HWM (obs/memwatch): scan buffers go live here and
-            # release in _finish; the device-stats sample around the
-            # execute bracket dominates the accounting when available.
-            # __live_bytes is the release token: every release POPS it,
-            # so the success/failure paths can never double-release
-            # (stripped from all published timings)
+            # release when the dispatch is over (_dispatch_over); the
+            # device-stats sample around the execute bracket dominates
+            # the accounting when available
             memwatch.add_live(timings["bytes_scanned"])
             timings["__live_bytes"] = timings["bytes_scanned"]
             memwatch.sample_device()
             # bufs/pvals are device-resident by the bind above
-            t1, devs = self._launch(
-                tracer, type(self).__name__, entry["compiled"],
-                *((bufs, pvals) if pvals is not None else (bufs,)))
+            t1, devs = self._launch_program(tracer, entry, bufs, pvals)
         return _AsyncResult(self, planned, key, entry, timings, t1,
                             devs, qspan)
+
+    def _call_args(self, side: dict, bufs: dict, pvals) -> tuple:
+        """What the plan's executable is called with — lowered with,
+        checked against after a plan-cache load, launched with."""
+        return (bufs, pvals) if pvals is not None else (bufs,)
+
+    def _launch_program(self, tracer, entry, bufs, pvals):
+        """Launch the statement's program -> (perf_counter at the
+        call, its device outputs)."""
+        return self._launch(
+            tracer, type(self).__name__, entry["compiled"],
+            *self._call_args(entry["side"], bufs, pvals))
 
     def _bind(self, planned, tracer) -> tuple:
         """``device.bind``: the (buffers, parameters) one call of the
@@ -874,11 +893,12 @@ class DeviceExecutor:
         """Per-query compression accounting (nds_tpu/columnar/):
         ``bytes_scanned`` already measures the ENCODED buffer bytes
         (the sum above counts what is actually resident); this adds
-        the raw bytes those buffers replace and the resulting
-        compression_ratio. Emitted only under an active mode so
+        the raw bytes those buffers replace (_finalize_timings derives
+        compression_ratio from them). Emitted only under an active
+        mode, and by executors that upload encoded at all, so
         ``columnar.encode=off`` summaries stay byte-identical."""
         from nds_tpu import columnar
-        if not columnar.enabled():
+        if not (self.COLUMNAR_UPLOAD and columnar.enabled()):
             return
         raw = 0.0
         for k, b in bufs.items():
@@ -889,9 +909,6 @@ class DeviceExecutor:
             else:
                 raw += float(b.nbytes)
         timings["bytes_scanned_raw"] = raw
-        if timings.get("bytes_scanned") and raw:
-            timings["compression_ratio"] = round(
-                raw / timings["bytes_scanned"], 4)
 
     def _attach_delta(self, timings: dict, planned) -> None:
         """Per-query delta accounting (columnar/delta.py): how many
@@ -950,6 +967,17 @@ class DeviceExecutor:
             planned=planned, tables=self.tables,
             extra_roots=self._fingerprint_roots())
 
+    # what a program's trace leaves beside its executable (``side``),
+    # persisted with it so that a loaded program says the same of
+    # itself
+    SIDE_KEYS: tuple = ("dicts", "kernels", "ops_est")
+
+    def _devices(self):
+        """The devices the statement's program is compiled for, in
+        assignment order; None = the one default device
+        (cache.aot.deserialize_compiled)."""
+        return None
+
     def _compile_or_load(self, planned, entry: dict, timings: dict,
                          tracer) -> None:
         """Fill ``entry['compiled']``/``entry['side']`` for a plan: a
@@ -959,19 +987,18 @@ class DeviceExecutor:
         next process."""
         import time as _time
         from nds_tpu.cache import aot as cache_aot
+        kind = type(self).__name__
         pc, fp = self._plan_fingerprint(planned, entry["slack"])
         if fp:
             with tracer.span("cache.load", fp=fp[:12]):
                 bufs, pvals = self._bind(planned, tracer)
                 hit = cache_aot.load_cached(
-                    pc, fp, type(self).__name__, timings,
-                    args=((bufs, pvals) if pvals is not None
-                          else (bufs,)))
+                    pc, fp, kind, timings,
+                    args=lambda side: self._call_args(side, bufs, pvals),
+                    devices=self._devices())
             if hit is not None:
                 entry["compiled"], extra = hit
-                entry["side"] = {"dicts": extra.get("dicts"),
-                                 "kernels": extra.get("kernels"),
-                                 "ops_est": extra.get("ops_est")}
+                entry["side"] = {k: extra.get(k) for k in self.SIDE_KEYS}
                 # an overflow retry served from another process's
                 # persisted recompile consumed no compile here
                 entry.pop("recompile", None)
@@ -984,28 +1011,23 @@ class DeviceExecutor:
             # AOT-compile now so compile cost is attributed
             # separately from steady-state execution (fresh when the
             # blob will persist: see lower_and_compile)
-            lower_args = ((bufs, pvals) if pvals is not None
-                          else (bufs,))
             entry["compiled"] = cache_aot.lower_and_compile(
-                jitted, *lower_args, fresh=cache_aot.fresh_for(pc, fp),
-                kind=type(self).__name__)
+                jitted, *self._call_args(side, bufs, pvals),
+                fresh=cache_aot.fresh_for(pc, fp), kind=kind)
         entry["side"] = side
         timings["compile_ms"] += (
             # ndslint: waive[NDS102,NDS103] -- .compile() is synchronous; the execute bracket closes via device_get in _finish_traced
             _time.perf_counter() - t0) * 1000
         # overflow retries recompile the SAME query: count them
-        # apart from first compiles (distributed executor
-        # semantics, README counter contract)
+        # apart from first compiles (README counter contract)
         obs_metrics.counter(
             "recompiles_total" if entry.pop("recompile", False)
             else "compiles_total").inc()
         if fp:
-            cache_aot.persist(pc, fp, type(self).__name__,
-                              entry["compiled"],
-                              {"dicts": side.get("dicts"),
-                               "kernels": side.get("kernels"),
-                               "ops_est": side.get("ops_est")},
-                              meta={"slack": entry["slack"]})
+            cache_aot.persist(pc, fp, kind, entry["compiled"],
+                              {k: side.get(k) for k in self.SIDE_KEYS},
+                              meta={"slack": entry["slack"]},
+                              devices=self._devices())
 
     # capacity at or above which results compact ON DEVICE before the
     # host transfer: a masked full-capacity result of a 576k-slot query
@@ -1068,11 +1090,13 @@ class DeviceExecutor:
         return cf
 
     def _finalize_timings(self, timings: dict, key: object) -> None:
-        """Shared tail of every executor's timing bill: roofline
-        derivation (achieved scan bandwidth vs the active backend's
-        peak memory bandwidth — the denominator that turns "N GB/s"
-        into "is it actually fast"), staged
-        sub-program fold, and the last_timings publication."""
+        """Shared tail of every executor's timing bill: the staged
+        sub-program fold, then — once, over the WHOLE statement — the
+        roofline derivation (achieved scan bandwidth vs the active
+        backend's peak memory bandwidth — the denominator that turns
+        "N GB/s" into "is it actually fast"), and the last_timings
+        publication."""
+        self._merge_stage_timings(timings, key)
         bs = timings.get("bytes_scanned", 0.0)
         if bs and timings.get("execute_ms", 0) > 0:
             timings["scan_gbps"] = (
@@ -1088,21 +1112,46 @@ class DeviceExecutor:
             # ndsreport roofline column pairs with roofline_frac
             timings["ops_per_byte"] = round(
                 timings["ops_est"] / bs, 4)
-        self._merge_stage_timings(timings, key)
+        if bs and timings.get("bytes_scanned_raw"):
+            timings["compression_ratio"] = round(
+                timings["bytes_scanned_raw"] / bs, 4)
         self.last_timings = timings
 
-    def _finish(self, planned, key, entry, timings, t1, devs,
-                attempt: int = 0, span=None):
-        """Blocking half of execute_async: one device->host transfer
-        for execution + result (rather than a separate
-        block_until_ready + int(overflow) + device_get: each is its
-        own host sync), then overflow-retry with doubled slack.
-        Large-capacity results compact on device first (see
-        COMPACT_MIN_ROWS)."""
+    # the rounds at doubled slack a statement gets after its first
+    # overflow, and what overflowed (the give-up message the ladder
+    # classifies on)
+    OVERFLOW_RETRIES = 3
+    OVERFLOW_WHAT = "join expansion overflow"
+
+    def _finish(self, handle: "_AsyncResult"):
+        """Blocking half of execute_async, and the one overflow loop:
+        a program whose read-back says rows did not fit (an M:N join's
+        expansion, an exchange's bucket) goes round again at doubled
+        slack — counted, recompiled, exact."""
         tracer = get_tracer()
+        h, span, timings = handle, handle.span, handle.timings
         try:
-            return self._finish_traced(planned, key, entry, timings,
-                                       t1, devs, attempt, span, tracer)
+            for attempt in range(self.OVERFLOW_RETRIES + 1):
+                out, n_over = self._finish_traced(
+                    h.planned, h.key, h.entry, timings, h.t1, h.devs,
+                    span, tracer)
+                if not n_over:
+                    return out
+                if attempt == self.OVERFLOW_RETRIES:
+                    raise DeviceExecError(
+                        f"{self.OVERFLOW_WHAT} persisted after retries")
+                # the attempt's accounted scan bytes: the next
+                # dispatch adds its own
+                self._dispatch_over(timings)
+                entry = h.entry
+                slack = entry["slack"]
+                entry.pop("compiled", None)
+                entry["recompile"] = True
+                entry["slack"] = slack * 2
+                obs_metrics.counter("slack_retries_total").inc()
+                self._note_overflow(n_over, slack)
+                h = self._dispatch_traced(h.planned, h.key, timings,
+                                          tracer, span)
         except BaseException as exc:
             # failed queries still close their span (with the error
             # attached) so trace durations stay truthful; and a staged
@@ -1112,16 +1161,31 @@ class DeviceExecutor:
                 span.set(error=f"{type(exc).__name__}: {exc}").end()
             raise
         finally:
-            # the dispatch's accounted scan bytes release when the
-            # query completes either way (overflow retries re-add
-            # through execute_async and release through THEIR finish;
-            # pop makes a second release a no-op)
-            memwatch.sub_live(timings.pop("__live_bytes", 0.0))
+            # the statement is over either way (pop makes a second
+            # release a no-op)
+            self._dispatch_over(timings)
+
+    def _note_overflow(self, n_over: int, slack: float) -> None:
+        """An overflow at ``slack`` is about to be retried at twice
+        that: a recovered task-level failure -> listener chain, the
+        CompletedWithTaskFailures analog of `Manager.notifyAll`."""
+        from nds_tpu.utils.report import TaskFailureCollector
+        TaskFailureCollector.notify(
+            f"{self.OVERFLOW_WHAT}: retry with slack {slack * 2}")
+
+    def _read_outputs(self, tracer, devs) -> tuple:
+        """``device.readback`` of an uncompacted program's outputs
+        -> (row, outs, overflow) on the host."""
+        return self._readback(tracer, devs)
 
     def _finish_traced(self, planned, key, entry, timings, t1, devs,
-                       attempt, span, tracer):
+                       span, tracer) -> tuple:
+        """One attempt's read-back, and — unless it overflowed — the
+        materialized result and the closed bill -> (result, rows that
+        did not fit). Large-capacity results compact on device first
+        (see COMPACT_MIN_ROWS)."""
         import time as _time
-        row_d, outs_d, overflow_d = devs
+        row_d, outs_d, overflow_d = devs[:3]
         n = row_d.shape[0]
         compact = n >= self.COMPACT_MIN_ROWS and bool(outs_d)
         with tracer.attach(span):
@@ -1132,7 +1196,9 @@ class DeviceExecutor:
                 _t, (cnt_d, row2, outs2) = self._launch(
                     tracer, "compact", cf, row_d, outs_d)
             # every blocking device->host transfer of the statement is
-            # made, and counted, here
+            # made, and counted, here: one for execution + result
+            # (rather than a separate block_until_ready + int(overflow)
+            # + device_get: each is its own host sync)
             if compact:
                 with tracer.span("device.readback") as rb:
                     cnt_h, overflow_h = jax.device_get((cnt_d, overflow_d))
@@ -1152,67 +1218,47 @@ class DeviceExecutor:
                 obs_metrics.counter("device_readbacks_total").inc(syncs)
                 obs_metrics.counter("readback_bytes_total").inc(nbytes)
             else:
-                row_h, outs_h, overflow_h = self._readback(tracer, devs)
+                row_h, outs_h, overflow_h = self._read_outputs(
+                    tracer, devs)
             # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span via begin(t0=t1).end(t=t2)
             t2 = _time.perf_counter()
-            if int(overflow_h) == 0:
-                # the execute bracket closed at t2 (device_get blocks
-                # until ready); record it as a span with the measured
-                # endpoints
-                tracer.begin("device.run", parent=span, t0=t1).end(t=t2)
-                with tracer.span("device.materialize"):
-                    out = self._materialize(planned, row_h, outs_h,
-                                            entry["side"])
-                # ndslint: waive[NDS102] -- host materialize endpoint; the device.materialize span brackets the same region
-                t3 = _time.perf_counter()
-                with tracer.span("device.finish"):
-                    # post-materialize allocator sample: results + scan
-                    # buffers are all resident here, the per-query
-                    # memory peak
-                    memwatch.sample_device()
-                    timings["execute_ms"] = (t2 - t1) * 1000
-                    timings["materialize_ms"] = (t3 - t2) * 1000
-                    side = entry.get("side") or {}
-                    if side.get("ops_est"):
-                        timings["ops_est"] = float(side["ops_est"])
-                    if side.get("kernels"):
-                        # dunder: a dict, not part of the numeric
-                        # timings vocabulary (engineTimings strips it;
-                        # report.py publishes it as the summary's
-                        # "kernels" block)
-                        timings["__kernels"] = dict(side["kernels"])
-                    self._finalize_timings(timings, key)
-        if int(overflow_h) == 0:
-            if span:
-                # dunder keys are internal accounting state (e.g. the
-                # __live_bytes release token), not part of the
-                # published timings vocabulary
-                span.set(timings={k: v for k, v in timings.items()
-                                  if not k.startswith("__")}).end()
-                self.last_query_span = span
-            return out
-        if attempt >= 3:
-            if span:
-                span.set(error="join expansion overflow").end()
-            raise DeviceExecError("join expansion overflow after retries")
-        # M:N join capacity exceeded: recompile with doubled slack
-        # (recovered task-level failure -> listener chain, the
-        # CompletedWithTaskFailures analog of `Manager.notifyAll`)
-        from nds_tpu.utils.report import TaskFailureCollector
-        TaskFailureCollector.notify(
-            f"join expansion overflow: retry with slack "
-            f"{entry['slack'] * 2}")
-        obs_metrics.counter("slack_retries_total").inc()
-        entry.pop("compiled", None)
-        entry["recompile"] = True
-        entry["slack"] *= 2
+            n_over = int(overflow_h)
+            if n_over:
+                return None, n_over
+            # the execute bracket closed at t2 (device_get blocks
+            # until ready); record it as a span with the measured
+            # endpoints
+            tracer.begin("device.run", parent=span, t0=t1).end(t=t2)
+            with tracer.span("device.materialize"):
+                out = self._materialize(planned, row_h, outs_h,
+                                        entry["side"])
+            # ndslint: waive[NDS102] -- host materialize endpoint; the device.materialize span brackets the same region
+            t3 = _time.perf_counter()
+            with tracer.span("device.finish"):
+                # post-materialize allocator sample: results + scan
+                # buffers are all resident here, the per-query
+                # memory peak
+                memwatch.sample_device()
+                timings["execute_ms"] = (t2 - t1) * 1000
+                timings["materialize_ms"] = (t3 - t2) * 1000
+                side = entry.get("side") or {}
+                if side.get("ops_est"):
+                    timings["ops_est"] = float(side["ops_est"])
+                if side.get("kernels"):
+                    # dunder: a dict, not part of the numeric
+                    # timings vocabulary (engineTimings strips it;
+                    # report.py publishes it as the summary's
+                    # "kernels" block)
+                    timings["__kernels"] = dict(side["kernels"])
+                self._finalize_timings(timings, key)
         if span:
-            span.set(overflow_retry=True, slack=entry["slack"]).end()
-        nxt = self.execute_async(planned, key)
-        # engineTimings must report the FULL compile bill across retries
-        nxt.timings["compile_ms"] += timings.get("compile_ms", 0.0)
-        return self._finish(planned, key, nxt.entry, nxt.timings, nxt.t1,
-                            nxt.devs, attempt + 1, span=nxt.span)
+            # dunder keys are internal accounting state (e.g. the
+            # __live_bytes release token), not part of the
+            # published timings vocabulary
+            span.set(timings={k: v for k, v in timings.items()
+                              if not k.startswith("__")}).end()
+            self.last_query_span = span
+        return out, 0
 
     def _compile(self, planned: P.PlannedQuery,
                  slack: float = DEFAULT_SLACK):
@@ -1534,10 +1580,10 @@ class DeviceExecutor:
 
 class _AsyncResult:
     """Handle for an in-flight query: dispatch happened, completion and
-    materialization wait until result()."""
+    materialization wait until the first result()."""
 
     __slots__ = ("ex", "planned", "key", "entry", "timings", "t1",
-                 "devs", "span")
+                 "devs", "span", "out")
 
     def __init__(self, ex, planned, key, entry, timings, t1, devs,
                  span=None):
@@ -1551,9 +1597,10 @@ class _AsyncResult:
         self.span = span
 
     def result(self):
-        return self.ex._finish(self.planned, self.key, self.entry,
-                               self.timings, self.t1, self.devs,
-                               span=self.span)
+        if self.devs is not None:
+            self.out = self.ex._finish(self)
+            self.devs = None    # finished: the device outputs can go
+        return self.out
 
 
 class _Trace:

@@ -784,10 +784,11 @@ class ExecutionPipeline:
         # multi-rank worlds run synchronously: the per-query boundary
         # vote must fire in dispatch order on every rank, and the
         # compiled collective programs serialize execution anyway.
-        # The sharded placement is sync even single-process — the
-        # DistributedExecutor overrides execute() only, and the base
-        # executor's inherited execute_async would route it through
-        # the wrong compile path. Governed and depth-demoted queries
+        # The sharded placement is sync even single-process — one
+        # collective program is in flight a process (the
+        # DistributedExecutor's dispatch lock), so its execute_async
+        # finishes before it returns and there is nothing to
+        # pipeline. Governed and depth-demoted queries
         # run synchronously too — the per-query chunk-shrink /
         # prefetch-depth restores ride _run_ladder's finally
         if dispatch is None or placement in (CPU, SHARDED) \

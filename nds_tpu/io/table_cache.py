@@ -3,8 +3,8 @@
 Lets drivers generate a scale factor once and reuse it across runs —
 the reference's datagen-then-transcode lifecycle persists data on HDFS
 (`nds/nds_gen_data.py:130-180`); here the warehouse is local columnar
-files. Used by bench.py so the round benchmark never regenerates data
-it already has.
+files. Used by tools/compress_check.py, so a check never regenerates
+data it already has.
 """
 
 from __future__ import annotations

@@ -17,14 +17,16 @@ delegated to the engine (SURVEY.md §2.6). The TPU-native equivalent here:
   jit): collectives are inside the program, not host-driven.
 
 Exchange overflow (static bucket exceeded) is counted in-program and
-surfaced; execute() retries once with doubled slack — adaptive, never
-silent (utils.report.TaskFailureCollector records the retry).
+surfaced; the executor's overflow loop (DeviceExecutor._finish) goes
+round again with doubled slack — adaptive, never silent
+(utils.report.TaskFailureCollector records the retry).
 """
 
 from __future__ import annotations
 
-import contextlib
+import math
 import os
+import threading
 
 import numpy as np
 
@@ -37,16 +39,13 @@ from nds_tpu.analysis import locksan
 from nds_tpu.engine import device_exec as dx
 from nds_tpu.engine.device_exec import DCtx, DVal, DeviceExecError, _ok
 from nds_tpu.io.host_table import HostTable
-from nds_tpu.obs import memwatch
 from nds_tpu.obs import metrics as obs_metrics
-from nds_tpu.obs.trace import get_tracer
 from nds_tpu.parallel.exchange import (
     exchange, exchange_hierarchical, exchange_trace, hash_columns,
 )
 from nds_tpu.parallel.mesh import (
     DATA_AXIS, HOST_AXIS, make_mesh, pad_to_multiple,
 )
-from nds_tpu.resilience import faults
 from nds_tpu.sql import plan as P
 from nds_tpu.utils.report import TaskFailureCollector
 
@@ -67,10 +66,6 @@ def _pextreme(op, x, axes):
     scaled-int64 decimals and the overflow count are all s64). Same
     value on every platform."""
     return op(lax.all_gather(x, axes))
-
-# what a program's trace leaves beside its executable, persisted with
-# it (plan cache) so that a loaded program says the same of itself
-_SIDE_KEYS = ("dicts", "kernels", "ops_est", "exchange")
 
 # a group-by exchange whose key can take fewer values than this many a
 # device is sized at the local row count (`_DistTrace._run_aggregate`)
@@ -98,6 +93,10 @@ class DistributedExecutor(dx.DeviceExecutor):
     # stay identical, only the bytes win is forfeit (ROADMAP item 3
     # owns making multi-host first-class)
     COLUMNAR_UPLOAD = False
+
+    # a sharded program's result is read back as it is: no on-device
+    # compaction of it
+    COMPACT_MIN_ROWS = math.inf
 
     def __init__(self, tables: dict[str, HostTable], mesh=None,
                  n_devices: int | None = None,
@@ -130,6 +129,10 @@ class DistributedExecutor(dx.DeviceExecutor):
         self._explicit_shard = shard_tables
         self.shard_threshold = shard_threshold
         self.slack = slack
+        self._execute_attrs["devices"] = self.n_dev
+        # the thread whose program holds _DISPATCH_LOCK, launched and
+        # not yet read back (written under the lock, by its holder)
+        self._in_flight = None
         from nds_tpu.analysis import plan_verify
         if plan_verify.verify_enabled():
             # exchange static-shape contract: a slack below 1.0 or a
@@ -179,15 +182,69 @@ class DistributedExecutor(dx.DeviceExecutor):
         ``device.bind`` span's ``uploads`` / ``upload_bytes``)."""
         return self._to_device(arr, lambda a: self._dev(a, sharded))
 
-    def _launch(self, tracer, kind, compiled, *args, attrs=None):
+    # beside the base's: the exchange totals of the program's trace,
+    # and which of its buffer keys are sharded / replicated
+    SIDE_KEYS = dx.DeviceExecutor.SIDE_KEYS + ("exchange", "sk", "rk")
+
+    def _devices(self):
+        return self.mesh.devices.flat
+
+    def _call_args(self, side, bufs, pvals) -> tuple:
+        return ({k: bufs[k] for k in side["sk"]},
+                {k: bufs[k] for k in side["rk"]})
+
+    def _launch_program(self, tracer, entry, bufs, pvals):
         """``device.launch`` of a sharded program: its exchange totals
         ride the span, and count."""
-        if attrs:
+        side = entry["side"]
+        xc = side.get("exchange")
+        if xc:
             obs_metrics.counter("exchange_rows_total").inc(
-                attrs["exchange_rows"])
+                xc["exchange_rows"])
             obs_metrics.counter("exchange_bytes_total").inc(
-                attrs["exchange_bytes"])
-        return super()._launch(tracer, kind, compiled, *args, attrs=attrs)
+                xc["exchange_bytes"])
+        # one collective program in flight per process: two host
+        # threads launching multi-device programs can enqueue them on
+        # the devices in different orders, and the collectives then
+        # wait on each other for ever. Held from the launch to the end
+        # of the read-back, the one arrangement with a record on four
+        # chips (PERF.md section 7); compiles, plan-cache loads and
+        # binds stay outside it
+        _DISPATCH_LOCK.acquire()
+        self._in_flight = threading.get_ident()
+        return self._launch(tracer, type(self).__name__,
+                            entry["compiled"],
+                            *self._call_args(side, bufs, pvals), attrs=xc)
+
+    def _landed(self) -> None:
+        """Give the dispatch lock back, if this thread's program still
+        holds it."""
+        if self._in_flight == threading.get_ident():
+            self._in_flight = None
+            _DISPATCH_LOCK.release()
+
+    def _read_outputs(self, tracer, devs) -> tuple:
+        """One batched device->host round trip of the program's four
+        outputs; the overflow and the skew ride the span."""
+        try:
+            row_h, outs_h, overflow_h, skew_h = self._readback(
+                tracer, devs, describe=lambda host: {
+                    "overflow_rows": int(host[2]),
+                    "skew": float(host[3])})
+        finally:
+            self._landed()
+        if float(skew_h) > 0:
+            # worst per-shuffle destination skew this program saw:
+            # visible in live snapshots before it becomes a
+            # straggler (README "Fleet & profiling")
+            obs_metrics.gauge("exchange_skew_ratio").set(
+                round(float(skew_h), 4))
+        return row_h, outs_h, overflow_h
+
+    def _dispatch_over(self, timings: dict) -> None:
+        # a raise between the launch and the end of the read-back
+        self._landed()
+        super()._dispatch_over(timings)
 
     # buffers: sharded tables pad to a multiple of n_dev
     def _upload(self, bufs: dict, table: str, name: str) -> None:
@@ -237,49 +294,45 @@ class DistributedExecutor(dx.DeviceExecutor):
             self._buffers[key] = self._place(live, sharded)
         bufs[key] = self._buffers[key]
 
-    def _compile(self, planned: P.PlannedQuery):
-        side = {}
+    def _compile(self, planned: P.PlannedQuery,
+                 slack: float = dx.DeviceExecutor.DEFAULT_SLACK):
+        sharded_keys, repl_keys = self._split_keys(planned)
+        side = {"sk": sharded_keys, "rk": repl_keys}
 
-        def make(slack):
-            def fn(shard_bufs, repl_bufs):
-                tr = _DistTrace(self, {**shard_bufs, **repl_bufs}, slack)
-                # what the program's shuffles are is collected at trace
-                # time (parallel/exchange.exchange_trace): the static
-                # totals ride `side` to the device.launch span, and the
-                # program returns the worst destination skew so the
-                # executor can publish the exchange_skew_ratio gauge
-                # host-side — an output, not a debug callback, so the
-                # executable still serializes into the AOT plan cache
-                with exchange_trace() as xt:
-                    row, outs, dicts = tr.run_query(planned)
-                side["dicts"] = dicts
-                side["kernels"] = tr.kernel_counts()
-                side["ops_est"] = int(tr.ops_est)
-                side["exchange"] = xt.stats()
-                overflow = tr.total_overflow()
-                if xt.skews:
-                    skew = xt.skews[0]
-                    for s in xt.skews[1:]:
-                        skew = jnp.maximum(skew, s)
-                    # every device sees every exchange; the fleet-wide
-                    # worst is the gauge's value
-                    skew = lax.pmax(skew, tr.axes)
-                else:
-                    skew = jnp.zeros((), jnp.float32)
-                return row, outs, overflow, skew
-            return fn
+        def fn(shard_bufs, repl_bufs):
+            tr = _DistTrace(self, {**shard_bufs, **repl_bufs}, slack)
+            # what the program's shuffles are is collected at trace
+            # time (parallel/exchange.exchange_trace): the static
+            # totals ride `side` to the device.launch span, and the
+            # program returns the worst destination skew so the
+            # executor can publish the exchange_skew_ratio gauge
+            # host-side — an output, not a debug callback, so the
+            # executable still serializes into the AOT plan cache
+            with exchange_trace() as xt:
+                row, outs, dicts = tr.run_query(planned)
+            side["dicts"] = dicts
+            side["kernels"] = tr.kernel_counts()
+            side["ops_est"] = int(tr.ops_est)
+            side["exchange"] = xt.stats()
+            overflow = tr.total_overflow()
+            if xt.skews:
+                skew = xt.skews[0]
+                for s in xt.skews[1:]:
+                    skew = jnp.maximum(skew, s)
+                # every device sees every exchange; the fleet-wide
+                # worst is the gauge's value
+                skew = lax.pmax(skew, tr.axes)
+            else:
+                skew = jnp.zeros((), jnp.float32)
+            return row, outs, overflow, skew
 
-        def build(slack):
-            sharded_keys, repl_keys = self._split_keys(planned)
-            wrapped = shard_map(
-                make(slack), mesh=self.mesh,
-                in_specs=({k: P_(self.axes) for k in sharded_keys},
-                          {k: P_() for k in repl_keys}),
-                out_specs=P_())
-            # ndslint: waive[NDS111] -- builds the traced callable only; AOT lower+compile routes through cache.aot in _execute_traced
-            return jax.jit(wrapped), sharded_keys, repl_keys
-
-        return build, side
+        wrapped = shard_map(
+            fn, mesh=self.mesh,
+            in_specs=({k: P_(self.axes) for k in sharded_keys},
+                      {k: P_() for k in repl_keys}),
+            out_specs=P_())
+        # ndslint: waive[NDS111] -- builds the traced callable only; AOT lower+compile routes through cache.aot (_compile_or_load)
+        return jax.jit(wrapped), side
 
     # ------------------------------------------------- plan cache (AOT)
 
@@ -296,69 +349,15 @@ class DistributedExecutor(dx.DeviceExecutor):
         })
         return parts
 
-    def _cache_for_sharded(self, planned, slack: float):
-        """Plan-cache handle for the sharded program — single-process
-        worlds only: a multi-controller executable spans every rank's
-        devices, and per-rank deserialization against a local client
-        is not a supported jax path. Multi-process runs fall back to
-        jax's own persistent XLA cache (utils/xla_cache.py)."""
+    def _plan_fingerprint(self, planned, slack: float):
+        """Single-process worlds only: a multi-controller executable
+        spans every rank's devices, and per-rank deserialization
+        against a local client is not a supported jax path.
+        Multi-process runs fall back to jax's own persistent XLA cache
+        (utils/xla_cache.py)."""
         if self.multiprocess:
             return None, None
-        return self._plan_fingerprint(planned, slack)
-
-    def _load_cached_sharded(self, planned, slack, state, side,
-                             timings, tracer) -> bool:
-        """Fill state[jitted/sk/rk] + side[dicts] from a verified
-        plan-cache hit; False on miss (compile as always). The
-        (cache, fingerprint) handle is stashed on ``state`` for
-        ``_persist_sharded`` — the fingerprint hashes the whole plan
-        tree, so a miss must not pay it twice."""
-        from nds_tpu.cache import aot as cache_aot
-        from nds_tpu.obs import metrics as obs_metrics
-        pc, fp = self._cache_for_sharded(planned, slack)
-        state["cache_handle"] = (pc, fp)
-        if not fp:
-            return False
-        # the hit/miss verdict is counted HERE, after the sharded
-        # key-split compat check load_cached cannot run itself
-        with tracer.span("cache.load", fp=fp[:12]):
-            bufs, _pvals = self._bind(planned, tracer)
-            hit = cache_aot.load_cached(
-                pc, fp, type(self).__name__, timings, count=False,
-                devices=self.mesh.devices.flat)
-        if hit is None:
-            return False
-        compiled, extra = hit
-        sk, rk = extra.get("sk"), extra.get("rk")
-        ok = sk is not None and rk is not None
-        if ok and not cache_aot.call_compatible(
-                compiled,
-                {k: bufs[k] for k in sk if k in bufs},
-                {k: bufs[k] for k in rk if k in bufs}):
-            from nds_tpu.cache.store import _warn
-            _warn(f"sharded entry {fp[:12]}… is "
-                  f"signature-incompatible; recompiling fresh")
-            ok = False
-        obs_metrics.counter(
-            "compile_cache_hits_total" if ok
-            else "compile_cache_misses_total").inc()
-        if not ok:
-            return False
-        state["jitted"], state["sk"], state["rk"] = compiled, sk, rk
-        for k in _SIDE_KEYS:
-            side[k] = extra.get(k)
-        return True
-
-    def _persist_sharded(self, planned, slack, state, side) -> None:
-        from nds_tpu.cache import aot as cache_aot
-        pc, fp = state.pop("cache_handle", (None, None))
-        if fp:
-            cache_aot.persist(pc, fp, type(self).__name__,
-                              state["jitted"],
-                              {"sk": state["sk"], "rk": state["rk"],
-                               **{k: side.get(k) for k in _SIDE_KEYS}},
-                              meta={"slack": slack},
-                              devices=self.mesh.devices.flat)
+        return super()._plan_fingerprint(planned, slack)
 
     # survivor cap for turning a SHARDED filtered scan into a
     # replicated reduced build side (the broadcast-join move Spark AQE
@@ -417,216 +416,38 @@ class DistributedExecutor(dx.DeviceExecutor):
     STAGE_WEIGHT = int(os.environ.get("NDS_TPU_STAGE_DIST", "24"))
 
     def _plan_for_dispatch(self, planned):
-        """Parameterized plans run INLINED on the sharded path (both
-        execute() and the inherited execute_async): sharded programs
-        bake literals into their traced collectives, and the
+        """Parameterized plans run INLINED on the sharded path: sharded
+        programs bake literals into their traced collectives, and the
         multi-rank story (rank-local binding would have to agree
         across ranks) is not built yet."""
         from nds_tpu.sql import params as sqlparams
         return sqlparams.inline(planned)
 
-    def execute(self, planned: P.PlannedQuery, key: object = None):
-        """Multichip execute with the SAME timing contract as the
-        single-chip executor: compile/execute/materialize wall-clock,
-        bytes_scanned and the roofline fields land in last_timings and
-        the query span, and the staged sub-program bill folds in after
-        materialize (the round-5 advisor finding: multichip queries
-        silently dropped their bill)."""
-        faults.fault_point("device.execute",
-                           executor=type(self).__name__)
-        from nds_tpu.resilience import watchdog
-        watchdog.beat("engine", phase="device.execute",
-                      executor=type(self).__name__)
-        planned = self._plan_for_dispatch(planned)
-        key = key if key is not None else id(planned)
-        orig = planned
-        tracer = get_tracer()
-        # a failed query must never inherit the previous query's span
-        self.last_query_span = None
-        qspan = tracer.begin("device.execute",
-                             executor=type(self).__name__,
-                             devices=self.n_dev)
-        with tracer.attach(qspan):
-            try:
-                out, timings = self._execute_traced(planned, orig, key,
-                                                    tracer)
-            except BaseException as exc:
-                # a staged sub's span must not survive as the failed
-                # query's (subs set last_query_span on their success)
-                self.last_query_span = None
-                # release the attempt's accounted scan bytes (success
-                # and overflow paths release inline by popping the same
-                # token, so this covers ONLY raises between the add and
-                # either release — never a second release)
-                memwatch.sub_live(
-                    (self.last_timings or {}).pop("__live_bytes", 0.0))
-                qspan.set(error=f"{type(exc).__name__}: {exc}").end()
-                raise
-        qspan.set(timings=dict(timings)).end()
-        self.last_query_span = qspan or None
-        return out
+    def execute_async(self, planned: P.PlannedQuery, key: object = None):
+        """Finished before it returns: the statement holds the
+        dispatch lock from its launch to the end of its read-back, and
+        a caller who dispatched twice before collecting would wait on
+        itself."""
+        handle = super().execute_async(planned, key)
+        handle.result()
+        return handle
 
-    def _entry(self, planned, orig, key) -> tuple:
-        """The compile-cache entry of ``key`` (LRU, bounded)."""
-        if key not in self._compiled:
-            while len(self._compiled) >= self.MAX_COMPILED:
-                old = next(iter(self._compiled))
-                self._compiled.pop(old)
-                # staged-plan state pins its plan through _compiled's
-                # strong ref; evict them together or a recycled id()
-                # can serve another query's staged split
-                self._evict_query_state(old)
-            # strong refs: the CALLER'S plan pins the id()-key, the
-            # staged main plan is what actually compiled (base executor
-            # rationale)
-            self._compiled[key] = (self._compile(planned), {},
-                                   (orig, planned))
-        else:
-            # LRU refresh: move the hit to the back of the dict order
-            self._compiled[key] = self._compiled.pop(key)
-        return self._compiled[key]
+    # three executions: the base slack, twice that, four times that
+    OVERFLOW_RETRIES = 2
+    OVERFLOW_WHAT = "exchange overflow"
 
-    def _compile_or_load_sharded(self, planned, slack, build, state, side,
-                         timings, tracer, retry: bool) -> None:
-        """Fill ``state['jitted'/'sk'/'rk']`` for this slack: a verified
-        plan-cache hit (zero compiles this process, ``cache_load_ms``
-        carries the deserialize cost) or a compile through the one
-        funnel, persisted for the next process."""
+    def _note_overflow(self, n_over: int, slack: float) -> None:
         import gc
-        import time as _time
-        from nds_tpu.cache import aot as cache_aot
-        # free the previous slack's executable BEFORE compiling the
-        # bigger one: the 8-way compiled forms of wide plans are GBs
+        TaskFailureCollector.notify(
+            f"exchange overflow ({n_over} rows) at slack="
+            f"{slack}; retrying with slack={slack * 2}")
+        obs_metrics.counter("exchange_overflow_retries_total").inc()
+        obs_metrics.counter("exchange_overflow_rows_total").inc(n_over)
+        # the previous slack's executable goes BEFORE the bigger one
+        # compiles: the 8-way compiled forms of wide plans are GBs
         # each, and holding both was the difference between fitting
         # and OOM on the virtual mesh (q72's slack-2 -> slack-4 retry)
-        state.pop("jitted", None)
         gc.collect()
-        if not self._load_cached_sharded(planned, slack, state, side,
-                                         timings, tracer):
-            # ndslint: waive[NDS102] -- raw bracket feeds compile_ms; the span records it too
-            t0 = _time.perf_counter()
-            with tracer.span("device.compile", slack=slack):
-                jitted, state["sk"], state["rk"] = build(slack)
-                bufs, _pvals = self._bind(planned, tracer)
-                # AOT-compile (single-chip contract): compile cost is
-                # attributed apart from the execute bracket, not hidden
-                # in the first timed call
-                state["jitted"] = cache_aot.lower_and_compile(
-                    jitted,
-                    {k: bufs[k] for k in state["sk"]},
-                    {k: bufs[k] for k in state["rk"]},
-                    fresh=cache_aot.fresh_for(*state.get(
-                        "cache_handle", (None, None))),
-                    kind=type(self).__name__)
-            timings["compile_ms"] += (
-                # ndslint: waive[NDS102,NDS103] -- .compile() is synchronous; bracket ends when it returns, no device work is in flight here
-                _time.perf_counter() - t0) * 1000
-            obs_metrics.counter("recompiles_total" if retry
-                                else "compiles_total").inc()
-            self._persist_sharded(planned, slack, state, side)
-        state["slack"] = slack
-
-    def _execute_traced(self, planned, orig, key, tracer):
-        """The statement's span tree is the single-device one
-        (``device.dispatch`` > ``device.bind`` / ``device.launch``,
-        then ``device.readback``, ``device.materialize``,
-        ``device.finish``), through the same helpers; an exchange
-        overflow goes round again at doubled slack, a recompile."""
-        import time as _time
-        # the slack loop rides the shared resilience policy (no backoff
-        # sleep: each retry already pays a full recompile; policy built
-        # by the pipeline module — the single home of engine retry
-        # wiring)
-        from nds_tpu.engine.scheduler import adaptive_policy
-        kind = type(self).__name__
-        timings = {"compile_ms": 0.0}
-        self.last_timings = timings
-        entry = None
-        for attempt in adaptive_policy(3).attempts():
-            with contextlib.ExitStack() as in_flight:
-                with tracer.span("device.dispatch"):
-                    if entry is None:
-                        planned = self._staged_effective(planned, key)
-                        entry = self._entry(planned, orig, key)
-                        (build, side), state, _ref = entry
-                        slack = state.get("slack", self.slack)
-                    if ("jitted" not in state
-                            or state.get("slack") != slack):
-                        self._compile_or_load_sharded(
-                            planned, slack, build, state, side, timings,
-                            tracer, retry=attempt > 0)
-                    bufs, _pvals = self._bind(planned, tracer)
-                    timings["bytes_scanned"] = float(
-                        sum(b.nbytes for b in bufs.values()))
-                    self._attach_delta(timings, planned)
-                    obs_metrics.counter("device_executions_total").inc()
-                    obs_metrics.counter("bytes_scanned_total").inc(
-                        timings["bytes_scanned"])
-                    # memory HWM (obs/memwatch): accounted scan bytes go
-                    # live for this attempt; device stats dominate when
-                    # available. __live_bytes is the pop-once release
-                    # token (a failure after an inline release must not
-                    # release twice)
-                    memwatch.add_live(timings["bytes_scanned"])
-                    timings["__live_bytes"] = timings["bytes_scanned"]
-                    memwatch.sample_device()
-                    # one collective program in flight per process: two
-                    # host threads launching multi-device programs can
-                    # enqueue them on the devices in different orders,
-                    # and the collectives then wait on each other for
-                    # ever. Held from the launch to the end of the
-                    # read-back, the one arrangement with a record on
-                    # four chips (PERF.md section 7); compiles and binds
-                    # stay outside it
-                    in_flight.enter_context(_DISPATCH_LOCK)
-                    t1, devs = self._launch(
-                        tracer, kind, state["jitted"],
-                        {k: bufs[k] for k in state["sk"]},
-                        {k: bufs[k] for k in state["rk"]},
-                        attrs=side.get("exchange"))
-                # one batched device->host round trip (see
-                # DeviceExecutor)
-                row_h, outs_h, overflow_h, skew_h = self._readback(
-                    tracer, devs, describe=lambda host: {
-                        "overflow_rows": int(host[2]),
-                        "skew": float(host[3])})
-            # ndslint: waive[NDS102] -- bracket endpoint after device_get; becomes the device.run span
-            t2 = _time.perf_counter()
-            if float(skew_h) > 0:
-                # worst per-shuffle destination skew this program saw:
-                # visible in live snapshots before it becomes a
-                # straggler (README "Fleet & profiling")
-                obs_metrics.gauge("exchange_skew_ratio").set(
-                    round(float(skew_h), 4))
-            n_over = int(overflow_h)
-            if n_over == 0:
-                tracer.begin("device.run", t0=t1).end(t=t2)
-                with tracer.span("device.materialize"):
-                    out = self._materialize(planned, row_h, outs_h,
-                                            side)
-                # ndslint: waive[NDS102] -- host materialize endpoint bracketed by the device.materialize span
-                t3 = _time.perf_counter()
-                with tracer.span("device.finish"):
-                    memwatch.sample_device()
-                    memwatch.sub_live(timings.pop("__live_bytes", 0.0))
-                    timings["execute_ms"] = (t2 - t1) * 1000
-                    timings["materialize_ms"] = (t3 - t2) * 1000
-                    if side.get("ops_est"):
-                        timings["ops_est"] = float(side["ops_est"])
-                    if side.get("kernels"):
-                        timings["__kernels"] = dict(side["kernels"])
-                    self._finalize_timings(timings, key)
-                return out, timings
-            memwatch.sub_live(timings.pop("__live_bytes", 0.0))
-            TaskFailureCollector.notify(
-                f"exchange overflow ({n_over} rows) at slack="
-                f"{slack}; retrying with slack={slack * 2}")
-            obs_metrics.counter("exchange_overflow_retries_total").inc()
-            obs_metrics.counter("exchange_overflow_rows_total").inc(
-                n_over)
-            obs_metrics.counter("slack_retries_total").inc()
-            slack = slack * 2
-        raise DeviceExecError("exchange overflow persisted after retries")
 
 
 def _rows(ctx: DCtx) -> int:

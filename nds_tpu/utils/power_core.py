@@ -233,8 +233,8 @@ def prepare_engine(config: EngineConfig) -> None:
             multiproc = multihost.maybe_initialize()
         require_accelerator(backend)
         if active_cache is None or multiproc or active_cache.readonly:
-            # compiles amortize across driver invocations (same cache
-            # bench.py uses); harmless for repeated in-process queries.
+            # compiles amortize across driver invocations (jax's
+            # persistent cache); harmless for repeated in-process queries.
             # Multi-rank worlds keep this EVEN with a plan cache: the
             # plan cache refuses multi-controller sharded programs
             # (per-rank deserialization against a local client is not

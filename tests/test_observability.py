@@ -375,7 +375,12 @@ class TestTimingsParity:
         ex = sess._executor_factory(sess.tables)
         tm = obs.query_timings(ex)
         assert tm.get("staged_programs", 0) >= 1
-        assert tm == ex.last_timings
+        # a statement's span carries the published vocabulary only, on
+        # every executor (one skeleton sets it); the dunder keys
+        # (``__kernels``, which report.attach_kernels reads) stay in
+        # last_timings alone
+        assert tm == {k: v for k, v in ex.last_timings.items()
+                      if not k.startswith("__")}
         assert not ex._stage_timings  # bill consumed, no leak
         assert len(ex.last_query_span.find("stage.sub")) >= 1
 

@@ -138,9 +138,11 @@ def _num(v) -> bool:
 # name -> the parents it may hang from in a statement's tree. A name
 # not listed here (sql.*, stage.*, cache.load, compile.*, load.*,
 # chunk.*, exchange.*, a tool's own) may hang anywhere. A sharded
-# statement (dist_exec) has the single-device tree: its retry at doubled
-# slack adds a second device.dispatch / device.readback pair under the
-# same device.execute. Attributes the catalogue names beyond the README's
+# statement (dist_exec) has the single-device tree; a retry at doubled
+# slack, on either executor, adds a second device.dispatch /
+# device.readback pair under the same device.execute. A chunked
+# statement's per-chunk programs launch and read back under their
+# chunk.* span. Attributes the catalogue names beyond the README's
 # table: device.launch carries exchanges / resized / exchange_rows /
 # exchange_bytes for a sharded program, device.readback overflow_rows /
 # skew.
@@ -154,8 +156,10 @@ SPAN_PARENTS = {
     "device.dispatch": ("device.execute",),
     "device.compile": ("device.dispatch", "device.execute"),
     "device.bind": ("device.dispatch", "device.compile", "cache.load"),
-    "device.launch": ("device.dispatch", "device.execute"),
-    "device.readback": ("device.execute",),
+    "device.launch": ("device.dispatch", "device.execute",
+                      "chunk.partial_agg", "chunk.reduce"),
+    "device.readback": ("device.execute", "chunk.partial_agg",
+                        "chunk.reduce"),
     "device.run": ("device.execute",),
     "device.materialize": ("device.execute",),
     "device.finish": ("device.execute",),
