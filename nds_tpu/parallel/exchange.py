@@ -18,6 +18,16 @@ none, so a relation that has been through an exchange still holds
 slack=2 covers real skew; rows that overflow a bucket are dropped AND
 counted — the executor surfaces the count so the host can retry with a
 bigger slack (adaptive, one recompile, never silent).
+
+How the send buffer is filled: ONE stable int32 sort groups the rows by
+destination (dead rows last), so slot ``r`` of peer ``d``'s bucket is
+row ``order[bounds[d] + r]`` while ``r < min(count[d], bucket)``
+(``bounds``: where each destination's rows start in the sorted order).
+One int32 index array a shuffle (``src``, shape ``(n_dev, bucket)``)
+says so, and every payload column is READ into place by one gather
+through it, zeros behind the kept rows. Nothing is scattered: on the TPU
+an ``at[].set`` of a 64-bit column costs 80 ns an update, a gather 6-10
+ns a 32-bit word (PERF.md section 5).
 """
 
 from __future__ import annotations
@@ -47,22 +57,27 @@ _SINK: "ExchangeTrace | None" = None
 
 class ExchangeTrace:
     """What one program's trace says of its exchanges: the traced skew
-    scalars, and four static totals. ``rows`` is the bucket capacity a
+    scalars, and five static totals. ``rows`` is the bucket capacity a
     chip sends (``n_dev x bucket``) and ``nbytes`` the bytes it hands
     to ``all_to_all`` (every payload array and the ``ok`` mask at that
     capacity), each summed over the program's exchanges: what ONE chip
     moves in ONE run of the program. ``resized`` counts the exchanges
     whose bucket is smaller than the buffer's capacity would make it:
-    sized from the relation's row bound."""
+    sized from the relation's row bound. ``send_words`` is the 32-bit
+    words a chip GATHERS into its send buffers: every payload array at
+    that capacity, and the one int32 index array a shuffle they are
+    read through."""
 
     def __init__(self):
         self.skews: list = []
         self.count = self.resized = self.rows = self.nbytes = 0
+        self.send_words = 0
 
     def stats(self) -> dict:
         """The ``device.launch`` attributes of a sharded program."""
         return {"exchanges": self.count, "resized": self.resized,
-                "exchange_rows": self.rows, "exchange_bytes": self.nbytes}
+                "exchange_rows": self.rows, "exchange_bytes": self.nbytes,
+                "send_words": self.send_words}
 
 
 @contextlib.contextmanager
@@ -154,68 +169,59 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     if bucket is None:
         bucket = by_capacity
     # dead rows get a sentinel dest PAST every real bucket so they never
-    # consume rank slots (a heavily filtered shard must not overflow its
-    # own bucket with corpses)
+    # take a slot (a heavily filtered shard must not overflow its own
+    # bucket with corpses) and sort behind every live row
     dest = jnp.where(ok, dest, jnp.int32(n_dev))
-    # stable-group rows by destination. Explicit int32 iota operand:
-    # jnp.argsort would carry an int64 index operand under x64, pushing
-    # the whole shuffle-grouping sort onto the TPU's emulated 64-bit
-    # path (NDS112 — same trap as device_exec._build_lookup)
+    # stable-group rows by destination, and keep BOTH outputs: the
+    # sorted key is read below, never re-gathered through the
+    # permutation. Explicit int32 iota operand: jnp.argsort would carry
+    # an int64 index operand under x64, pushing the whole
+    # shuffle-grouping sort onto the TPU's emulated 64-bit path (NDS112
+    # — same trap as device_exec._build_lookup)
     iota = jnp.arange(n, dtype=jnp.int32)
-    _, order = lax.sort([dest, iota], num_keys=1, is_stable=True)
-    dest_s = jnp.take(dest, order)
-    ok_s = jnp.take(ok, order)
-    # per-destination boundaries: [:-1] are the bucket starts the rank
-    # derivation needs; the full fencepost vector also yields the
-    # per-destination row COUNTS behind the skew gauge below
+    dest_s, order = lax.sort([dest, iota], num_keys=1, is_stable=True)
+    # per-destination fenceposts: [:-1] are the rows each bucket starts
+    # at, the differences the per-destination row COUNTS (overflow, the
+    # skew gauge). bounds[-1] counts the live rows
     bounds = jnp.searchsorted(dest_s,
                               jnp.arange(n_dev + 1, dtype=jnp.int32))
-    first_of_dest = bounds[:-1]
+    counts = bounds[1:] - bounds[:-1]
+    kept = jnp.minimum(counts, bucket)
+    n_overflow = jnp.sum(counts - kept)
     if _SINK is not None:
         capacity = n_dev * bucket
+        widths = [a.dtype.itemsize * math.prod(a.shape[1:]) for a in arrays]
         _SINK.count += 1
         _SINK.resized += bucket < by_capacity
         _SINK.rows += capacity
-        _SINK.nbytes += capacity * (1 + sum(    # 1: the bool ok mask
-            a.dtype.itemsize * math.prod(a.shape[1:]) for a in arrays))
+        _SINK.nbytes += capacity * (1 + sum(widths))   # 1: the bool ok mask
+        _SINK.send_words += capacity * (               # 1: the index array
+            1 + sum(-(-w // 4) for w in widths))
         # partition-skew visibility (README "Fleet & profiling"):
-        # max/mean valid rows per destination for THIS shuffle — the
+        # max/mean live rows per destination for THIS shuffle — the
         # signal that a key distribution is loading one device before
-        # it becomes a straggler. bounds[-1] counts the valid rows
-        # (dead rows carry the sentinel dest and sort past every
-        # real bucket)
-        counts = (bounds[1:] - bounds[:-1]).astype(jnp.float32)
+        # it becomes a straggler
         total = bounds[-1].astype(jnp.float32)
         ratio = jnp.where(
             total > 0,
-            jnp.max(counts) / jnp.maximum(total / n_dev, 1e-9),
+            jnp.max(counts).astype(jnp.float32)
+            / jnp.maximum(total / n_dev, 1e-9),
             jnp.float32(1.0))
         _SINK.skews.append(ratio)
-    rank = iota - jnp.take(first_of_dest,
-                           jnp.clip(dest_s, 0, n_dev - 1))
-    overflow = ok_s & (rank >= bucket)
-    n_overflow = jnp.sum(overflow)
-    keep = ok_s & (rank < bucket)
-    # kept rows get unique slots; everything else lands in a trash slot
-    # past the buffer (sliced off below) so it can't clobber a kept row
-    trash = n_dev * bucket
-    slot = jnp.where(keep, dest_s * bucket + jnp.clip(rank, 0, bucket - 1),
-                     trash)
-
-    def scatter(vals_sorted, fill):
-        buf = jnp.full((n_dev * bucket + 1,), fill, dtype=vals_sorted.dtype)
-        return buf.at[slot].set(vals_sorted)[:-1]
-
-    send_ok = jnp.zeros((n_dev * bucket + 1,), dtype=bool).at[slot].set(
-        keep)[:-1]
-    out_ok = lax.all_to_all(
-        send_ok.reshape(n_dev, bucket), axis, 0, 0).reshape(-1)
+    # slot r of peer d's bucket holds sorted row bounds[d] + r while
+    # r < kept[d]: the one gather through the sort's permutation, int32,
+    # once a shuffle. Slots past the kept rows read some row in bounds
+    # (clipped) and are zeroed; rows ranked at or past the bucket are
+    # the overflow counted above, read by no slot
+    r = jnp.arange(bucket, dtype=jnp.int32)[None, :]
+    live = r < kept[:, None]
+    src = jnp.take(order, bounds[:-1, None] + r, mode="clip")
+    out_ok = lax.all_to_all(live, axis, 0, 0).reshape(-1)
     outs = []
     for a in arrays:
-        a_s = jnp.take(a, order, axis=0)
-        sent = scatter(a_s, jnp.zeros((), a.dtype))
-        outs.append(lax.all_to_all(
-            sent.reshape(n_dev, bucket), axis, 0, 0).reshape(-1))
+        sent = jnp.where(live, jnp.take(a, src, axis=0),
+                         jnp.zeros((), a.dtype))
+        outs.append(lax.all_to_all(sent, axis, 0, 0).reshape(-1))
     return outs, out_ok, n_overflow
 
 

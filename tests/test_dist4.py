@@ -5,7 +5,8 @@ the benchmark's plain references by the benchmark's own comparison, a
 planted exchange fault, the sharded statement's span tree, PR 26's
 top-N path under sharding, and PR 28's rule that a sharded relation's
 capacity follows the rows it can hold (``slack x rows``) through any
-number of exchanges, a wide group key hashed instead of replicated.
+number of exchanges, a wide group key hashed instead of replicated,
+and PR 30's send buffer, read into place by one gather a column.
 """
 
 import os
@@ -92,10 +93,18 @@ def dist4(raw, tmp_path_factory):
     session = _session(raw, shards=4, cache_dir=cache)
     pipe = session._executor_factory(session.tables)
     out = {"session": session, "cache": str(cache), "records": {},
-           "first": {}, "warm": {}}
+           "first": {}, "warm": {}, "lowered": []}
+    from nds_tpu.cache import aot
+    compile_ = aot.lower_and_compile
+
+    def keep_text(jitted, *args, **kw):
+        out["lowered"].append(jitted.lower(*args).as_text(debug_info=True))
+        return compile_(jitted, *args, **kw)
+
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("NDS_TPU_TRACE",
                   str(tmp_path_factory.mktemp("dist4_trace") / "t.jsonl"))
+        mp.setattr(aot, "lower_and_compile", keep_text)
         for stmt in _statements():
             for which in ("first", "warm"):
                 rec = run.run_statement(session, stmt)
@@ -267,7 +276,8 @@ def test_attributes_survive_a_plan_cache_load(dist4, raw):
     assert "cache.load" in _names(root.find("device.dispatch")[0].children)
     (loaded,) = root.find("device.launch")
     (traced,) = dist4["warm"]["q3"].find("device.launch")
-    for key in ("exchanges", "exchange_rows", "exchange_bytes"):
+    for key in ("exchanges", "exchange_rows", "exchange_bytes",
+                "send_words"):
         assert loaded.attrs[key] == traced.attrs[key] > 0
     assert _verdict([rec], raw)["correct"] is True
 
@@ -626,3 +636,140 @@ def test_hash_of_key_columns_separates_null_from_zero():
                                        (a.astype(jnp.int32), None)]))
     assert swapped[0] != np.asarray(hash_columns(
         [(a, None), (b, None)]))[0]
+
+
+# ---- PR 30: the send buffer is read into place, nothing is scattered ----
+
+def _ops_from(text, op, source):
+    """(result name, enclosing function's text) of every region-bearing
+    ``stablehlo.<op>`` of a lowered program (``debug_info=True``) whose
+    location chain leads to the file ``source``."""
+    import re
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    seen = {}
+
+    def leads_there(loc):
+        if loc not in seen:
+            seen[loc] = False       # a cycle is no way there
+            body = locs.get(loc, "")
+            seen[loc] = source in body or any(
+                leads_there(ref) for ref in re.findall(r"#loc\d+", body))
+        return seen[loc]
+
+    found = []
+    for body in re.split(r"^\s*func\.func ", text, flags=re.M)[1:]:
+        for m in re.finditer(
+                r'^( *)(%\w+)(?::\d+)? = "stablehlo\.' + op + r'"\(.*?'
+                r"^\1\}\) : [^\n]* loc\((#loc\d+)\)$",
+                body, re.M | re.S):
+            if leads_there(m.group(3)):
+                found.append((m.group(2), body))
+    return found
+
+
+def test_send_buffer_is_gathered_not_scattered(dist4):
+    """The mechanism, from the programs as lowered: no scatter comes
+    from ``parallel/exchange.py`` (the parent's q3 held fifteen), every
+    exchange's one sort has its sorted key READ (the parent dropped it
+    and gathered ``dest`` through the permutation again), and the launch
+    span says how many words were gathered into send buffers."""
+    source = "nds_tpu/parallel/exchange.py"
+    texts = dist4["lowered"]
+    assert len(texts) == 4                  # one program a statement
+    exchanges = 0
+    for text in texts:
+        assert source in text and "stablehlo.all_to_all" in text
+        assert _ops_from(text, "scatter", source) == []
+        sorts = _ops_from(text, "sort", source)
+        exchanges += len(sorts)
+        for result, body in sorts:
+            assert f"{result}#0" in body and f"{result}#1" in body
+    launches = {name: root.find("device.launch")[0].attrs
+                for name, root in dist4["warm"].items()}
+    assert exchanges == sum(a["exchanges"] for a in launches.values())
+    for attrs in launches.values():
+        # the index array and at least one 32-bit payload word a slot
+        assert attrs["send_words"] >= 2 * attrs["exchange_rows"]
+
+
+def _partition_reference(arrays, dest, ok, n_dev, bucket):
+    """What ``exchange_by_dest`` has to deliver, in plain numpy: sender
+    ``s`` gives peer ``d`` its live rows bound for ``d`` in input order,
+    cut at ``bucket`` and zero-filled; device ``d`` holds the buckets of
+    senders 0..n_dev-1 side by side. Rows cut are the sender's
+    overflow."""
+    outs = [np.zeros((n_dev, n_dev, bucket), a.dtype) for a in arrays]
+    out_ok = np.zeros((n_dev, n_dev, bucket), bool)
+    overflow = np.zeros(n_dev, np.int64)
+    for s in range(n_dev):
+        for d in range(n_dev):
+            rows = np.flatnonzero(ok[s] & (dest[s] == d))
+            overflow[s] += max(0, len(rows) - bucket)
+            rows = rows[:bucket]
+            out_ok[d, s, :len(rows)] = True
+            for out, a in zip(outs, arrays):
+                out[d, s, :len(rows)] = a[s][rows]
+    return ([o.reshape(n_dev, -1) for o in outs],
+            out_ok.reshape(n_dev, -1), overflow)
+
+
+# name -> (live share, destinations drawn from, bucket or None for the
+# capacity's)
+PARTITIONS = {
+    "dead_rows_mixed_in": (0.6, (0, 1, 2, 3), None),
+    "every_row_to_one_destination": (1.0, (2,), None),
+    "bucket_below_capacity": (0.45, (0, 1, 2, 3), "rows"),
+    "an_empty_destination": (0.8, (0, 2, 3), None),
+    "all_rows_dead": (0.0, (0, 1, 2, 3), None),
+    "bucket_of_one": (0.9, (0, 1, 2, 3), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARTITIONS))
+def test_exchange_by_dest_is_the_plain_partition(case):
+    """Slot for slot against the numpy partition: every output array
+    (int64, int32 and bool payloads together), ``out_ok`` and each
+    sender's overflow count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from nds_tpu.parallel.dist_exec import shard_map
+    from nds_tpu.parallel.exchange import bucket_for, exchange_by_dest
+    from nds_tpu.parallel.mesh import DATA_AXIS, make_mesh
+    live, dests, bucket = PARTITIONS[case]
+    n_dev, per = 4, 203
+    rng = np.random.default_rng(30)
+    ok = rng.random((n_dev, per)) < live
+    dest = rng.choice(dests, (n_dev, per)).astype(np.int32)
+    if bucket == "rows":        # sized from a row bound under capacity
+        bucket = bucket_for(per, int(ok.sum(axis=1).max()), SLACK, n_dev)
+        assert bucket < bucket_for(per, None, SLACK, n_dev)
+    elif bucket is None:
+        bucket = bucket_for(per, None, SLACK, n_dev)
+    arrays = [rng.integers(-2**62, 2**62, (n_dev, per)),
+              rng.integers(-2**31, 2**31 - 1, (n_dev, per)).astype(np.int32),
+              rng.random((n_dev, per)) < 0.5]
+
+    def fn(dest, ok, *arrays):
+        outs, out_ok, over = exchange_by_dest(
+            [a.reshape(-1) for a in arrays], dest.reshape(-1),
+            ok.reshape(-1), n_dev, SLACK, DATA_AXIS, bucket=bucket)
+        return (*(o.reshape(1, -1) for o in outs), out_ok.reshape(1, -1),
+                jnp.reshape(over, (1,)))
+
+    rows = P(DATA_AXIS)
+    f = shard_map(fn, mesh=make_mesh(n_dev), in_specs=(rows,) * 5,
+                  out_specs=(rows,) * 5)
+    *outs, out_ok, over = (np.asarray(x) for x in jax.jit(f)(
+        dest, ok, *arrays))
+    want, want_ok, want_over = _partition_reference(
+        arrays, dest, ok, n_dev, bucket)
+    for got, ref in zip(outs, want):
+        assert got.dtype == ref.dtype
+        np.testing.assert_array_equal(got, ref)
+    np.testing.assert_array_equal(out_ok, want_ok)
+    np.testing.assert_array_equal(over, want_over)
+    # the cases are what they say they are
+    overflowed = case in ("every_row_to_one_destination", "bucket_of_one")
+    assert (want_over.sum() > 0) == overflowed
+    assert out_ok.sum() + want_over.sum() == ok.sum()   # counted, not lost

@@ -144,8 +144,8 @@ def _num(v) -> bool:
 # statement's per-chunk programs launch and read back under their
 # chunk.* span. Attributes the catalogue names beyond the README's
 # table: device.launch carries exchanges / resized / exchange_rows /
-# exchange_bytes for a sharded program, device.readback overflow_rows /
-# skew.
+# exchange_bytes / send_words for a sharded program, device.readback
+# overflow_rows / skew.
 _ROOTS = ("stmt", "query")
 SPAN_PARENTS = {
     "sched.place": _ROOTS,
