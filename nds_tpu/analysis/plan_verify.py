@@ -615,18 +615,23 @@ def _scan_bytes(table, output, nrows: int,
     ITS working-set math by the compression ratio would under-admit).
     Catalog-only estimates (and mode off) keep the raw device-width
     formula."""
+    return sum(b for _n, b in _column_bytes(table, output, nrows,
+                                            encoded))
+
+
+def _column_bytes(table, output, nrows: int,
+                  encoded: "bool | None" = None) -> list:
+    """``_scan_bytes`` a column: [(name, bytes)] in ``output``'s
+    order."""
     cols = getattr(table, "columns", None)
     if cols is not None:
         from nds_tpu import columnar
         if columnar.enabled() and encoded is not False:
-            total = 0
-            for name, dt in output:
-                col = cols.get(name)
-                total += (columnar.scan_nbytes(col)
-                          if col is not None
-                          else _dtype_width(dt) * nrows)
-            return total
-    return nrows * sum(_dtype_width(dt) for _n, dt in output)
+            return [(name, columnar.scan_nbytes(cols[name])
+                     if cols.get(name) is not None
+                     else _dtype_width(dt) * nrows)
+                    for name, dt in output]
+    return [(name, nrows * _dtype_width(dt)) for name, dt in output]
 
 
 def check_encoding_spec(spec, values, mask, nrows=None) -> list:
@@ -700,10 +705,57 @@ class PlanEstimate:
     bytes: int = 0
     widest_table_bytes: int = 0
     tables: dict = None  # type: ignore[assignment]
+    # what the scheduler's working set is made of (engine/scheduler.
+    # working_set). ``held_bytes``: the scans' columns counted ONCE a
+    # (table, column), which is what a device executor keeps of them
+    # (its buffers are keyed by table and column, however many Scan
+    # nodes read the table). ``read_bytes``: of every scan, only the
+    # columns an expression of the plan refers to (or the statement
+    # returns): the bytes its operators' intermediates grow from. None:
+    # an estimate made by hand, taken as ``bytes`` for both.
+    held_bytes: "int | None" = None
+    read_bytes: "int | None" = None
     joins: int = 0
     aggregates: int = 0
     sorts: int = 0
     windows: int = 0
+
+
+def _plan_shape(planned: P.PlannedQuery) -> tuple:
+    """What of a plan the size estimate needs, walked ONCE (a plan
+    does not change once planned; the walk is kept on it):
+    (joins, aggregates, sorts, windows, scans), every node counted
+    once however many roots reach it, and per Scan node
+    ``(table, output, read)`` with ``read`` the names of its columns
+    that some expression of the plan refers to under the scan's
+    binding, or that the statement's roots return (a column handed
+    through to the result with no expression over it is read too)."""
+    shape = planned.__dict__.get("_plan_shape")
+    if shape is not None:
+        return shape
+    roots = [r for r in [planned.root, *planned.scalar_subplans]
+             if isinstance(r, P.Node)]
+    nodes, seen = [], set()
+    for root in roots:
+        for node in P.walk_plan(root):
+            if id(node) not in seen:
+                seen.add(id(node))
+                nodes.append(node)
+    refs: dict = {}
+    for node in nodes:
+        for e in P.all_exprs(node):
+            for x in ir.walk(e):
+                if isinstance(x, ir.ColRef):
+                    refs.setdefault(x.binding, set()).add(x.name)
+    passed = {name for root in roots for name, _dt in root.output}
+    ops = [sum(isinstance(n, kind) for n in nodes)
+           for kind in (P.Join, P.Aggregate, P.Sort, P.Window)]
+    scans = [(n.table, n.output,
+              frozenset(c for c, _dt in n.output
+                        if c in passed or c in refs.get(n.binding, ())))
+             for n in nodes if isinstance(n, P.Scan)]
+    shape = planned.__dict__["_plan_shape"] = (*ops, scans)
+    return shape
 
 
 def estimate_plan(planned: P.PlannedQuery, tables: "dict | None" = None,
@@ -721,37 +773,27 @@ def estimate_plan(planned: P.PlannedQuery, tables: "dict | None" = None,
     est = PlanEstimate(tables={})
     if not isinstance(planned, P.PlannedQuery):
         return est
-    seen: set = set()
-    for root in [planned.root, *planned.scalar_subplans]:
-        if not isinstance(root, P.Node):
-            continue
-        for node in P.walk_plan(root):
-            if id(node) in seen:
-                continue
-            seen.add(id(node))
-            if isinstance(node, P.Join):
-                est.joins += 1
-            elif isinstance(node, P.Aggregate):
-                est.aggregates += 1
-            elif isinstance(node, P.Sort):
-                est.sorts += 1
-            elif isinstance(node, P.Window):
-                est.windows += 1
-            if not isinstance(node, P.Scan):
-                continue
-            nrows = 0
-            t = tables.get(node.table) if tables is not None else None
-            if t is not None:
-                nrows = t.nrows
-            elif catalog is not None:
-                nrows = int(catalog.sizes.get(node.table, 0))
-            nbytes = _scan_bytes(t, node.output, nrows, encoded)
-            rows0, bytes0 = est.tables.get(node.table, (0, 0))
-            # one table scanned by several Scan nodes: rows count once,
-            # bytes accumulate per scan (each scan uploads its columns)
-            # ndslint: waive[NDS119] -- est.tables is a local cost-estimate accumulator, not a session catalog
-            est.tables[node.table] = (max(rows0, nrows),
-                                      bytes0 + nbytes)
+    (est.joins, est.aggregates, est.sorts, est.windows,
+     scans) = _plan_shape(planned)
+    est.read_bytes = 0
+    held: dict = {}     # table -> {column: bytes}
+    for table, output, read in scans:
+        nrows = 0
+        t = tables.get(table) if tables is not None else None
+        if t is not None:
+            nrows = t.nrows
+        elif catalog is not None:
+            nrows = int(catalog.sizes.get(table, 0))
+        per_col = _column_bytes(t, output, nrows, encoded)
+        nbytes = sum(b for _n, b in per_col)
+        est.read_bytes += sum(b for n, b in per_col if n in read)
+        held.setdefault(table, {}).update(per_col)
+        rows0, bytes0 = est.tables.get(table, (0, 0))
+        # one table scanned by several Scan nodes: rows count once,
+        # bytes accumulate per scan (each scan uploads its columns)
+        # ndslint: waive[NDS119] -- est.tables is a local cost-estimate accumulator, not a session catalog
+        est.tables[table] = (max(rows0, nrows), bytes0 + nbytes)
+    est.held_bytes = sum(sum(cols.values()) for cols in held.values())
     for nrows, nbytes in est.tables.values():
         est.rows += nrows
         est.bytes += nbytes

@@ -88,6 +88,9 @@ class _PhaseBExecutor(dx.DeviceExecutor):
     # plan's scan filters; a second per-scan shrink would desync
     # _PartialAggExecutor's buffer walk from the trace for marginal gain
     SCAN_REDUCE = False
+    # its pools hold reduced and chunked stand-ins under a table's
+    # keys: never the process's whole-column copies
+    SHARE_COLUMNS = False
 
     def __init__(self, tables, float_dtype, shared_buffers: dict,
                  streamed: set):
@@ -238,6 +241,9 @@ class ChunkedExecutor(dx.DeviceExecutor):
     # buffers); older ones evict so reduced-row HBM doesn't accumulate
     # across a 99-query power run
     MAX_REDUCED = 16
+    # the relief placement holds what IT uploads, nothing a process
+    # keeps resident beside it
+    SHARE_COLUMNS = False
 
     def __init__(self, tables: dict[str, HostTable],
                  stream_bytes: int = DEFAULT_STREAM_BYTES,

@@ -229,6 +229,63 @@ class _ReducedScan:
         self.capacity = c
 
 
+class _ResidentColumns:
+    """The process's device copies of WHOLE base-table columns: one a
+    host column, however many executors scan it. Sessions of one
+    process over one warehouse (the benchmark's concurrent warm-up, the
+    in-process throughput streams) each kept a copy of every table they
+    scanned; at 6M lineitem rows that doubled a compiling run's memory
+    peak, at 30M four copies of lineitem are 11 GB of a 16 GB chip
+    before any program runs (PERF.md section 6, PR 31). Keyed by the
+    host column's identity: a HostColumn's content does not change
+    (DML makes new columns), so its copy is good for as long as it
+    lives, and goes with it. Reduced scan views, chunk windows and
+    sharded placements stay their executor's own."""
+
+    def __init__(self):
+        from nds_tpu.analysis import locksan
+        self._lock = locksan.lock("engine.device_exec._RESIDENT")
+        # id(HostColumn) -> {its encoding (None: raw): {key suffix:
+        # device array}}
+        self._cols: dict = {}
+        # goes up whenever a column comes or goes: what was counted
+        # under an older version may no longer hold
+        self.version = 0
+
+    def place(self, col, spec, upload) -> dict:
+        """The column's device arrays by key suffix under the encoding
+        ``spec`` (None: raw), uploaded by ``upload()`` if no executor
+        of the process has yet. One upload at a time: two sessions that
+        want the same column must not both make it."""
+        import weakref
+        with self._lock:
+            forms = self._cols.get(id(col))
+            if forms is None:
+                # ndslint: waive[NDS101] -- the entry is dropped by the column's own finalizer, before its address can be given to another object
+                forms = self._cols[id(col)] = {}
+                weakref.finalize(col, self._forget, id(col))
+            if spec not in forms:
+                forms[spec] = upload()
+                self.version += 1
+            return forms[spec]
+
+    def _forget(self, key: int) -> None:
+        with self._lock:
+            self._cols.pop(key, None)
+            self.version += 1
+
+    def nbytes(self, cols) -> int:
+        """Device bytes held of these host columns, in every form."""
+        with self._lock:
+            return sum(a.nbytes for col in cols
+                       for arrays in (self._cols.get(id(col)) or {}
+                                      ).values()
+                       for a in arrays.values())
+
+
+_RESIDENT = _ResidentColumns()
+
+
 class DCtx:
     """One relation during trace: capacity (static), presence mask (traced),
     and columns keyed by (binding, name)."""
@@ -1346,19 +1403,74 @@ class DeviceExecutor:
     REDUCE_MAX_FRAC = 0.5       # only shrink when survivors fit in half
     MAX_SCAN_VIEWS = 96         # bound host+device copies across a power run
 
-    def scan_view(self, node):
-        """_ReducedScan for this scan's (table, filters), or None for the
-        full-table path. Deterministic per signature; cached."""
+    def _view_key(self, node) -> "tuple | None":
+        """The cache key of this scan's reduced view, or None where the
+        scan reads the full table whatever its filters keep."""
         if not self.SCAN_REDUCE or os.environ.get(
                 "NDS_TPU_SCAN_REDUCE", "1") == "0":
             return None
-        t = self.tables[node.table]
-        if not node.filters or t.nrows < self.REDUCE_MIN_ROWS:
+        if (not node.filters
+                or self.tables[node.table].nrows < self.REDUCE_MIN_ROWS):
             return None
         # binding-normalized signature: the same table+filter pair under
         # different query aliases must share one reduced buffer set
         sig = "&".join(sorted(_pred_sig(f) for f in node.filters))
-        ck = (node.table, sig)
+        return (node.table, sig)
+
+    def resident_bytes(self, planned: P.PlannedQuery) -> int:
+        """Bytes of ``planned``'s scan buffers on the device now: what
+        a dispatch binds without an upload (the scheduler's memory
+        governor takes them off its projection: the device's live bytes
+        hold them already). Looks only: builds no view, uploads
+        nothing; a scan whose view is not decided yet counts what is
+        there of its full columns. The count is kept beside the
+        statement's compiled program for as long as nothing came or
+        went: a warm statement pays one comparison."""
+        entry = self._compiled.get(self._plan_key(planned))
+        stamp = (self._uploads, len(self._buffers), _RESIDENT.version)
+        if isinstance(entry, dict):
+            memo = entry.get("resident")
+            if memo is not None and memo[0] == stamp:
+                return memo[1]
+        total = self._count_resident(planned)
+        if isinstance(entry, dict):
+            entry["resident"] = (stamp, total)
+        return total
+
+    def _count_resident(self, planned: P.PlannedQuery) -> int:
+        total, seen, shared = 0, set(), []
+        for root in [planned.root, *planned.scalar_subplans]:
+            for node in P.walk_plan(root):
+                if not isinstance(node, P.Scan):
+                    continue
+                ck = self._view_key(node)
+                rv = self._scan_views.get(ck) if ck else None
+                view = isinstance(rv, _ReducedScan)
+                prefix = rv.prefix if view else node.table
+                cols = self.tables[node.table].columns
+                for name, _dt in node.output:
+                    key = f"{prefix}.{name}"
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    if self.SHARE_COLUMNS and not view:
+                        # another executor of the process may have
+                        # placed it: it is on the device all the same
+                        shared.append(cols[name])
+                    elif key in self._buffers:
+                        total += sum(
+                            self._buffers[key + sfx].nbytes
+                            for sfx in ("", "#v", "#x")
+                            if key + sfx in self._buffers)
+        return total + _RESIDENT.nbytes(shared)
+
+    def scan_view(self, node):
+        """_ReducedScan for this scan's (table, filters), or None for the
+        full-table path. Deterministic per signature; cached."""
+        ck = self._view_key(node)
+        if ck is None:
+            return None
+        t, sig = self.tables[node.table], ck[1]
         hit = self._scan_views.get(ck)
         if hit is not None:
             obs_metrics.counter("scan_view_hits_total").inc()
@@ -1390,7 +1502,15 @@ class DeviceExecutor:
         q32/q92 shape) simply don't reduce. None = nothing evaluable."""
         from nds_tpu.engine import cpu_exec as cx
         ctx = cx.Context(t.nrows)
+        # only the columns the filters name: a scan lists every column
+        # of its table, and decoding lineitem's five string columns for
+        # a filter on l_shipdate is 1.2 GB and seconds a scan at 30M
+        # rows, six scans at a time in a concurrent warm-up
+        named = {x.name for f in node.filters for x in ir.walk(f)
+                 if isinstance(x, ir.ColRef)}
         for name, _dt in node.output:
+            if name not in named:
+                continue
             col = t.columns[name]
             # ndslint: waive[NDS116] -- host-side scan-reduction planning (compile-time filter eval via the CPU evaluator), not device dataflow; nothing decoded here reaches a device buffer
             arr = col.decode() if col.is_string else col.values
@@ -1483,8 +1603,25 @@ class DeviceExecutor:
             if key + sfx in self._buffers:
                 bufs[key + sfx] = self._buffers[key + sfx]
 
+    # whole base-table columns are placed once a process (_RESIDENT);
+    # executors whose pools hold something else under a column's key
+    # (chunk windows, shards) keep their own
+    SHARE_COLUMNS = True
+
     def _upload(self, bufs: dict, table: str, name: str) -> None:
         self._pool_upload(self._buffers, bufs, table, name)
+
+    def _place_column(self, spec, col) -> dict:
+        """Key suffix -> device array of one whole column: encoded under
+        ``spec``, else its values and, if it has one, its null mask."""
+        if spec is not None:
+            from nds_tpu import columnar
+            return {sfx: self._to_device(arr) for sfx, arr in
+                    columnar.encode_column(spec, col).items()}
+        placed = {"": self._to_device(col.values)}
+        if col.null_mask is not None:
+            placed["#v"] = self._to_device(col.null_mask)
+        return placed
 
     def _pool_upload(self, pool: dict, bufs: dict, table: str,
                      name: str) -> None:
@@ -1503,14 +1640,13 @@ class DeviceExecutor:
                     and table not in self._no_encode)
                 else None)
         if key not in pool:
-            if spec is not None:
-                for sfx, arr in columnar.encode_column(
-                        spec, col).items():
-                    pool[key + sfx] = self._to_device(arr)
+            if self.SHARE_COLUMNS and pool is self._buffers:
+                placed = _RESIDENT.place(
+                    col, spec, lambda: self._place_column(spec, col))
             else:
-                pool[key] = self._to_device(col.values)
-                if col.null_mask is not None:
-                    pool[key + "#v"] = self._to_device(col.null_mask)
+                placed = self._place_column(spec, col)
+            for sfx, arr in placed.items():
+                pool[key + sfx] = arr
         if spec is not None:
             self._enc_specs[key] = spec
             self._raw_nbytes[key] = float(

@@ -93,6 +93,32 @@ DEFAULT_DEVICE_BUDGET = 8 << 30
 # (intermediates, padding, exchange buffers)
 EXPANSION = 2.0
 
+
+def working_set(est, expansion: float, resident: int = 0) -> int:
+    """Bytes one dispatch of the estimated plan adds to the device:
+    the scan columns it still has to place there, and its
+    intermediates.
+
+    The scans are ``held_bytes``: every column of a scanned table
+    ONCE, however many Scan nodes read it, because that is what the
+    executor keeps (one buffer a table and column), less ``resident``,
+    the part of them already on the device, which the caller's live
+    bytes hold already. The intermediates grow from ``read_bytes``, the
+    columns the plan's expressions refer to, ``expansion - 1`` times
+    over: a column no operator touches is uploaded, and inflates
+    nothing. ``est.bytes`` (scans x scanned columns, a table counted
+    once a Scan node) overstated both at 30M rows: q21 reads lineitem
+    three times and its eleven columns of sixteen, 8.6 GB of estimate
+    for 3.1 GB of buffers (PERF.md section 6, PR 31). An estimate that
+    gives only ``bytes`` is taken at ``bytes x expansion`` as before."""
+    scans = int(getattr(est, "bytes", 0) or 0)
+    held = getattr(est, "held_bytes", None)
+    read = getattr(est, "read_bytes", None)
+    held = scans if held is None else held
+    read = scans if read is None else read
+    return (max(held - int(resident), 0)
+            + int(read * (expansion - 1.0)))
+
 # consecutive ladder-walked queries before the STARTING rung demotes
 DEFAULT_DEMOTE_AFTER = 2
 # consecutive clean queries at a demoted start before promotion back
@@ -197,6 +223,11 @@ class CostModel:
         self.hwm_history: dict[str, int] = {}
 
     def observe(self, qname: str | None, hwm_bytes: int) -> None:
+        """``hwm_bytes``: the statement's OWN high-water, the device's
+        less what other statements kept resident there while it ran
+        (ExecutionPipeline._note_success): the budget is one
+        statement's working set, and a warehouse resident beside it is
+        not part of that."""
         if qname and hwm_bytes:
             self.hwm_history[qname] = max(
                 self.hwm_history.get(qname, 0), int(hwm_bytes))
@@ -226,9 +257,10 @@ class CostModel:
             # set beyond the raw scans: pad the expansion per operator
             ops = est.joins + est.aggregates + est.sorts + est.windows
             factor = self.expansion * (1.0 + 0.1 * ops)
-            if est.bytes * factor > self.device_budget:
-                return CHUNKED, (f"working-set:{est.bytes}b"
-                                 f"x{factor:.1f}")
+            need = working_set(est, factor)
+            if need > self.device_budget:
+                return CHUNKED, (f"working-set:{need}b of "
+                                 f"{est.bytes}b scanned x{factor:.1f}")
         return fast, f"fits:{est.bytes}b"
 
 
@@ -251,7 +283,9 @@ class MemoryGovernor:
 
         live bytes now (obs/memwatch.live_bytes — allocator stats when
         a backend is live, accounted buffers otherwise)
-      + the plan verifier's size estimate x the expansion factor
+      + what the dispatch adds to them (``working_set``: the plan's
+        scan columns not yet resident, and its intermediates at the
+        expansion factor)
 
     and when the projection exceeds
     ``engine.placement.device_budget_bytes``, demote the query's
@@ -274,19 +308,31 @@ class MemoryGovernor:
         self.expansion = expansion
         self.low_frac = low_frac
         self.governing = False
+        # what the last decision projected (the sched.place span's
+        # ``projected_bytes``)
+        self.projected = 0
 
-    def project(self, est) -> int:
-        est_bytes = int(getattr(est, "bytes", 0) or 0)
-        if est_bytes <= 0:
+    def project(self, est, resident: int = 0,
+                live: "int | None" = None) -> int:
+        """``resident``: bytes of the plan's scan columns on the device
+        already. The live bytes hold them, so the projection must not
+        add them a second time; at SF1 nobody saw the difference, at
+        SF5 it is 3 GB of an 8 GiB budget. ``live``: a reading the
+        caller has taken already."""
+        if int(getattr(est, "bytes", 0) or 0) <= 0:
             return 0
-        return memwatch.live_bytes() + int(est_bytes * self.expansion)
+        if live is None:
+            live = memwatch.live_bytes()
+        return live + working_set(est, self.expansion, resident)
 
-    def decide(self, est) -> "str | None":
+    def decide(self, est, resident: int = 0,
+               live: "int | None" = None) -> "str | None":
         """Non-None reason string when the query must be demoted /
         pre-shrunk before dispatch."""
+        self.projected = 0
         if self.budget <= 0:
             return None
-        projected = self.project(est)
+        projected = self.projected = self.project(est, resident, live)
         if projected <= 0:
             return None
         limit = (int(self.budget * self.low_frac) if self.governing
@@ -620,9 +666,23 @@ class ExecutionPipeline:
         the reactive failure the cost model exists to prevent."""
         return self.universe[0] != SHARDED
 
+    def _resident_bytes(self, planned, placement: str) -> int:
+        """Bytes of ``planned``'s scan buffers that ``placement``'s
+        executor finds on the device already; 0 where there is no such
+        executor yet, or it keeps no count."""
+        count = getattr(self._executors.get(placement), "resident_bytes",
+                        None)
+        return int(count(planned)) if count else 0
+
     def _initial_placement(self, planned, qname) -> tuple:
         self._gov_shrink = False
         self._gov_depth = None
+        # what the sched.place span says of this decision, and what
+        # _note_success takes off the device's high-water
+        self._placed = {"est_bytes": 0, "live_bytes": 0,
+                        "projected_bytes": 0,
+                        "budget_bytes": self.cost_model.device_budget}
+        self._others_bytes = 0
         catalog = None
         from nds_tpu.analysis import plan_verify
         if self.forced or self._demoted_to:
@@ -639,6 +699,9 @@ class ExecutionPipeline:
         placement, why = self.cost_model.choose(
             planned, self.universe, tables=self._tables,
             catalog=catalog, qname=qname, est=est)
+        self._placed["est_bytes"] = est.bytes
+        if why.startswith("hwm-history:"):
+            obs_metrics.counter("hwm_history_placements_total").inc()
         # pre-admission governor: projected HWM (live bytes + estimate
         # x expansion) over budget demotes BEFORE dispatch — every
         # avoided OOM is an avoided ladder walk and re-execute.
@@ -653,7 +716,14 @@ class ExecutionPipeline:
         if (self.governor is not None and not self._multi
                 and CHUNKED in self.universe
                 and placement in (DEVICE, SHARDED, CHUNKED)):
-            reason = self.governor.decide(est)
+            live = memwatch.live_bytes()
+            resident = (self._resident_bytes(planned, placement)
+                        if placement != CHUNKED else 0)
+            reason = self.governor.decide(est, resident, live)
+            self._others_bytes = max(live - resident, 0)
+            self._placed.update(
+                live_bytes=live, projected_bytes=self.governor.projected,
+                budget_bytes=self.governor.budget)
             if reason and placement in (DEVICE, SHARDED):
                 placement, why = CHUNKED, reason
             elif reason and placement == CHUNKED:
@@ -735,7 +805,8 @@ class ExecutionPipeline:
         est = plan_verify.estimate_plan(
             planned, tables=self._tables,
             encoded=self._encoded_estimates())
-        return self.governor.project(est), self.governor.budget
+        resident = self._resident_bytes(planned, self.universe[0])
+        return self.governor.project(est, resident), self.governor.budget
 
     def choose_placement(self, planned, qname: "str | None" = None,
                          catalog=None) -> tuple:
@@ -756,13 +827,19 @@ class ExecutionPipeline:
         """(placement, stats, schedule) for one query: the cost model's
         initial placement, the memory governor and the prefetch depth
         admission, under the ``sched.place`` span."""
-        with get_tracer().span("sched.place"):
+        with get_tracer().span("sched.place") as span:
             placement, why = self._initial_placement(
                 planned, self._current_query())
             stats, sched = self._new_schedule(placement, why)
             self._apply_governor(sched, placement)
             self._apply_prefetch(sched, placement)
             self.last_stats, self.last_schedule = stats, sched
+            sched["_others_bytes"] = self._others_bytes
+            # governed: the governor or the high-water history chose
+            # the placement, not the plan's own size
+            span.set(placement=placement, governed=int(
+                why.startswith(("governor:", "hwm-history:"))),
+                **self._placed)
         return placement, stats, sched
 
     def execute(self, planned, key: object = None):
@@ -884,7 +961,7 @@ class ExecutionPipeline:
                                  overrun, flag_deadline)
             with tracer.span("sched.note"):
                 self._note_success(rescheduled=sched["reschedules"] > 0,
-                                   qname=qname)
+                                   qname=qname, sched=sched)
             return out
         except BaseException:
             if sched.pop("_gave_up", False):
@@ -1118,7 +1195,8 @@ class ExecutionPipeline:
     # ------------------------------------------- demotion / promotion
 
     def _note_success(self, rescheduled: bool,
-                      qname: "str | None" = None) -> None:
+                      qname: "str | None" = None,
+                      sched: "dict | None" = None) -> None:
         hwm = memwatch.high_water()
         if hwm and not self._multi:
             # the HWM history is RANK-LOCAL: feeding it to the cost
@@ -1127,9 +1205,15 @@ class ExecutionPipeline:
             # than its peers compute — the silent-divergence deadlock
             # the consensus step exists to prevent. Single-process
             # pipelines (where the initial choice needs no agreement)
-            # use it freely.
-            self.cost_model.observe(qname or self._current_query(),
-                                    hwm.get("device_hwm_bytes", 0))
+            # use it freely. The device's high-water is the whole
+            # process's: what was on the device at placement and is
+            # not this statement's (a resident warehouse, another
+            # session's buffers) comes off it first.
+            others = (sched if sched is not None
+                      else self.last_schedule).get("_others_bytes", 0)
+            self.cost_model.observe(
+                qname or self._current_query(),
+                max(hwm.get("device_hwm_bytes", 0) - others, 0))
         if self._multi:
             return  # demotion/promotion run in the boundary vote
         if rescheduled:

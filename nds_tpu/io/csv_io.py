@@ -14,6 +14,7 @@ import os
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 import pyarrow.csv as pacsv
 import pyarrow.parquet as pq
 
@@ -109,14 +110,20 @@ def _from_arrow(name: str, schema: Schema, t: pa.Table) -> HostTable:
             # only the (small) dictionary is ever sorted, not the column
             if not pa.types.is_dictionary(arr.type):
                 arr = arr.dictionary_encode()
-            raw_dict = np.asarray(arr.dictionary.to_pylist(), dtype=object)
             raw_codes = arr.indices.fill_null(0).to_numpy(
                 zero_copy_only=False).astype(np.int32)
-            order = np.argsort(raw_dict.astype(str), kind="stable")
-            remap = np.empty(len(raw_dict), dtype=np.int32)
-            remap[order] = np.arange(len(raw_dict), dtype=np.int32)
-            codes = remap[raw_codes] if len(raw_dict) else raw_codes
-            cols[f.name] = HostColumn(f.dtype, codes, raw_dict[order], mask)
+            # Arrow orders strings by their UTF-8 bytes, which is code
+            # point order, numpy's: the codes are what an argsort of
+            # the dictionary as a numpy unicode array gave, without
+            # that array (256 B a value: 6.4 GB for lineitem's 25M
+            # distinct comments at scale 5) and without its minutes
+            order = pc.sort_indices(arr.dictionary).to_numpy()
+            remap = np.empty(len(order), dtype=np.int32)
+            remap[order] = np.arange(len(order), dtype=np.int32)
+            codes = remap[raw_codes] if len(order) else raw_codes
+            dictionary = np.asarray(arr.dictionary.take(order).to_numpy(
+                zero_copy_only=False), dtype=object)
+            cols[f.name] = HostColumn(f.dtype, codes, dictionary, mask)
         elif isinstance(f.dtype, DecimalType):
             s = f.dtype.scale
             if f.dtype.precision <= 15:
@@ -181,6 +188,28 @@ def write_parquet(table: HostTable, path: str, compression: str = "snappy",
                   row_group_rows: int = 1 << 20) -> None:
     write_arrow(to_arrow(table), path, "parquet", compression,
                 row_group_rows)
+
+
+def transcode_parquet(paths: list[str], name: str, schema: Schema,
+                      out: str, compression: str = "snappy",
+                      row_group_rows: int = 1 << 20) -> None:
+    """Raw '|'-delimited chunk files -> ONE parquet file, a chunk at a
+    time: each chunk is read, built and written as row groups of its
+    own (its strings under the chunk's own sorted dictionary), so the
+    host never holds more than a chunk. The reader unifies the
+    dictionaries and sorts the whole one once (``from_arrow``)."""
+    os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+    writer = None
+    try:
+        for p in paths:
+            chunk = to_arrow(read_tbl([p], name, schema))
+            if writer is None:
+                writer = pq.ParquetWriter(out, chunk.schema,
+                                          compression=compression)
+            writer.write_table(chunk, row_group_size=row_group_rows)
+    finally:
+        if writer is not None:
+            writer.close()
 
 
 def read_parquet(paths: list[str] | str, name: str, schema: Schema) -> HostTable:

@@ -34,10 +34,19 @@ def transcode_table(name, schema, input_dir: str, output_dir: str,
     else:
         single = os.path.join(input_dir, f"{name}.tbl")
         paths = [single]
-    table = csv_io.read_tbl(paths, name, schema)
     ext = csv_io.FORMAT_EXT[output_format]
     out = os.path.join(output_dir, name, f"part-0{ext}")
-    csv_io.write_table(table, out, output_format, compression=compression)
+    if output_format == "parquet":
+        # one input chunk at a time: the host holds a chunk, not the
+        # table (at scale 5 lineitem whole took 19 GiB and 21 minutes
+        # here, most of it a sort of its 25M distinct comments that the
+        # load makes again; PERF.md section 6, PR 31)
+        csv_io.transcode_parquet(paths, name, schema, out,
+                                 compression=compression)
+    else:
+        table = csv_io.read_tbl(paths, name, schema)
+        csv_io.write_table(table, out, output_format,
+                           compression=compression)
     # per-table digest manifest for verified loads (io/integrity.py)
     from nds_tpu.io import integrity
     integrity.write_manifest(os.path.join(output_dir, name))

@@ -93,6 +93,9 @@ class DistributedExecutor(dx.DeviceExecutor):
     # stay identical, only the bytes win is forfeit (ROADMAP item 3
     # owns making multi-host first-class)
     COLUMNAR_UPLOAD = False
+    # its buffers are shards placed over the mesh, never the process's
+    # single-device copies of whole columns
+    SHARE_COLUMNS = False
 
     # a sharded program's result is read back as it is: no on-device
     # compaction of it

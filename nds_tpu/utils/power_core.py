@@ -302,6 +302,10 @@ def load_warehouse(suite: Suite, session: Session, data_dir: str,
         with tracer.span("load.table", table=name) as span:
             table = _read_table(suite, data_dir, name, schema, fmt, log)
             span.set(rows=table.nrows, bytes=memwatch.table_bytes(table))
+            # Arrow keeps what it freed: after a 30M-row table that is
+            # 10 GiB of a 40 GiB host the warm-up's compiles then lack
+            import pyarrow as pa
+            pa.default_memory_pool().release_unused()
         session.register_table(table)
         timings[name] = time.perf_counter() - t0
     return timings

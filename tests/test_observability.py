@@ -605,7 +605,10 @@ class TestToolGates:
 
 STMT_SF = 0.01
 PROFILE_STATS = ("stmt_id", "plan_cache_hit", "syncs", "bytes", "first",
-                 "uploads", "upload_bytes", "bytes_accessed")
+                 "uploads", "upload_bytes", "bytes_accessed",
+                 # sched.place (PR 31; its `placement` is a string)
+                 "est_bytes", "live_bytes", "projected_bytes",
+                 "budget_bytes", "governed")
 
 
 def _totals_since(before: dict, after: dict, key: str = "count") -> dict:
@@ -895,6 +898,9 @@ class TestProfilerAnnotations:
         assert stats["nds.device.readback"]["bytes"] > 0
         assert stats["nds.device.bind"] == {"first": 0, "uploads": 0,
                                             "upload_bytes": 0}
+        place = stats["nds.sched.place"]
+        assert place["governed"] == 0 and place["est_bytes"] > 0
+        assert place["projected_bytes"] <= place["budget_bytes"]
         # strings stay out of the annotation; numbers are all there is
         assert all(k in PROFILE_STATS or k == "flops"
                    for s in stats.values() for k in s)

@@ -145,8 +145,12 @@ def _num(v) -> bool:
 # chunk.* span. Attributes the catalogue names beyond the README's
 # table: device.launch carries exchanges / resized / exchange_rows /
 # exchange_bytes / send_words for a sharded program, device.readback
-# overflow_rows / skew.
+# overflow_rows / skew. sched.place says what the placement was made
+# from (PLACE_ATTRS; checked where a span carries them).
 _ROOTS = ("stmt", "query")
+PLACE_BYTES = ("est_bytes", "live_bytes", "projected_bytes",
+               "budget_bytes")
+PLACEMENTS = ("device", "sharded", "chunked", "cpu")
 SPAN_PARENTS = {
     "sched.place": _ROOTS,
     "sched.run": _ROOTS,
@@ -166,6 +170,20 @@ SPAN_PARENTS = {
 }
 
 
+def _place_errors(attrs: dict) -> list[str]:
+    """``sched.place``'s attributes, all six or none (a span exported
+    by a tree older than them carries none)."""
+    errs = []
+    if attrs.get("placement") not in PLACEMENTS:
+        errs.append(f"bad placement {attrs.get('placement')!r}")
+    if attrs.get("governed") not in (0, 1):
+        errs.append(f"governed {attrs.get('governed')!r} not 0 / 1")
+    for key in PLACE_BYTES:
+        if not _num(attrs.get(key)) or attrs[key] < 0:
+            errs.append(f"bad {key} {attrs.get(key)!r}")
+    return errs
+
+
 def _validate_span_tree(node: object, path: str,
                         parent: "str | None" = None) -> list[str]:
     if not isinstance(node, dict):
@@ -182,6 +200,9 @@ def _validate_span_tree(node: object, path: str,
         errs.append(f"{path}: bad dur_ms {node.get('dur_ms')!r}")
     if "attrs" in node and not isinstance(node["attrs"], dict):
         errs.append(f"{path}: attrs is not an object")
+    elif node.get("name") == "sched.place" and node.get("attrs"):
+        errs.extend(f"{path}: sched.place {e}"
+                    for e in _place_errors(node["attrs"]))
     kids = node.get("children", [])
     if not isinstance(kids, list):
         errs.append(f"{path}: children is not a list")
