@@ -402,6 +402,19 @@ def _narrow_key(dv: DVal):
     return arr
 
 
+def _key_radix(kv: DVal):
+    """(lowest value, domain size) of a group key whose domain the host
+    knows: a dictionary's codes, or integer bounds; a nullable key has
+    one more digit, its last, for NULL. None where it knows neither."""
+    if kv.sdict is not None:
+        lo, dom = 0, max(len(kv.sdict), 1)
+    elif kv.lo is not None and kv.hi is not None:
+        lo, dom = int(kv.lo), max(int(kv.hi) - int(kv.lo) + 1, 1)
+    else:
+        return None
+    return lo, dom + (kv.valid is not None)
+
+
 def _plan_bindings(node: P.Node) -> set:
     """All binding names produced anywhere inside a plan subtree."""
     out = set()
@@ -2409,8 +2422,11 @@ class _Trace:
                 out.cols[(b, name)] = DVal(arr, valid, sdict, lo, hi)
             return out
         keyvals = [self.eval(e, ctx) for _, e in node.group_keys]
-        perm, gid, present_s, ngroups, keys_s = self._group_ids(ctx, keyvals)
         G = self._group_capacity(ctx.n, keyvals)
+        if (G < ctx.n and G <= KX.DENSE_AGG_MAX_GROUPS
+                and not any(spec.distinct for _, spec in node.aggs)):
+            return self._run_aggregate_dense(node, ctx, keyvals, G)
+        perm, gid, present_s, ngroups, keys_s = self._group_ids(ctx, keyvals)
         gid = jnp.minimum(gid, G - 1)
         out_row = jnp.arange(G, dtype=jnp.int32) < ngroups
         out = DCtx(G, out_row)
@@ -2427,6 +2443,46 @@ class _Trace:
                 kernel=node.kernel)
             lo, hi = self._agg_bounds(spec, ctx)
             out.cols[(b, name)] = DVal(arr, valid, sdict, lo, hi)
+        return out
+
+    def _run_aggregate_dense(self, node: P.Aggregate, ctx: DCtx,
+                             keyvals, G: int) -> DCtx:
+        """The grouped form for a handful of slots: every key domain is
+        known on the host (_group_capacity), so a row's slot is the
+        mixed-radix code of its key digits and each aggregate is
+        _agg_global's reduction under ``slot == g``. No group sort, no
+        permutation, no gather, no cumsum. Slots stand in the order the
+        group sort gives its groups (first key most significant, values
+        ascending, a nullable key's NULL last); a slot no row falls in
+        is an absent output row."""
+        b = node.binding
+        radix = [_key_radix(kv) for kv in keyvals]
+        slot = jnp.zeros(ctx.n, jnp.int32)
+        for kv, (lo, dom) in zip(keyvals, radix):
+            digit = (kv.arr - lo).astype(jnp.int32)
+            if kv.valid is not None:
+                digit = jnp.where(kv.valid, digit, dom - 1)
+            slot = slot * dom + digit
+        g = jnp.arange(G, dtype=jnp.int32)
+        # [G, n], consumed only by reductions over n: never materialised
+        hit = slot == g[:, None]
+        out = DCtx(G, jnp.any(hit & ctx.row, axis=1))
+        stride = G
+        for (kname, _kexpr), kv, (lo, dom) in zip(
+                node.group_keys, keyvals, radix):
+            stride //= dom
+            digit = (g // stride) % dom
+            arr_g, valid_g = digit.astype(kv.arr.dtype) + lo, None
+            if kv.valid is not None:
+                valid_g = digit < dom - 1
+                arr_g = jnp.where(valid_g, arr_g,
+                                  jnp.zeros((), arr_g.dtype))
+            out.cols[(b, kname)] = kv.with_arrays(arr_g, valid_g)
+        for name, spec in node.aggs:
+            arr, valid, sdict = self._agg_global(spec, ctx, hit)
+            lo, hi = self._agg_bounds(spec, ctx)
+            out.cols[(b, name)] = DVal(arr, valid, sdict, lo, hi)
+        self._note("agg.dense")
         return out
 
     def _seg_sum(self, data, starts2, G):
@@ -2479,15 +2535,10 @@ class _Trace:
         sort — the big TPU win since s64 sorts are emulated."""
         prod = 1
         for kv in keyvals:
-            if kv.sdict is not None:
-                dom = max(len(kv.sdict), 1)
-            elif kv.lo is not None and kv.hi is not None:
-                dom = max(int(kv.hi) - int(kv.lo) + 1, 1)
-            else:
+            radix = _key_radix(kv)
+            if radix is None:
                 return n
-            if kv.valid is not None:
-                dom += 1  # a NULL key forms one extra group
-            prod *= dom
+            prod *= radix[1]
             if prod >= n:
                 return n
         return max(min(prod, n), 1)
@@ -2550,13 +2601,21 @@ class _Trace:
             return None
         return self.eval(spec.arg, ctx)
 
-    def _agg_global(self, spec: P.AggSpec, ctx: DCtx):
+    def _agg_global(self, spec: P.AggSpec, ctx: DCtx, hit=None):
+        """One aggregate over every present row, shape [1]; under
+        ``hit`` ([G, n]: row r falls in slot g) the same reduction a
+        slot, shape [G]: the dense grouped form, never ``distinct``."""
+        if hit is None:
+            axis, rows, shaped = None, (lambda w: w), (
+                lambda x: x.reshape(1))
+        else:
+            axis, rows, shaped = 1, (lambda w: hit & w), (lambda x: x)
         dv = self._agg_arg(spec, ctx)
         if spec.func == "count":
             if dv is None:
-                cnt = jnp.sum(ctx.row)
-                return (cnt.reshape(1).astype(jnp.int64),
-                        jnp.ones(1, bool), None)
+                cnt = shaped(jnp.sum(rows(ctx.row), axis=axis))
+                return (cnt.astype(jnp.int64),
+                        jnp.ones(cnt.shape[0], bool), None)
             w = _ok(dv, ctx.row)
             if spec.distinct:
                 # sentinel-FREE distinct: validity is its own sort
@@ -2572,22 +2631,25 @@ class _Trace:
                     [jnp.ones(1, bool), v_s[1:] != v_s[:-1]])
                 cnt = jnp.sum(newv & w_s)
             else:
-                cnt = jnp.sum(w)
-            return (cnt.reshape(1).astype(jnp.int64),
-                    jnp.ones(1, bool), None)
-        w = _ok(dv, ctx.row)
-        cnt = jnp.sum(w)
-        valid = (cnt > 0).reshape(1)
+                cnt = jnp.sum(rows(w), axis=axis)
+            cnt = shaped(cnt)
+            return (cnt.astype(jnp.int64),
+                    jnp.ones(cnt.shape[0], bool), None)
+        w = rows(_ok(dv, ctx.row))
+        cnt = jnp.sum(w, axis=axis)
+        valid = shaped(cnt > 0)
         if spec.func == "sum":
             if isinstance(spec.dtype, FloatType):
-                s = jnp.sum(jnp.where(w, dv.arr.astype(self.fdt), 0.0))
+                s = jnp.sum(jnp.where(w, dv.arr.astype(self.fdt), 0.0),
+                            axis=axis)
             else:
-                s = jnp.sum(jnp.where(w, dv.arr.astype(jnp.int64), 0))
-            return s.reshape(1), valid, None
+                s = jnp.sum(jnp.where(w, dv.arr.astype(jnp.int64), 0),
+                            axis=axis)
+            return shaped(s), valid, None
         if spec.func == "avg":
             f = _to_float(dv.arr, spec.arg.dtype, self.fdt)
-            s = jnp.sum(jnp.where(w, f, 0.0))
-            return (s / jnp.maximum(cnt, 1)).reshape(1), valid, None
+            s = jnp.sum(jnp.where(w, f, 0.0), axis=axis)
+            return shaped(s / jnp.maximum(cnt, 1)), valid, None
         if spec.func in ("min", "max"):
             if jnp.issubdtype(dv.arr.dtype, jnp.floating):
                 fill = jnp.inf if spec.func == "min" else -jnp.inf
@@ -2595,17 +2657,18 @@ class _Trace:
             else:
                 fill = I64_MAX if spec.func == "min" else I64_MIN
                 masked = jnp.where(w, dv.arr.astype(jnp.int64), fill)
-            red = jnp.min(masked) if spec.func == "min" else jnp.max(masked)
-            return red.reshape(1), valid, dv.sdict
+            red = (jnp.min(masked, axis=axis) if spec.func == "min"
+                   else jnp.max(masked, axis=axis))
+            return shaped(red), valid, dv.sdict
         if spec.func in ("stddev_samp", "stddev"):
             f = _to_float(dv.arr, spec.arg.dtype, self.fdt)
-            s1 = jnp.sum(jnp.where(w, f, 0.0))
-            s2 = jnp.sum(jnp.where(w, f * f, 0.0))
+            s1 = jnp.sum(jnp.where(w, f, 0.0), axis=axis)
+            s2 = jnp.sum(jnp.where(w, f * f, 0.0), axis=axis)
             c = cnt.astype(self.fdt)
             var = (s2 - s1 * s1 / jnp.maximum(c, 1)) / jnp.maximum(
                 c - 1, 1)
             sd = jnp.sqrt(jnp.maximum(var, 0.0))
-            return (jnp.where(cnt > 1, sd, jnp.nan).reshape(1),
+            return (shaped(jnp.where(cnt > 1, sd, jnp.nan)),
                     valid, None)
         raise DeviceExecError(spec.func)
 

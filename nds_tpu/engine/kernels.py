@@ -39,6 +39,10 @@ roofline"):
                    anywhere in the grouped-aggregation path, and the
                    one group sort is amortized across every AggSpec of
                    the node.
+- ``dense``        (no plan stamp: the trace's own choice) a GROUP BY
+                   whose key domains the host knows, at or under
+                   ``DENSE_AGG_MAX_GROUPS`` slots, is one masked
+                   reduction a slot and aggregate: no group sort at all.
 
 The SELECTION is a planning-time decision: ``annotate`` walks a planned
 tree and stamps ``node.kernel`` on Join/SemiJoin/Aggregate nodes from
@@ -96,6 +100,16 @@ DIRECT_MAX_DOMAIN = 1 << 23
 # capacity — beyond it the scatter/gather wins are eaten by the
 # table's own HBM traffic (surrogate keys are near-dense, ratio ~1-4)
 DIRECT_DOMAIN_FACTOR = 16
+# most group slots a GROUP BY may have for the trace to aggregate it
+# densely (`_Trace._run_aggregate_dense`: one masked reduction a slot
+# and aggregate, so its cost grows as slots x aggregates x rows) and
+# not through the group sort (whose cost does not grow with the
+# slots). The largest power of two at which q1's aggregates (four
+# int64 sums, three float64 avgs, a count) took at most half the
+# sorted form's time on a v5e, at 6.0M and at 30.0M rows: 286 against
+# 962 ms and 2,019 against 6,418 ms (8 slots: 8.6 and 31 ms; twice as
+# many slots cost twice the time from 512 up; PERF.md section 6, PR 32)
+DENSE_AGG_MAX_GROUPS = 1024
 # both sides of an M:N join must estimate at least this many rows for
 # radix partitioning to beat one flat sort
 PARTITION_MIN_ROWS = 1 << 16

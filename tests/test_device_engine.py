@@ -149,15 +149,22 @@ def wide():
     return _wide_sessions()
 
 
-def assert_frames_equal_in_order(got, exp, label):
+def assert_frames_equal_in_order(got, exp, label, float_rtol=None):
     """Row for row, in the order returned: the ORDER BY of every
-    statement below leaves no tie for the engines to break apart."""
+    statement below leaves no tie for the engines to break apart.
+    ``float_rtol``: hold float columns to it and not to the digit (a
+    float sum's order of addition is the engine's own)."""
     assert got.shape == exp.shape, (
         f"{label}: shape {got.shape} vs oracle {exp.shape}")
     for i in range(exp.shape[1]):
         g, e = got.iloc[:, i], exp.iloc[:, i]
         assert list(g.isna()) == list(e.isna()), f"{label} col {i} nulls"
         keep = ~e.isna()
+        if float_rtol is not None and e.dtype.kind == "f":
+            np.testing.assert_allclose(
+                g[keep].to_numpy(dtype=float), e[keep].to_numpy(dtype=float),
+                rtol=float_rtol, atol=0, err_msg=f"{label} col {i}")
+            continue
         assert list(g[keep].astype(str)) == list(e[keep].astype(str)), (
             f"{label} col {i} ({exp.columns[i]})")
 
@@ -194,16 +201,29 @@ SORT_PERM_CASES = [
     ("topn-over-groups", "select w_key, w_name, sum(w_val) s from w "
      "group by w_key, w_name order by s desc, w_key, w_name limit 5",
      "sort.topn"),
-    # GROUP BY: keys read from the group sort's sorted operands
+    # GROUP BY over a handful of slots (key domains known on the host,
+    # at or under kernels.DENSE_AGG_MAX_GROUPS): the dense form, no sort
     ("group-null-keys", "select w_key, count(*) c, sum(w_val) s from w "
-     "group by w_key order by w_key", "agg.sorted_keys"),
+     "group by w_key order by w_key", "agg.dense"),
+    ("group-int64-nullable-wide", "select w_bign, count(*) c, max(w_val) "
+     "m from w group by w_bign order by w_bign", "agg.dense"),
+    ("group-string-keys", "select w_name, w_key, count(*) c from w "
+     "group by w_name, w_key order by w_name, w_key", "agg.dense"),
+    # GROUP BY with as many slots as rows (each twin of a case above adds
+    # w_small's 100 values): keys read from the group sort's sorted
+    # operands
+    ("group-null-keys-sorted", "select w_key, w_small, count(*) c, "
+     "sum(w_val) s from w group by w_key, w_small order by w_key, w_small",
+     "agg.sorted_keys"),
     ("group-int64-narrowed-and-not", "select w_small, w_big, count(*) c, "
      "min(w_id) m from w group by w_small, w_big order by w_small, w_big",
      "agg.sorted_keys"),
-    ("group-int64-nullable-wide", "select w_bign, count(*) c, max(w_val) "
-     "m from w group by w_bign order by w_bign", "agg.sorted_keys"),
-    ("group-string-keys", "select w_name, w_key, count(*) c from w "
-     "group by w_name, w_key order by w_name, w_key", "agg.sorted_keys"),
+    ("group-int64-nullable-wide-sorted", "select w_bign, w_small, "
+     "count(*) c, max(w_val) m from w group by w_bign, w_small "
+     "order by w_bign, w_small", "agg.sorted_keys"),
+    ("group-string-keys-sorted", "select w_name, w_key, w_small, count(*) c "
+     "from w group by w_name, w_key, w_small "
+     "order by w_name, w_key, w_small", "agg.sorted_keys"),
     ("group-filtered", "select w_key, w_big, avg(w_val) a from w "
      "where w_id >= 40 and w_small < 20 group by w_key, w_big "
      "order by w_key, w_big", "agg.sorted_keys"),

@@ -221,18 +221,35 @@ def test_empty_build_side(sessions):
 # ------------------------------------------- SQL tier: aggregation/window
 
 def test_grouped_minmax_segscan(sessions):
+    # f_key x f_tiny: 800 slots for 400 rows, so the group sort
     both(sessions,
-         "select f_key, min(f_val) mn, max(f_val) mx, sum(f_qty) s, "
-         "count(*) c from fact group by f_key order by f_key",
+         "select f_key, f_tiny, min(f_val) mn, max(f_val) mx, "
+         "sum(f_qty) s, count(*) c from fact group by f_key, f_tiny "
+         "order by f_key, f_tiny",
          want_kernel="agg.segscan")
 
 
 def test_grouped_minmax_null_groups(sessions):
     # NULL group key forms its own group; NULL values are skipped
     both(sessions,
+         "select f_dim, f_tiny, min(f_val) mn, max(f_val) mx from fact "
+         "group by f_dim, f_tiny order by f_dim, f_tiny",
+         want_kernel="agg.segscan")
+
+
+def test_grouped_minmax_dense(sessions):
+    # 100 slots (f_key alone): masked reductions, no sort, no scan
+    both(sessions,
+         "select f_key, min(f_val) mn, max(f_val) mx, sum(f_qty) s, "
+         "count(*) c from fact group by f_key order by f_key",
+         want_kernel="agg.dense")
+
+
+def test_grouped_minmax_null_groups_dense(sessions):
+    both(sessions,
          "select f_dim, min(f_val) mn, max(f_val) mx from fact "
          "group by f_dim order by f_dim",
-         want_kernel="agg.segscan")
+         want_kernel="agg.dense")
 
 
 def test_window_partition_minmax(sessions):
