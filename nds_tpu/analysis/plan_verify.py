@@ -451,8 +451,8 @@ class _Verifier:
         ns = _namespace(node.child, self.ns_memo)
         for e, asc, nf in node.keys:
             self.check_expr(e, ns, node)
-            # nulls_first is Optional: None = SQL default (nulls last),
-            # the encoding both engines' sort paths treat as falsy
+            # the planner hands a bool (NULL lowest where the ORDER BY
+            # does not say); a hand-built plan's None reads as nulls last
             if not isinstance(asc, bool) or not isinstance(nf,
                                                            (bool,
                                                             type(None))):

@@ -534,11 +534,11 @@ class CpuExecutor:
         else:
             codes = np.zeros(n, dtype=np.int64)
         # sorted space: partition-major, order-minor (stable); NULL order
-        # keys sort per nulls_first (default last), matching the device
+        # keys sort per nulls_first (the planner's bool), as on the device
         idx = np.arange(n)
         for e, asc, nf in reversed(spec.order):
             a, v = self.eval(e, ctx)
-            a2 = a[idx]
+            a2 = _null_keys_tie(a[idx], v, idx)
             if a2.dtype == object:
                 a2 = a2.astype(str)
             key = a2 if asc else _rank_desc(a2)
@@ -666,10 +666,10 @@ class CpuExecutor:
         ctx = self.run(node.child)
         idx = np.arange(ctx.nrows)
         # stable sort from last key to first; NULL keys per nulls_first
-        # (default last), matching the device engine
+        # (the planner's bool), matching the device engine
         for e, asc, nf in reversed(node.keys):
             arr, v = self.eval(e, ctx)
-            arr = arr[idx]
+            arr = _null_keys_tie(arr[idx], v, idx)
             if arr.dtype == object:
                 arr = arr.astype(str)
             key = arr if asc else _rank_desc(arr)
@@ -961,6 +961,15 @@ def _and_valid(a, b):
     if b is None:
         return a
     return a & b
+
+
+def _null_keys_tie(arr: np.ndarray, valid, idx: np.ndarray) -> np.ndarray:
+    """A sort key's values in the current order ``idx`` with every NULL
+    given one value: what a NULL's slot holds does not order it, NULL
+    keys tie and the later keys order them (as on the device)."""
+    if valid is None or not len(arr):
+        return arr
+    return np.where(valid[idx], arr, arr[:1])
 
 
 def _rank_desc(arr: np.ndarray) -> np.ndarray:

@@ -25,7 +25,7 @@ import argparse
 import os
 from concurrent.futures import ProcessPoolExecutor
 
-from nds_tpu.datagen import tpcds
+from nds_tpu.datagen import scale_factor, tpcds
 from nds_tpu.io.csv_io import write_tbl
 from nds_tpu.nds.schema import get_maintenance_schemas, get_schemas
 
@@ -130,7 +130,8 @@ def generate_data_dsdgen(scale: int, parallel: int, data_dir: str,
 
 def main(argv=None) -> None:
     p = argparse.ArgumentParser(description="generate NDS raw data")
-    p.add_argument("scale", type=float, help="scale factor")
+    p.add_argument("scale", type=scale_factor,
+                   help="scale factor: 1, 0.01, sf1")
     p.add_argument("parallel", type=int, help="number of chunks")
     p.add_argument("data_dir", help="output directory")
     p.add_argument("--table", choices=SOURCE_TABLES)

@@ -23,7 +23,7 @@ import os
 import subprocess
 from concurrent.futures import ProcessPoolExecutor
 
-from nds_tpu.datagen import tpch
+from nds_tpu.datagen import scale_factor, tpch
 from nds_tpu.io.csv_io import write_tbl
 from nds_tpu.nds_h.schema import get_schemas
 
@@ -88,17 +88,6 @@ def generate_data_dbgen(scale: int, parallel: int, data_dir: str,
     rc = [p.wait() for p in procs]
     if any(rc):
         raise SystemExit(f"dbgen chunks failed: {rc}")
-
-
-def scale_factor(text: str) -> float:
-    """A scale factor as a number or as TPC writes it: ``5``, ``0.01``,
-    ``sf5``, ``SF0.01``. A deployment above SF1 is asked for as ``sf<n>``
-    (benchmarks/configs/nds_h_sf5.json): a program from before PR 31
-    refuses that spelling here, at once, where it would otherwise
-    generate the population and then run its host out of memory
-    staging it (transcode and load held a 30M-row table whole)."""
-    t = text.strip()
-    return float(t[2:] if t[:2].lower() == "sf" else t)
 
 
 def main(argv=None) -> None:

@@ -30,7 +30,9 @@ Metric names in use across the stack (documented in README
   ``exchange_overflow_rows_total`` — distributed exchange;
   ``exchange_rows_total`` / ``exchange_bytes_total`` — bucket capacity
   and bytes one chip hands to all_to_all, added at every launch of a
-  sharded program (the same numbers ride its ``device.launch`` span)
+  sharded program (the same numbers ride its ``device.launch`` span);
+  ``replicate_bytes_total`` — bytes one chip receives through the
+  program's replicates (all_gather of a sharded relation), likewise
 - ``chunk_scans_total`` / ``chunk_fallbacks_total`` /
   ``chunk_shrink_total`` — out-of-core executor
 - ``task_failures_total`` — TaskFailureCollector bridge

@@ -185,8 +185,9 @@ class DistributedExecutor(dx.DeviceExecutor):
         ``device.bind`` span's ``uploads`` / ``upload_bytes``)."""
         return self._to_device(arr, lambda a: self._dev(a, sharded))
 
-    # beside the base's: the exchange totals of the program's trace,
-    # and which of its buffer keys are sharded / replicated
+    # beside the base's: the exchange and replicate totals of the
+    # program's trace, and which of its buffer keys are sharded /
+    # replicated
     SIDE_KEYS = dx.DeviceExecutor.SIDE_KEYS + ("exchange", "sk", "rk")
 
     def _devices(self):
@@ -197,8 +198,8 @@ class DistributedExecutor(dx.DeviceExecutor):
                 {k: bufs[k] for k in side["rk"]})
 
     def _launch_program(self, tracer, entry, bufs, pvals):
-        """``device.launch`` of a sharded program: its exchange totals
-        ride the span, and count."""
+        """``device.launch`` of a sharded program: its exchange and
+        replicate totals ride the span, and count."""
         side = entry["side"]
         xc = side.get("exchange")
         if xc:
@@ -206,6 +207,8 @@ class DistributedExecutor(dx.DeviceExecutor):
                 xc["exchange_rows"])
             obs_metrics.counter("exchange_bytes_total").inc(
                 xc["exchange_bytes"])
+            obs_metrics.counter("replicate_bytes_total").inc(
+                xc["replicate_bytes"])
         # one collective program in flight per process: two host
         # threads launching multi-device programs can enqueue them on
         # the devices in different orders, and the collectives then
@@ -305,8 +308,9 @@ class DistributedExecutor(dx.DeviceExecutor):
         def fn(shard_bufs, repl_bufs):
             tr = _DistTrace(self, {**shard_bufs, **repl_bufs}, slack)
             # what the program's shuffles are is collected at trace
-            # time (parallel/exchange.exchange_trace): the static
-            # totals ride `side` to the device.launch span, and the
+            # time (parallel/exchange.exchange_trace; the replicates by
+            # the trace itself): the static totals ride `side` to the
+            # device.launch span, and the
             # program returns the worst destination skew so the
             # executor can publish the exchange_skew_ratio gauge
             # host-side — an output, not a debug callback, so the
@@ -316,7 +320,9 @@ class DistributedExecutor(dx.DeviceExecutor):
             side["dicts"] = dicts
             side["kernels"] = tr.kernel_counts()
             side["ops_est"] = int(tr.ops_est)
-            side["exchange"] = xt.stats()
+            side["exchange"] = {**xt.stats(),
+                                "replicates": tr.replicates,
+                                "replicate_bytes": tr.replicate_bytes}
             overflow = tr.total_overflow()
             if xt.skews:
                 skew = xt.skews[0]
@@ -474,6 +480,10 @@ class _DistTrace(dx._Trace):
         super().__init__(ex, bufs, slack)
         self.n_dev = ex.n_dev
         self.axes = ex.axes
+        # what `_replicate` gathered, static, from its shapes: how many
+        # relations, and the bytes ONE device receives of them
+        self.replicates = 0
+        self.replicate_bytes = 0
 
     @staticmethod
     def _stamp(out: DCtx, sharded: bool, rows: int) -> DCtx:
@@ -502,15 +512,27 @@ class _DistTrace(dx._Trace):
 
     # ------------------------------------------------------------- helpers
 
-    def _replicate(self, ctx: DCtx) -> DCtx:
+    def _replicate(self, ctx: DCtx, who: str) -> DCtx:
+        """Every device gets every device's slots of a sharded relation
+        (one ``all_gather`` an array); ``who`` is the operator that asked
+        (``replicate.<who>`` in the statement's ``kernels``). What one
+        device receives, the other devices' slots of the row mask, every
+        column and every validity, adds to ``replicate_bytes``."""
         if not getattr(ctx, "sharded", False):
             return ctx
+        self._note(f"replicate.{who}")
+        self.replicates += 1
+
+        def gather(a):
+            self.replicate_bytes += (
+                (self.n_dev - 1) * a.dtype.itemsize * math.prod(a.shape))
+            return lax.all_gather(a, self.axes, tiled=True)
+
         n = ctx.n * self.n_dev
-        out = DCtx(n, lax.all_gather(ctx.row, self.axes, tiled=True))
+        out = DCtx(n, gather(ctx.row))
         for k, dv in ctx.cols.items():
-            arr = lax.all_gather(dv.arr, self.axes, tiled=True)
-            valid = (None if dv.valid is None
-                     else lax.all_gather(dv.valid, self.axes, tiled=True))
+            arr = gather(dv.arr)
+            valid = None if dv.valid is None else gather(dv.valid)
             out.cols[k] = dv.with_arrays(arr, valid)
         return self._stamp(out, False, _rows(ctx) * self.n_dev)
 
@@ -675,7 +697,7 @@ class _DistTrace(dx._Trace):
                     lctx, _lk = self._exchange_ctx(lctx, lkey, lok)
                 rctx, _rk = self._exchange_ctx(rctx, rkey, rok)
             elif rs:
-                rctx = self._replicate(rctx)
+                rctx = self._replicate(rctx, "join")
             # every probe row comes out once at most: the probe's bound
             out = self._join_cached(node, lctx, rctx)
             return self._stamp(out, probe_sharded, _rows(lctx))
@@ -701,14 +723,14 @@ class _DistTrace(dx._Trace):
             out = self._join_cached(node, lctx, rctx)
             return self._stamp(out, True, out.n)
         if ls:
-            lctx = self._replicate(lctx)
+            lctx = self._replicate(lctx, "join")
         if rs and node.kind == "left":
             # left outer with replicated left + sharded right: the base
             # join computes 'matched' per device, so a left row matched
             # only on another device would ALSO null-extend from every
             # device's block B (duplicates). Replicate the right side —
             # correctness over memory until a pmax-matched path lands.
-            rctx = self._replicate(rctx)
+            rctx = self._replicate(rctx, "join")
             rs = False
         out = self._join_cached(node, lctx, rctx)
         return self._stamp(out, rs, out.n)
@@ -721,8 +743,8 @@ class _DistTrace(dx._Trace):
         return super()._run_join(node)
 
     def _cross_replicated(self, node, lctx, rctx, ls, rs):
-        lctx = self._replicate(lctx) if ls else lctx
-        rctx = self._replicate(rctx) if rs else rctx
+        lctx = self._replicate(lctx, "join") if ls else lctx
+        rctx = self._replicate(rctx, "join") if rs else rctx
         self.stash(node.left, lctx)
         self.stash(node.right, rctx)
         out = self._cross_join(node, lctx, rctx)
@@ -733,7 +755,7 @@ class _DistTrace(dx._Trace):
         lctx, rctx = self.run(node.left), self.run(node.right)
         ls = getattr(lctx, "sharded", False)
         if getattr(rctx, "sharded", False):
-            rctx = self._replicate(rctx)
+            rctx = self._replicate(rctx, "join")
         self.stash(node.left, lctx)
         self.stash(node.right, rctx)
         self._cache.pop(id(node), None)
@@ -755,7 +777,7 @@ class _DistTrace(dx._Trace):
             key, _kok, card = self._key_of(
                 ctx, [e for _, e in node.group_keys])
         except DeviceExecError:
-            self.stash(node.child, self._replicate(ctx))
+            self.stash(node.child, self._replicate(ctx, "agg"))
             self._cache.pop(id(node), None)
             out = super()._run_aggregate(node)
             out.sharded = False
@@ -780,7 +802,7 @@ class _DistTrace(dx._Trace):
     def _global_agg_sharded(self, node: P.Aggregate, ctx: DCtx) -> DCtx:
         b = node.binding
         if any(spec.distinct for _, spec in node.aggs):
-            self.stash(node.child, self._replicate(ctx))
+            self.stash(node.child, self._replicate(ctx, "agg"))
             self._cache.pop(id(node), None)
             out = super()._run_aggregate(node)
             out.sharded = False
@@ -831,7 +853,7 @@ class _DistTrace(dx._Trace):
     def _run_sort(self, node: P.Sort) -> DCtx:
         child = self.run(node.child)
         if getattr(child, "sharded", False):
-            self.stash(node.child, self._replicate(child))
+            self.stash(node.child, self._replicate(child, "sort"))
             self._cache.pop(id(node), None)
         out = super()._run_sort(node)
         out.sharded = False
@@ -844,7 +866,7 @@ class _DistTrace(dx._Trace):
                else node.child)
         child = self.run(src)
         if getattr(child, "sharded", False):
-            self.stash(src, self._replicate(child))
+            self.stash(src, self._replicate(child, "limit"))
             self._cache.pop(id(node), None)
         out = super()._run_limit(node)
         out.sharded = False
@@ -853,7 +875,7 @@ class _DistTrace(dx._Trace):
     def _run_distinct(self, node: P.Distinct) -> DCtx:
         child = self.run(node.child)
         if getattr(child, "sharded", False):
-            self.stash(node.child, self._replicate(child))
+            self.stash(node.child, self._replicate(child, "distinct"))
             self._cache.pop(id(node), None)
         out = super()._run_distinct(node)
         out.sharded = False
@@ -863,7 +885,7 @@ class _DistTrace(dx._Trace):
         for side in (node.left, node.right):
             c = self.run(side)
             if getattr(c, "sharded", False):
-                self.stash(side, self._replicate(c))
+                self.stash(side, self._replicate(c, "setop"))
         self._cache.pop(id(node), None)
         out = super()._run_setop(node)
         out.sharded = False
@@ -874,7 +896,7 @@ class _DistTrace(dx._Trace):
         # (an exchange-by-partition-key path can land later)
         child = self.run(node.child)
         if getattr(child, "sharded", False):
-            self.stash(node.child, self._replicate(child))
+            self.stash(node.child, self._replicate(child, "window"))
             self._cache.pop(id(node), None)
         out = super()._run_window(node)
         out.sharded = False
@@ -882,7 +904,7 @@ class _DistTrace(dx._Trace):
 
     def run_query(self, planned: P.PlannedQuery):
         for i, sub in enumerate(planned.scalar_subplans):
-            ctx = self._replicate(self.run(sub))
+            ctx = self._replicate(self.run(sub), "subplan")
             self.stash(sub, ctx)
             name, dt = sub.output[0]
             dv = ctx.cols[(sub.binding, name)]
@@ -892,7 +914,7 @@ class _DistTrace(dx._Trace):
             if dv.valid is not None:
                 ok = ok & dv.valid[pos]
             self.scalars[i] = (v, ok, dv.sdict, dt)
-        ctx = self._replicate(self.run(planned.root))
+        ctx = self._replicate(self.run(planned.root), "root")
         root = planned.root
         outs, dicts = [], []
         for name, _dt in root.output:

@@ -330,8 +330,8 @@ def _operator_log(monkeypatch):
                         getattr(ctx, "sharded", False), len(ctx.cols)))
         return ctx
 
-    def spy_replicate(self, ctx):
-        out = replicate(self, ctx)
+    def spy_replicate(self, ctx, who):
+        out = replicate(self, ctx, who)
         if out is not ctx:
             log.append(("_replicate", out.n, out.rows, False,
                         len(out.cols)))
