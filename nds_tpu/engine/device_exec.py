@@ -85,19 +85,27 @@ class DeviceExecError(RuntimeError):
 class DVal:
     """One evaluated column on device: array + optional validity, plus
     host-side metadata (string dictionary; integer value bounds used for
-    join-key bit packing)."""
+    join-key bit packing; ``scan``: ``(origin, rows)`` where every slot
+    holds the column of at most one of an origin's ``rows`` rows, or
+    NULL — the group capacity's scan bound, ``_Trace._scan_bound``).
 
-    __slots__ = ("arr", "valid", "sdict", "lo", "hi")
+    ``with_arrays`` keeps ``scan``: it is how a column travels through
+    filters, joins, gathers, exchanges and replicates, which move rows
+    and make none. A DVal computed from other values leaves it None."""
 
-    def __init__(self, arr, valid=None, sdict=None, lo=None, hi=None):
+    __slots__ = ("arr", "valid", "sdict", "lo", "hi", "scan")
+
+    def __init__(self, arr, valid=None, sdict=None, lo=None, hi=None,
+                 scan=None):
         self.arr = arr
         self.valid = valid
         self.sdict = sdict
         self.lo = lo
         self.hi = hi
+        self.scan = scan
 
     def with_arrays(self, arr, valid):
-        return DVal(arr, valid, self.sdict, self.lo, self.hi)
+        return DVal(arr, valid, self.sdict, self.lo, self.hi, self.scan)
 
 
 def _pred_sig(e) -> str:
@@ -1891,7 +1899,8 @@ class _Trace:
                 valid = None
             lo, hi = self.ex.col_bounds(node.table, name)
             sdict = col.dictionary if col.is_string else None
-            ctx.cols[(node.binding, name)] = DVal(arr, valid, sdict, lo, hi)
+            ctx.cols[(node.binding, name)] = DVal(
+                arr, valid, sdict, lo, hi, scan=(id(node), nrows))
         for pred in node.filters:
             # re-applied even on a reduced view (host-eval misses lose
             # only the shrink; unhandled predicates still filter here)
@@ -1915,12 +1924,25 @@ class _Trace:
         return out
 
     def _run_derivedscan(self, node: P.DerivedScan) -> DCtx:
+        """A CTE or view read under this node's binding. Its columns
+        take this node as their origin: a body referenced twice is two
+        origins, so a self-join of it is bounded by the product of the
+        two sides' rows, never by one side's."""
         child = self.run(node.child)
         cb = node.child.binding
         out = DCtx(child.n, child.row)
+        rows = self._slots_everywhere(child)
         for name, _dt in node.child.output:
-            out.cols[(node.binding, name)] = child.cols[(cb, name)]
+            dv = child.cols[(cb, name)]
+            out.cols[(node.binding, name)] = DVal(
+                dv.arr, dv.valid, dv.sdict, dv.lo, dv.hi,
+                scan=(id(node), rows))
         return out
+
+    def _slots_everywhere(self, ctx: DCtx) -> int:
+        """The slots a relation holds over every device: here, its
+        capacity (the sharded trace counts every device's)."""
+        return ctx.n
 
     def _run_stagedscan(self, node: P.StagedScan) -> DCtx:
         """Host-staged intermediate (engine/staging.py): scan the temp
@@ -2427,6 +2449,14 @@ class _Trace:
                 and not any(spec.distinct for _, spec in node.aggs)):
             return self._run_aggregate_dense(node, ctx, keyvals, G)
         perm, gid, present_s, ngroups, keys_s = self._group_ids(ctx, keyvals)
+        G_scan = self._scan_bound(ctx.n, node.group_keys, keyvals)
+        if G_scan < G:
+            # the bound is the trace's reading of where the keys came
+            # from: a group past it fails the statement through the
+            # overflow path instead of merging into the last slot
+            G = G_scan
+            self._overflows.append(jnp.maximum(ngroups - G, 0))
+            self._note("agg.scan_bound")
         gid = jnp.minimum(gid, G - 1)
         out_row = jnp.arange(G, dtype=jnp.int32) < ngroups
         out = DCtx(G, out_row)
@@ -2542,6 +2572,30 @@ class _Trace:
             if prod >= n:
                 return n
         return max(min(prod, n), 1)
+
+    @staticmethod
+    def _scan_bound(n: int, group_keys, keyvals) -> int:
+        """The sorted form's bound on distinct groups from the scans
+        the keys were read from (``DVal.scan``). A slot holds the
+        columns of at most one row of each origin, or an outer join's
+        NULLs for all of them, so the key tuples over one origin number
+        at most its rows plus one; each origin contributes that or the
+        product of its keys' domains, whichever is less. A key that is
+        not a bare column, or has no origin, contributes its domain (n
+        where the host does not know it). At most n."""
+        prod, origins = 1, {}
+        for (_name, e), kv in zip(group_keys, keyvals):
+            radix = _key_radix(kv)
+            dom = n if radix is None else radix[1]
+            if isinstance(e, ir.ColRef) and kv.scan is not None:
+                origin, rows = kv.scan
+                doms = origins.get(origin, (0, 1))[1]
+                origins[origin] = (rows + 1, doms * dom)
+            else:
+                prod *= dom
+        for tuples, doms in origins.values():
+            prod *= min(tuples, doms)
+        return max(1, min(n, prod))
 
     def _group_ids(self, ctx: DCtx, keyvals):
         """Stable sort rows by (presence, key validity+values...); returns

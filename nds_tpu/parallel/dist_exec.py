@@ -650,7 +650,10 @@ class _DistTrace(dx._Trace):
             valid = self.bufs.get(f"{node.table}.{name}#v")
             lo, hi = self.ex.col_bounds(node.table, name)
             sdict = col.dictionary if col.is_string else None
-            ctx.cols[(node.binding, name)] = DVal(arr, valid, sdict, lo, hi)
+            # the group bound is the table's, not the shard's: a
+            # device's groups after an exchange come from every shard
+            ctx.cols[(node.binding, name)] = DVal(
+                arr, valid, sdict, lo, hi, scan=(id(node), t.nrows))
         for pred in node.filters:
             ctx = self._carry(self._apply_filter(ctx, pred), ctx)
         return ctx
@@ -658,6 +661,9 @@ class _DistTrace(dx._Trace):
     def _run_derivedscan(self, node: P.DerivedScan) -> DCtx:
         ctx = super()._run_derivedscan(node)
         return self._carry(ctx, self.run(node.child))
+
+    def _slots_everywhere(self, ctx: DCtx) -> int:
+        return ctx.n * (self.n_dev if getattr(ctx, "sharded", False) else 1)
 
     def _run_filter(self, node: P.Filter) -> DCtx:
         child = self.run(node.child)

@@ -9,6 +9,10 @@ of its replicates (``replicates`` / ``replicate_bytes`` on
 as exchanges plus one key-less ``psum`` aggregate, NULL foreign keys
 through the exchange, the ORDER BY default the comparison holds the
 engine to (NULL lowest), and the cell's entries in ``BENCHMARK.json``.
+The GROUP BY's scan bound (``agg.scan_bound``): query98 and query21
+against the CPU oracle, and every program of this cell and of the
+older cells lowered with the bound and without it, equal to the byte
+wherever the statement's ``kernels`` do not carry it.
 """
 
 import json
@@ -201,6 +205,28 @@ def test_statement_matches_the_single_device_executor(dist4, single, name):
                                   rtol=1e-12, atol=0)
 
 
+@pytest.fixture(scope="module")
+def oracle(raw):
+    return _session(raw, backend="cpu")
+
+
+@pytest.mark.parametrize("name", ["query98", "query21"])
+def test_scan_bounded_group_by_matches_the_cpu_oracle(dist4, oracle, name):
+    """query98's five item columns and query21's warehouse and item
+    columns are keys of replicated scans: the GROUP BY after the
+    exchange takes their rows as its capacity where that is less than
+    the key domains (``agg.scan_bound``: query98 here, query21 at SF1),
+    and the rows are the CPU oracle's."""
+    stmt = _statement(name)
+    want = oracle.sql(stmt.sql).to_pandas()
+    got = dist4["records"][name][0]["result"].to_pandas()
+    assert len(got) == len(want) > 0
+    pd.testing.assert_frame_equal(got, want, check_exact=False,
+                                  rtol=1e-12, atol=0)
+    assert dist4["kernels"][name].get("agg.scan_bound") == (
+        1 if f"{MIX}:{name}#0" in SCAN_BOUNDED else None)
+
+
 def test_every_program_was_launched_by_the_sharded_executor(dist4):
     """query38 is two programs (the staged split): both go through the
     sharded executor, so no single-device executor is live beside it
@@ -380,20 +406,43 @@ OLDER = (["power_dist4:" + n for n in ("q1#0", "q3#0", "q5#0", "q18#0")]
          + ["power_nds_h:" + n for n in (
              "q1#0", "q3#0", "q18#0", "q13#0", "q16#0", "q21#0")]
          + ["power_nds:" + n for n in ("query96#0", "query7#0", "query3#0")])
+# and this cell's
+OWN = [f"{MIX}:{n}#0" for n in NAMES]
 # the one program the ORDER BY default changes: query96 orders by its
 # one count, a key-less aggregate's column, which carries a validity
 PROGRAMS_CHANGED = {"power_nds:query96#0"}
+# the statements whose GROUP BY takes the scan bound at SF0.01
+# (`_Trace._scan_bound`): query98's five item columns, q16's three of
+# part's reduced view; query21's item ids are bounded by their
+# dictionary at this scale, and take the bound at SF1 only
+SCAN_BOUNDED = {f"{MIX}:query98#0", "power_nds_h:q16#0"}
+
+
+def _no_scan_bound(n, group_keys, keyvals):
+    """``_Trace._scan_bound`` that never binds: the group capacity as
+    the domains alone gave it, before the scan bound."""
+    return n
+
+
+# (side, mixes lowered, the scan bound taken out, the replicate's notes
+# and the ORDER BY default put back to the commit before them)
+SIDES = (("change", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False),
+         ("no_scan_bound", OLDER_MIXES + ((MIX, "nds", N_DEV),), True, False),
+         ("parent", OLDER_MIXES, True, True))
 
 
 @pytest.fixture(scope="module")
 def older_lowered(raw, tmp_path_factory):
-    """The lowered text of every program of the older cells' statements
-    (SF0.01; the shipped templates), as this tree lowers it and with
-    what this PR changed under a program put back to the parent's: the
-    replicate without its notes, the planner without its ORDER BY
-    default (a recorder round ``cache.aot.lower_and_compile``, PR 29)."""
+    """The lowered text and ``kernels`` of every program of the older
+    cells' statements and of this cell's (SF0.01; the shipped
+    templates), as this tree lowers them, with the scan bound taken
+    out, and with the two changes that came with this cell put back
+    too: the replicate without its notes, the planner without its
+    ORDER BY default (a recorder round
+    ``cache.aot.lower_and_compile``)."""
     from benchmarks import run
     from nds_tpu.cache import aot
+    from nds_tpu.engine import device_exec as dx
     from nds_tpu.nds_h import gen_data
     from nds_tpu.parallel import dist_exec
     from nds_tpu.sql import planner
@@ -401,16 +450,28 @@ def older_lowered(raw, tmp_path_factory):
     gen_data.generate_data_local(SF, 2, str(raw_h), workers=2)
     population = {"nds_h": str(raw_h), "nds": raw}
     compile_ = aot.lower_and_compile
-    texts = {}
-    for side in ("change", "parent"):
+    counts = dx._Trace.kernel_counts
+    texts, kernels = {}, {}
+    for side, mixes, unbound, before_cell in SIDES:
         kept = texts.setdefault(side, {})
+        noted = kernels.setdefault(side, {})
         with pytest.MonkeyPatch.context() as mp:
-            if side == "parent":
+            if unbound:
+                mp.setattr(dx._Trace, "_scan_bound",
+                           staticmethod(_no_scan_bound))
+            if before_cell:
                 mp.setattr(dist_exec._DistTrace, "_replicate",
                            _parent_replicate)
                 mp.setattr(planner, "_nulls_first", _parent_nulls_first)
+            traced = []
+
+            def kernel_counts(self):
+                traced.append(counts(self))
+                return traced[-1]
+
+            mp.setattr(dx._Trace, "kernel_counts", kernel_counts)
             sessions, seen = {}, set()
-            for mix, suite, shards in OLDER_MIXES:
+            for mix, suite, shards in mixes:
                 if (suite, shards) not in sessions:
                     sessions[suite, shards] = _session(
                         population[suite], suite=suite, shards=shards,
@@ -424,32 +485,60 @@ def older_lowered(raw, tmp_path_factory):
                                   _key=f"{mix}:{stmt.label}", **kw):
                         kept.setdefault(_key, []).append(
                             jitted.lower(*args).as_text())
+                        noted.setdefault(_key, []).append(traced[-1])
                         return compile_(jitted, *args, **kw)
 
                     mp.setattr(aot, "lower_and_compile", keep_text)
                     rec = run.run_statement(sessions[suite, shards], stmt)
                     assert rec["error"] is None, rec["error"]
-    return texts
+    return {"texts": texts, "kernels": kernels}
 
 
 def test_the_older_cells_statements_are_the_ones_lowered(older_lowered):
-    assert sorted(older_lowered["change"]) == sorted(OLDER)
-    assert sorted(older_lowered["parent"]) == sorted(OLDER)
+    texts = older_lowered["texts"]
+    assert sorted(texts["change"]) == sorted(texts["no_scan_bound"]) == \
+        sorted(OLDER + OWN)
+    assert sorted(texts["parent"]) == sorted(OLDER)
     sf1, sf5 = (_statements(m) for m in ("power_nds_h", "power_nds_h_sf5"))
     assert [s.sql for s in sf1] == [s.sql for s in sf5]
-    sharded = [t for k, ts in older_lowered["change"].items() for t in ts
+    sharded = [t for k, ts in texts["change"].items() for t in ts
                if k.startswith("power_dist4:")]
     assert len(sharded) == 4 and all("all_to_all" in t for t in sharded)
 
 
 @pytest.mark.parametrize("key", OLDER)
 def test_no_program_of_an_older_cell_changes_but_query96(older_lowered, key):
-    change, parent = (older_lowered[s][key] for s in ("change", "parent"))
+    """What came with this cell alone: the tree without the scan bound
+    against the commit before the cell."""
+    change, parent = (older_lowered["texts"][s][key]
+                      for s in ("no_scan_bound", "parent"))
     assert len(change) == len(parent) >= 1
     if key in PROGRAMS_CHANGED:
         assert change != parent
     else:
         assert change == parent
+
+
+@pytest.mark.parametrize("key", OLDER + OWN)
+def test_only_a_program_that_notes_the_scan_bound_changes(older_lowered,
+                                                          key):
+    """The scan bound changes the program of a statement whose
+    ``kernels`` carries ``agg.scan_bound``, and no other by a byte:
+    every other statement of every cell lowers to the same text with
+    the rule and without it."""
+    texts, kernels = older_lowered["texts"], older_lowered["kernels"]
+    change, unbound = texts["change"][key], texts["no_scan_bound"][key]
+    assert len(change) == len(unbound) == len(kernels["change"][key]) >= 1
+    for before, after, noted, plain in zip(
+            unbound, change, kernels["change"][key],
+            kernels["no_scan_bound"][key]):
+        assert "agg.scan_bound" not in plain
+        if "agg.scan_bound" in noted:
+            assert after != before
+            assert key in SCAN_BOUNDED
+        else:
+            assert after == before
+            assert key not in SCAN_BOUNDED
 
 
 # --------------------------- (d) NULL foreign keys through the exchange
