@@ -157,12 +157,8 @@ def lower_and_compile(jitted, *args, fresh: bool = False,
     from nds_tpu.analysis import jitsan
     jitsan.on_compile(kind)
     import jax
-    with get_tracer().span("compile.lower", kind=kind) as span:
-        t0 = time.perf_counter()
+    with get_tracer().span("compile.lower", kind=kind):
         with _TRACE_LOCK:
-            # the wait is thread-seconds nobody saw while a warm-up
-            # compiled several programs at a time
-            span.set(lock_wait_ms=(time.perf_counter() - t0) * 1000)
             lowered = jitted.lower(*args)
     if not fresh or not jax.config.jax_enable_compilation_cache:
         return _compile(lowered, kind)

@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from nds_tpu.columnar.encodings import EncSpec
+from nds_tpu.engine import kernels as KX
 
 
 def _unpack_words(words, n: int, bits: int):
@@ -24,7 +25,7 @@ def _unpack_words(words, n: int, bits: int):
     arithmetic right shift's sign extension is masked off."""
     per = 32 // bits
     idx = jnp.arange(n, dtype=jnp.int32)
-    w = jnp.take(words, idx // per)
+    w = KX.take(words, idx // per)
     return (w >> ((idx % per) * bits)) & ((1 << bits) - 1)
 
 
@@ -57,7 +58,7 @@ def decode(spec: EncSpec, bufs: dict, key: str):
         starts = bufs[key + "#x"]
         seg = jnp.cumsum(jnp.zeros(n, jnp.int32).at[starts].add(
             jnp.int32(1))) - 1
-        vals = jnp.take(bufs[key], seg)
+        vals = KX.take(bufs[key], seg)
     else:
         vals = bufs[key]
     from nds_tpu.analysis import plan_verify

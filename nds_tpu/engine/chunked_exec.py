@@ -186,7 +186,8 @@ class _PartialAggExecutor(_PhaseBExecutor):
             tr = _MergeTrace(self, bufs, slack)
             row, outs, dicts = tr.run_query(planned)
             side["dicts"] = dicts
-            return row, outs, tr.total_overflow()
+            with jax.named_scope("op.root"):
+                return row, outs, tr.total_overflow()
 
         # ndslint: waive[NDS111] -- builds the traced callable only; AOT lower+compile routes through cache.aot (_compile_or_load)
         return jax.jit(fn), side

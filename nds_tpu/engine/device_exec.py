@@ -933,11 +933,13 @@ class DeviceExecutor:
         (obs/costs; memoized, so before the execute bracket opens and
         never inside ``device.run``) and make the compiled call.
         ``attrs``: what else the caller knows of the program (the
-        sharded executor's exchange totals).
+        sharded executor's exchange totals); ``program`` is its number
+        in the registry (``obs_costs.sites``).
         -> (perf_counter at the call, what it returned)."""
         import time as _time
         cost = obs_costs.record_program(kind, compiled) or {}
         attrs = dict(attrs) if attrs else {}
+        attrs["program"] = obs_costs.program_id(compiled)
         for k in ("bytes_accessed", "flops"):
             if k in cost:
                 attrs[k] = cost[k]
@@ -1127,14 +1129,16 @@ class DeviceExecutor:
         cf = self._compiled.get(key)
         if cf is None:
             def fn(row, outs):
-                iota = jnp.arange(n, dtype=jnp.int32)
-                k = jnp.where(row, 0, 1).astype(jnp.int32)
-                _, perm = lax.sort([k, iota], num_keys=1,
-                                   is_stable=True)
-                cnt = jnp.sum(row)
-                outs2 = [(jnp.take(a, perm, axis=0),
-                          jnp.take(v, perm, axis=0)) for a, v in outs]
-                return cnt, jnp.take(row, perm), outs2
+                # the root's output, compacted: its own program
+                with jax.named_scope("op.root"):
+                    iota = jnp.arange(n, dtype=jnp.int32)
+                    k = jnp.where(row, 0, 1).astype(jnp.int32)
+                    _, perm = lax.sort([k, iota], num_keys=1,
+                                       is_stable=True)
+                    cnt = jnp.sum(row)
+                    outs2 = [(KX.take(a, perm, axis=0),
+                              KX.take(v, perm, axis=0)) for a, v in outs]
+                    return cnt, KX.take(row, perm), outs2
             # ndslint: waive[NDS102] -- compactor compile bracket (attributed to compile_ms)
             t0 = _time.perf_counter()
             avatars = (jax.ShapeDtypeStruct(row_d.shape, row_d.dtype),
@@ -1349,7 +1353,8 @@ class DeviceExecutor:
             side["dicts"] = dicts
             side["kernels"] = tr.kernel_counts()
             side["ops_est"] = int(tr.ops_est)
-            return row, outs, tr.total_overflow()
+            with jax.named_scope("op.root"):
+                return row, outs, tr.total_overflow()
 
         if sqlparams.has_params(planned):
             # hoisted literals ride as a second runtime-input pytree:
@@ -1804,9 +1809,10 @@ class _Trace:
         return out
 
     def _take(self, arr, idx, **kw):
-        """``jnp.take``, tallied into ``gather_words``."""
+        """``jnp.take`` in the ``gather`` scope, tallied into
+        ``gather_words``."""
         self.gather_words += int(idx.size) * _words_per_row(arr)
-        return jnp.take(arr, idx, **kw)
+        return KX.take(arr, idx, **kw)
 
     def _gather(self, ctx: DCtx, idx, clear_valid=None) -> DCtx:
         """``ctx.gather``, tallied into ``gather_words``."""
@@ -1821,26 +1827,40 @@ class _Trace:
         return tot
 
     def run_query(self, planned: P.PlannedQuery):
+        """The program's outputs: the scalar subplans' values, read in
+        the scope ``op.subplan``, then the root's row mask and columns,
+        assembled in ``op.root`` (where the caller's overflow total goes
+        too)."""
         for i, sub in enumerate(planned.scalar_subplans):
             ctx = self.run(sub)
-            name, dt = sub.output[0]
-            dv = ctx.cols[(sub.binding, name)]
-            pos = jnp.argmax(ctx.row)
-            v = dv.arr[pos]
-            ok = ctx.row[pos]
-            if dv.valid is not None:
-                ok = ok & dv.valid[pos]
-            self.scalars[i] = (v, ok, dv.sdict, dt)
+            with jax.named_scope("op.subplan"):
+                ctx = self._everywhere(sub, ctx, "subplan")
+                name, dt = sub.output[0]
+                dv = ctx.cols[(sub.binding, name)]
+                pos = jnp.argmax(ctx.row)
+                v = dv.arr[pos]
+                ok = ctx.row[pos]
+                if dv.valid is not None:
+                    ok = ok & dv.valid[pos]
+                self.scalars[i] = (v, ok, dv.sdict, dt)
         ctx = self.run(planned.root)
         root = planned.root
         outs, dicts = [], []
-        for name, _dt in root.output:
-            dv = ctx.cols[(root.binding, name)]
-            valid = dv.valid if dv.valid is not None else jnp.ones(
-                ctx.n, dtype=bool)
-            outs.append((dv.arr, valid))
-            dicts.append(dv.sdict)
+        with jax.named_scope("op.root"):
+            ctx = self._everywhere(root, ctx, "root")
+            for name, _dt in root.output:
+                dv = ctx.cols[(root.binding, name)]
+                valid = dv.valid if dv.valid is not None else jnp.ones(
+                    ctx.n, dtype=bool)
+                outs.append((dv.arr, valid))
+                dicts.append(dv.sdict)
         return ctx.row, outs, dicts
+
+    def _everywhere(self, node: P.Node, ctx: DCtx, who: str) -> DCtx:
+        """``ctx`` as every device holds it, for ``who`` (a scalar
+        subplan or the root) to read: here, as it is (the sharded trace
+        gathers it)."""
+        return ctx
 
     # ----------------------------------------------------------- plan nodes
 
@@ -1853,10 +1873,16 @@ class _Trace:
         self._cache[id(node)] = ctx
 
     def run(self, node: P.Node) -> DCtx:
+        """The one dispatch point of every plan node: ``_run_<kind>``
+        in the scope ``op.<kind>``, which names the node's instructions
+        in the compiled program (scopes nest as the plan does; the
+        innermost ``op.*`` of an instruction is its operator)."""
         nid = id(node)
         if nid in self._cache:
             return self._cache[nid]
-        ctx = getattr(self, "_run_" + type(node).__name__.lower())(node)
+        kind = type(node).__name__.lower()
+        with jax.named_scope("op." + kind):
+            ctx = getattr(self, "_run_" + kind)(node)
         # ops/byte model numerator: row-slots this node's context holds
         # (deduplicated — shared CTE bodies count once via the cache)
         self.ops_est += int(getattr(ctx, "n", 0))

@@ -244,6 +244,15 @@ def direct_feasible(dom: "int | None", build_capacity: int) -> bool:
 # must run accelerator-free) and are pure traced functions — no state,
 # no host round trips; the caller owns capacity/overflow policy.
 
+def take(arr, idx, **kw):
+    """``jnp.take`` in the ``gather`` scope, where the profile's op
+    events are read by mechanism (README "Observability")."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("gather"):
+        return jnp.take(arr, idx, **kw)
+
+
 def direct_lookup_join(bkey, bok, pkey, pok, lo: int, dom: int):
     """Unique-build equi-join via a dense direct-address table.
 
@@ -261,7 +270,7 @@ def direct_lookup_join(bkey, bok, pkey, pok, lo: int, dom: int):
     tbl = tbl.at[jnp.where(bok, slots, dom)].set(iota, mode="drop")
     pos = pkey.astype(jnp.int64) - lo
     inb = (pos >= 0) & (pos < dom)
-    ridx = jnp.take(tbl, jnp.clip(pos, 0, dom - 1).astype(jnp.int32))
+    ridx = take(tbl, jnp.clip(pos, 0, dom - 1).astype(jnp.int32))
     hit = pok & inb & (ridx >= 0)
     return jnp.maximum(ridx, 0), hit
 
@@ -292,7 +301,7 @@ def bitmask_semi(bkey, bok, pkey, pok, lo: int, dom: int):
     bm = bm.at[jnp.where(bok, slots, dom)].set(True, mode="drop")
     pos = pkey.astype(jnp.int64) - lo
     inb = (pos >= 0) & (pos < dom)
-    member = jnp.take(bm, jnp.clip(pos, 0, dom - 1).astype(jnp.int32))
+    member = take(bm, jnp.clip(pos, 0, dom - 1).astype(jnp.int32))
     return pok & inb & member
 
 
@@ -316,8 +325,8 @@ def keyed_minmax_semi(bkey, bok, bval, pkey, pok, pval, lo: int,
     pos = pkey.astype(jnp.int64) - lo
     inb = (pos >= 0) & (pos < dom)
     at = jnp.clip(pos, 0, dom - 1).astype(jnp.int32)
-    has_key = pok & inb & jnp.take(present, at)
-    differs = ((jnp.take(mn, at) != pval) | (jnp.take(mx, at) != pval))
+    has_key = pok & inb & take(present, at)
+    differs = ((take(mn, at) != pval) | (take(mx, at) != pval))
     return has_key & differs
 
 
@@ -464,7 +473,7 @@ def seg_reduce_at_ends(op, data, gid, starts2):
     nxt = jnp.concatenate(
         [starts2[1:], jnp.full((1,), n, starts2.dtype)])
     end = jnp.clip(nxt - 1, 0, n - 1)
-    return jnp.take(run, end)
+    return take(run, end)
 
 
 def part_reduce_broadcast(op, data, part_start, pend):
@@ -474,7 +483,7 @@ def part_reduce_broadcast(op, data, part_start, pend):
     scatter + gather pair."""
     import jax.numpy as jnp
     run = seg_scan(op, data, part_start)
-    return jnp.take(run, pend)
+    return take(run, pend)
 
 
 def last_of_group(change, n: int):

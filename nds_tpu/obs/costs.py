@@ -26,6 +26,14 @@ dispatches (a 40-chunk scan costs 40x its program), memory sizes MAX
 cumulative).  Overflow-retry re-dispatches bill again, matching the
 wall-clock they consume.
 
+The same registry names each executable: ``program_id()`` gives it a
+small number the first time it is dispatched (the ``device.launch``
+span's ``program``), and ``sites(program)`` reads its compiled text,
+only when a reader asks, into ``{instruction: (op_name, opcode)}``: the
+scope path (``op.<kind>`` / ``exchange`` / ``replicate`` / ``gather``,
+README "Observability") of every instruction a profile's op events are
+named by.
+
 ``cross_check()`` reconciles the block against PR 8's hand-rolled
 ``ops_est``: a flops/ops ratio outside a generous sanity corridor
 flags ``ops_est_drift`` so the legacy estimator can't silently rot.
@@ -38,10 +46,14 @@ datasheet builtins; a TPU kind in neither is an error, not a blank.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import re
+import weakref
 
 from nds_tpu.analysis import locksan
+from nds_tpu.obs import trace
 
 _LOCK = locksan.lock("obs.costs._LOCK")
 
@@ -253,6 +265,103 @@ def record_program(kind: str, compiled) -> "dict | None":
 
 def query_block() -> "dict | None":
     return LEDGER.query_block()
+
+
+# -------------------------------------------------------------- programs
+
+# program id -> executable, held weakly: the registry keeps alive
+# nothing its executor dropped.  While a profile is live a dispatched
+# executable is also pinned, so that a reader of that profile can still
+# name its instructions after the session that ran it has gone
+_PROGRAMS: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+_PROFILED: dict = {}
+_NEXT_PROGRAM = itertools.count(1)
+
+# one HLO instruction of ``as_text()``: its name, the opcode after its
+# shape (a layout's ``T(..)`` / ``S(..)`` follow no blank), the metadata
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def program_id(compiled) -> int:
+    """The executable's number, given at its first dispatch."""
+    pid = getattr(compiled, "_nds_program", None)
+    if pid is None:
+        with _LOCK:
+            pid = getattr(compiled, "_nds_program", None)
+            if pid is None:
+                pid = next(_NEXT_PROGRAM)
+                setattr(compiled, "_nds_program", pid)
+                _PROGRAMS[pid] = compiled
+    if pid not in _PROFILED and _profile_live():
+        _PROFILED[pid] = compiled
+    return pid
+
+
+def _profile_live() -> bool:
+    ann = trace._annotation_cls()
+    return ann is not None and ann.is_enabled()
+
+
+def parse_sites(text: str) -> dict:
+    """``{instruction name: (op_name, opcode)}`` of an HLO module's
+    text.  An instruction the compiler made without metadata (a copy,
+    the TPU's tree of a cumsum's ``reduce-window``) takes the operator
+    scopes of its first operand that has any: its ``op_name`` is that
+    operand's path up to its innermost ``op.*`` scope, then its own
+    opcode.  '' where no operand has one."""
+    out: dict = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        scope = _OP_NAME.search(line)
+        op_name = scope.group(1) if scope else ""
+        if "/op." not in op_name:
+            for operand in _OPERAND.findall(line, m.end(), _close(line,
+                                                                m.end())):
+                path = out.get(operand, ("",))[0]
+                cut = path.rfind("/op.")
+                if cut >= 0:
+                    end = path.find("/", cut + 1)
+                    op_name = (path if end < 0 else path[:end]) + "/" + \
+                        m.group(2)
+                    break
+        out[m.group(1)] = (op_name, m.group(2))
+    return out
+
+
+def _close(line: str, start: int) -> int:
+    """Where the operand list that opens before ``start`` closes."""
+    depth = 1
+    for i in range(start, len(line)):
+        if line[i] == "(":
+            depth += 1
+        elif line[i] == ")":
+            depth -= 1
+            if not depth:
+                return i
+    return len(line)
+
+
+def sites(program: int) -> "dict | None":
+    """What ``parse_sites`` reads from the program's compiled text,
+    parsed the first time it is asked for; None where the executable
+    has gone or gives no text.  A program served from a compile cache
+    carries the metadata of the tree that compiled it."""
+    compiled = _PROGRAMS.get(program) or _PROFILED.get(program)
+    if compiled is None:
+        return None
+    found = getattr(compiled, "_nds_sites", None)
+    if found is None:
+        try:
+            found = parse_sites(compiled.as_text())
+        except Exception:  # noqa: BLE001 - no text: nothing to name
+            return None
+        setattr(compiled, "_nds_sites", found)
+    return found
 
 
 # ----------------------------------------------------------- cross-check

@@ -40,6 +40,7 @@ from nds_tpu.engine import device_exec as dx
 from nds_tpu.engine.device_exec import DCtx, DVal, DeviceExecError, _ok
 from nds_tpu.io.host_table import HostTable
 from nds_tpu.obs import metrics as obs_metrics
+from nds_tpu.obs.trace import get_tracer
 from nds_tpu.parallel.exchange import (
     exchange, exchange_hierarchical, exchange_trace, hash_columns,
 )
@@ -64,8 +65,10 @@ def _pextreme(op, x, axes):
     all-reduces, and an s64 pmax is refused by its compiler
     ("UNIMPLEMENTED: Supported lowering only of Sum all reduce"; the
     scaled-int64 decimals and the overflow count are all s64). Same
-    value on every platform."""
-    return op(lax.all_gather(x, axes))
+    value on every platform.  The gather is a replicate of one value a
+    device, and is named so in the compiled program."""
+    with jax.named_scope("replicate"):
+        return op(lax.all_gather(x, axes))
 
 # a group-by exchange whose key can take fewer values than this many a
 # device is sized at the local row count (`_DistTrace._run_aggregate`)
@@ -323,16 +326,17 @@ class DistributedExecutor(dx.DeviceExecutor):
             side["exchange"] = {**xt.stats(),
                                 "replicates": tr.replicates,
                                 "replicate_bytes": tr.replicate_bytes}
-            overflow = tr.total_overflow()
-            if xt.skews:
-                skew = xt.skews[0]
-                for s in xt.skews[1:]:
-                    skew = jnp.maximum(skew, s)
-                # every device sees every exchange; the fleet-wide
-                # worst is the gauge's value
-                skew = lax.pmax(skew, tr.axes)
-            else:
-                skew = jnp.zeros((), jnp.float32)
+            with jax.named_scope("op.root"):
+                overflow = tr.total_overflow()
+                if xt.skews:
+                    skew = xt.skews[0]
+                    for s in xt.skews[1:]:
+                        skew = jnp.maximum(skew, s)
+                    # every device sees every exchange; the fleet-wide
+                    # worst is the gauge's value
+                    skew = lax.pmax(skew, tr.axes)
+                else:
+                    skew = jnp.zeros((), jnp.float32)
             return row, outs, overflow, skew
 
         wrapped = shard_map(
@@ -398,7 +402,10 @@ class DistributedExecutor(dx.DeviceExecutor):
         return self._dev(arr, sharded=False)
 
     def _split_keys(self, planned):
-        bufs = self._collect_buffers(planned)
+        """(sharded, replicated) buffer keys of the plan's program.  The
+        buffers are bound here, at the program's compile: where that
+        uploads them it is the statement's first ``device.bind``."""
+        bufs, _pvals = self._bind(planned, get_tracer())
         sharded, repl = [], []
         for k in bufs:
             table = k.split(".", 1)[0]
@@ -529,11 +536,13 @@ class _DistTrace(dx._Trace):
             return lax.all_gather(a, self.axes, tiled=True)
 
         n = ctx.n * self.n_dev
-        out = DCtx(n, gather(ctx.row))
-        for k, dv in ctx.cols.items():
-            arr = gather(dv.arr)
-            valid = None if dv.valid is None else gather(dv.valid)
-            out.cols[k] = dv.with_arrays(arr, valid)
+        # the scope names the all_gathers in the compiled program
+        with jax.named_scope("replicate"):
+            out = DCtx(n, gather(ctx.row))
+            for k, dv in ctx.cols.items():
+                arr = gather(dv.arr)
+                valid = None if dv.valid is None else gather(dv.valid)
+                out.cols[k] = dv.with_arrays(arr, valid)
         return self._stamp(out, False, _rows(ctx) * self.n_dev)
 
     def _exchange_ctx(self, ctx: DCtx, key, kok,
@@ -908,28 +917,10 @@ class _DistTrace(dx._Trace):
         out.sharded = False
         return out
 
-    def run_query(self, planned: P.PlannedQuery):
-        for i, sub in enumerate(planned.scalar_subplans):
-            ctx = self._replicate(self.run(sub), "subplan")
-            self.stash(sub, ctx)
-            name, dt = sub.output[0]
-            dv = ctx.cols[(sub.binding, name)]
-            pos = jnp.argmax(ctx.row)
-            v = dv.arr[pos]
-            ok = ctx.row[pos]
-            if dv.valid is not None:
-                ok = ok & dv.valid[pos]
-            self.scalars[i] = (v, ok, dv.sdict, dt)
-        ctx = self._replicate(self.run(planned.root), "root")
-        root = planned.root
-        outs, dicts = [], []
-        for name, _dt in root.output:
-            dv = ctx.cols[(root.binding, name)]
-            valid = dv.valid if dv.valid is not None else jnp.ones(
-                ctx.n, dtype=bool)
-            outs.append((dv.arr, valid))
-            dicts.append(dv.sdict)
-        return ctx.row, outs, dicts
+    def _everywhere(self, node: P.Node, ctx: DCtx, who: str) -> DCtx:
+        ctx = self._replicate(ctx, who)
+        self.stash(node, ctx)
+        return ctx
 
 
 def make_distributed_factory(mesh=None, n_devices=None,

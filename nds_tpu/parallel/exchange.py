@@ -33,6 +33,7 @@ ns a 32-bit word (PERF.md section 5).
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 
 import jax
@@ -91,6 +92,19 @@ def exchange_trace():
         _SINK = prev
 
 
+def _in_exchange_scope(fn):
+    """Trace ``fn`` in the scope ``exchange``: the hash, the
+    destination sort, the send-buffer gathers and the ``all_to_all``
+    of a shuffle are named so in the compiled program, where the
+    profile's op events are read by mechanism (README
+    "Observability")."""
+    @functools.wraps(fn)
+    def scoped(*args, **kw):
+        with jax.named_scope("exchange"):
+            return fn(*args, **kw)
+    return scoped
+
+
 def _mix64(x):
     """splitmix64 finalizer: avalanche int64 keys before bucketing (raw
     TPC keys are sequential — modulo alone would stripe, not spread)."""
@@ -127,6 +141,7 @@ def bucket_for(n: int, rows: "int | None", slack: float,
     return max(1, int(-(-live * slack // n_dev)))
 
 
+@_in_exchange_scope
 def exchange(arrays: list, key, ok, n_dev: int, slack: float = 2.0,
              axis: str = DATA_AXIS, rows: "int | None" = None):
     """Repartition rows by hash(key) across the mesh axis.
@@ -143,6 +158,7 @@ def exchange(arrays: list, key, ok, n_dev: int, slack: float = 2.0,
         bucket=bucket_for(dest.shape[0], rows, slack, n_dev))
 
 
+@_in_exchange_scope
 def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
                      slack: float = 2.0, axis: str = DATA_AXIS,
                      bucket: int | None = None):
@@ -215,16 +231,19 @@ def exchange_by_dest(arrays: list, dest, ok, n_dev: int,
     # the overflow counted above, read by no slot
     r = jnp.arange(bucket, dtype=jnp.int32)[None, :]
     live = r < kept[:, None]
-    src = jnp.take(order, bounds[:-1, None] + r, mode="clip")
+    with jax.named_scope("gather"):
+        src = jnp.take(order, bounds[:-1, None] + r, mode="clip")
     out_ok = lax.all_to_all(live, axis, 0, 0).reshape(-1)
     outs = []
     for a in arrays:
-        sent = jnp.where(live, jnp.take(a, src, axis=0),
-                         jnp.zeros((), a.dtype))
+        with jax.named_scope("gather"):
+            sent = jnp.where(live, jnp.take(a, src, axis=0),
+                             jnp.zeros((), a.dtype))
         outs.append(lax.all_to_all(sent, axis, 0, 0).reshape(-1))
     return outs, out_ok, n_overflow
 
 
+@_in_exchange_scope
 def exchange_hierarchical(arrays: list, key, ok, n_hosts: int,
                           n_lanes: int, slack: float = 2.0,
                           host_axis: str = "h",

@@ -12,9 +12,15 @@ engine to (NULL lowest), and the cell's entries in ``BENCHMARK.json``.
 The GROUP BY's scan bound (``agg.scan_bound``): query98 and query21
 against the CPU oracle, and every program of this cell and of the
 older cells lowered with the bound and without it, equal to the byte
-wherever the statement's ``kernels`` do not carry it.
+wherever the statement's ``kernels`` do not carry it.  The operator and
+mechanism scopes (``op.<kind>``, ``exchange``, ``replicate``,
+``gather``): the same programs lowered with them and without them, equal
+to the byte but for locations, the compiled programs equal but for
+their metadata, and every instruction of them that the trace made under
+an operator.
 """
 
+import contextlib
 import json
 import os
 import re
@@ -424,11 +430,25 @@ def _no_scan_bound(n, group_keys, keyvals):
     return n
 
 
+def _no_scope(_name):
+    """``jax.named_scope`` that names nothing."""
+    return contextlib.nullcontext()
+
+
 # (side, mixes lowered, the scan bound taken out, the replicate's notes
-# and the ORDER BY default put back to the commit before them)
-SIDES = (("change", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False),
-         ("no_scan_bound", OLDER_MIXES + ((MIX, "nds", N_DEV),), True, False),
-         ("parent", OLDER_MIXES, True, True))
+# and the ORDER BY default put back to the commit before them, the
+# scopes taken out)
+SIDES = (("change", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False,
+          False),
+         ("no_scan_bound", OLDER_MIXES + ((MIX, "nds", N_DEV),), True, False,
+          False),
+         ("parent", OLDER_MIXES, True, True, False),
+         ("no_scopes", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False,
+          True))
+# the sides whose programs are compiled afresh and kept as text: jax's
+# persistent cache leaves metadata out of its key, so a program it served
+# would carry the scopes of whichever tree compiled it first
+COMPILED = ("change", "no_scopes")
 
 
 @pytest.fixture(scope="module")
@@ -436,10 +456,11 @@ def older_lowered(raw, tmp_path_factory):
     """The lowered text and ``kernels`` of every program of the older
     cells' statements and of this cell's (SF0.01; the shipped
     templates), as this tree lowers them, with the scan bound taken
-    out, and with the two changes that came with this cell put back
-    too: the replicate without its notes, the planner without its
-    ORDER BY default (a recorder round
-    ``cache.aot.lower_and_compile``)."""
+    out, with the two changes that came with this cell put back too
+    (the replicate without its notes, the planner without its ORDER BY
+    default), and with the scopes taken out (a recorder round
+    ``cache.aot.lower_and_compile``); the compiled text of this tree's
+    programs with the scopes and without them."""
     from benchmarks import run
     from nds_tpu.cache import aot
     from nds_tpu.engine import device_exec as dx
@@ -451,11 +472,15 @@ def older_lowered(raw, tmp_path_factory):
     population = {"nds_h": str(raw_h), "nds": raw}
     compile_ = aot.lower_and_compile
     counts = dx._Trace.kernel_counts
-    texts, kernels = {}, {}
-    for side, mixes, unbound, before_cell in SIDES:
+    import jax
+    texts, kernels, compiled = {}, {}, {}
+    for side, mixes, unbound, before_cell, unscoped in SIDES:
         kept = texts.setdefault(side, {})
         noted = kernels.setdefault(side, {})
+        done = compiled.setdefault(side, {})
         with pytest.MonkeyPatch.context() as mp:
+            if unscoped:
+                mp.setattr(jax, "named_scope", _no_scope)
             if unbound:
                 mp.setattr(dx._Trace, "_scan_bound",
                            staticmethod(_no_scan_bound))
@@ -486,12 +511,16 @@ def older_lowered(raw, tmp_path_factory):
                         kept.setdefault(_key, []).append(
                             jitted.lower(*args).as_text())
                         noted.setdefault(_key, []).append(traced[-1])
-                        return compile_(jitted, *args, **kw)
+                        if side not in COMPILED:
+                            return compile_(jitted, *args, **kw)
+                        out = compile_(jitted, *args, **{**kw, "fresh": True})
+                        done.setdefault(_key, []).append(out.as_text())
+                        return out
 
                     mp.setattr(aot, "lower_and_compile", keep_text)
                     rec = run.run_statement(sessions[suite, shards], stmt)
                     assert rec["error"] is None, rec["error"]
-    return {"texts": texts, "kernels": kernels}
+    return {"texts": texts, "kernels": kernels, "compiled": compiled}
 
 
 def test_the_older_cells_statements_are_the_ones_lowered(older_lowered):
@@ -539,6 +568,86 @@ def test_only_a_program_that_notes_the_scan_bound_changes(older_lowered,
         else:
             assert after == before
             assert key not in SCAN_BOUNDED
+
+
+@pytest.mark.parametrize("key", OLDER + OWN)
+def test_the_scopes_change_no_instruction(older_lowered, key):
+    """Every program of the six cells' statements (`power_nds_h_sf5`'s
+    SQL is `power_nds_h`'s), on one chip or on the four-device mesh as
+    its cell runs it, lowers to the same text without debug info with
+    the scopes and with ``jax.named_scope`` naming nothing."""
+    texts = older_lowered["texts"]
+    scoped, plain = texts["change"][key], texts["no_scopes"][key]
+    assert len(scoped) == len(plain) >= 1
+    assert scoped == plain
+
+
+# one statement a cell; `nds_h_sf5.power` runs `power_nds_h`'s SQL
+COMPILED_KEYS = ["short:q19#0", "power_nds:query3#0", "power_nds_h:q18#0",
+                 "power_nds_h:q3#0", "power_dist4:q5#0", f"{MIX}:query38#0"]
+
+
+def _no_metadata(text: str) -> str:
+    """A compiled module's text without what the scopes can reach: each
+    instruction's ``metadata`` and the header's tables of files,
+    functions and stack frames they point into."""
+    text = re.sub(r",? metadata=\{[^{}]*\}", "", text)
+    return "\n".join(
+        line for line in text.splitlines()
+        if not re.match(r"^(FileNames|FunctionNames|FileLocations|"
+                        r"StackFrames)$|^\d+ ", line))
+
+
+@pytest.mark.parametrize("key", COMPILED_KEYS)
+def test_the_compiled_program_differs_in_metadata_alone(older_lowered, key):
+    """Compiled with the scopes and without them (each afresh), a
+    program differs in its instructions' metadata and nothing else."""
+    compiled = older_lowered["compiled"]
+    scoped, plain = compiled["change"][key], compiled["no_scopes"][key]
+    assert len(scoped) == len(plain) >= 1
+    for a, b in zip(scoped, plain):
+        assert a != b
+        assert _no_metadata(a) == _no_metadata(b)
+
+
+CHECKED = ("fusion", "sort", "gather", "scatter", "all-to-all",
+           "all-gather", "all-reduce", "collective-permute",
+           "reduce-scatter")
+
+
+def _entry_sites(text: str) -> dict:
+    """``costs.parse_sites`` of the entry computation: the instructions
+    a profile's op events name."""
+    from nds_tpu.obs import costs
+    entry = text[text.index("\nENTRY "):]
+    return costs.parse_sites(entry[:entry.index("\n}")])
+
+
+@pytest.mark.parametrize("key", OLDER + OWN)
+def test_every_compiled_instruction_has_an_operator(older_lowered, key):
+    """In the compiled program every fusion, sort, gather, scatter and
+    collective that the trace made has an ``op.*`` scope; every
+    all-to-all is under ``exchange``, every all-gather under
+    ``replicate``.  The CPU compiler makes some fusions of its own
+    (constant broadcasts, its reduce-window tree), with no ``op_name``
+    or one that ends in an instruction's name (``broadcast.66``)."""
+    from benchmarks import op_reduce
+    sharded = key.startswith(("power_dist4:", MIX))
+    kinds = set()
+    for text in older_lowered["compiled"]["change"][key]:
+        for name, (op_name, opcode) in _entry_sites(text).items():
+            if opcode not in CHECKED or re.search(r"(^|\.\d+)$", op_name):
+                continue
+            kinds.add(opcode)
+            path, mechanisms = op_reduce.scopes(op_name)
+            assert path, (key, name, op_name)
+            if opcode == "all-to-all":
+                assert "exchange" in mechanisms, (key, name, op_name)
+            if opcode == "all-gather":
+                assert "replicate" in mechanisms, (key, name, op_name)
+    assert "fusion" in kinds
+    if sharded:
+        assert kinds & {"all-to-all", "all-gather"}, (key, kinds)
 
 
 # --------------------------- (d) NULL foreign keys through the exchange
@@ -711,8 +820,9 @@ def test_benchmark_entries():
         CELL, "nds_h_sf1.dist4"]
     assert layers["replicate_mb_per_pass"]["layer"] == "exchange"
     # and of its set-up what the sharded path has spans for: its uploads
-    # are made under device.compile (_compile asks _split_keys for the
-    # buffers), no bind is a first one, first_bind_s has nothing to read
+    # are a first device.bind under device.compile (_split_keys binds
+    # the program's buffers), which first_bind_s would read; listing the
+    # cell there is an edit of that entry, a benchmark change of its own
     for name in ("engine_init_s", "load_read_s", "load_build_s",
                  "lower_s", "cache_read_s"):
         assert CELL in layers[name]["workloads"]

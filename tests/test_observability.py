@@ -608,7 +608,9 @@ PROFILE_STATS = ("stmt_id", "plan_cache_hit", "syncs", "bytes", "first",
                  "uploads", "upload_bytes", "bytes_accessed",
                  # sched.place (PR 31; its `placement` is a string)
                  "est_bytes", "live_bytes", "projected_bytes",
-                 "budget_bytes", "governed")
+                 "budget_bytes", "governed",
+                 # device.launch: the executable's number (obs/costs)
+                 "program")
 
 
 def _totals_since(before: dict, after: dict, key: str = "count") -> dict:
@@ -716,7 +718,7 @@ class TestStatementSpans:
         assert under.attrs["upload_bytes"] > 0
         assert own.attrs["first"] is False and own.attrs["uploads"] == 0
         (lower,) = first.find("compile.lower")
-        assert lower.attrs["lock_wait_ms"] >= 0
+        assert lower.attrs == {"kind": "DeviceExecutor"}
         (xla,) = first.find("compile.xla")
         assert xla.attrs["persistent_cache_hit"] in (True, False)
 
@@ -904,6 +906,11 @@ class TestProfilerAnnotations:
         # strings stay out of the annotation; numbers are all there is
         assert all(k in PROFILE_STATS or k == "flops"
                    for s in stats.values() for k in s)
+        # the launch names its program, whose instructions the registry
+        # reads back for the profile's op events
+        from nds_tpu.obs import costs
+        program = stats["nds.device.launch"]["program"]
+        assert program > 0 and costs.sites(program)
 
     def test_obs_off_is_the_noop_and_opens_no_annotation(
             self, stmt_session, tmp_path, monkeypatch):
@@ -924,3 +931,39 @@ class TestProfilerAnnotations:
         assert events == []
         assert tracer.totals() == before
         assert stmt_session["pipe"].last_query_span is None
+
+
+SITES_TEXT = """\
+HloModule jit_fn, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %gather.9 = f32[8]{0} gather(%param_0), metadata={op_name="gather"}
+}
+
+ENTRY %main.5 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0:T(1024)} parameter(0)
+  %fusion.1 = f32[8]{0:T(1024)S(1)} fusion(f32[8]{0:T(1024)} %p), kind=kCustom, calls=%fused_computation, metadata={op_name="jit(fn)/op.limit/op.aggregate/gather/jit(_take)/gather" stack_frame_id=3}
+  %copy.2 = f32[8]{0} copy(%fusion.1)
+  %reduce-window.3 = (f32[8]{0}, f32[8]{0}) reduce-window(%copy.2, %copy.2), window={size=8}
+  %sort.4 = (f32[8]{0:T(1024)}, s32[8]{0}) sort(%p, %p), dimensions={0}
+  ROOT %tuple.5 = (f32[8]{0}) tuple(%copy.2)
+}
+"""
+
+
+def test_sites_name_every_instruction_by_its_scopes():
+    """``costs.parse_sites``: each instruction's ``op_name`` and opcode
+    (a layout's ``T(..)`` is no opcode); an instruction the compiler
+    made without metadata takes the operator scopes of its first
+    operand that has any, and none where no operand has one."""
+    from nds_tpu.obs import costs
+    sites = costs.parse_sites(SITES_TEXT)
+    assert sites["fusion.1"] == (
+        "jit(fn)/op.limit/op.aggregate/gather/jit(_take)/gather", "fusion")
+    assert sites["gather.9"] == ("gather", "gather")
+    assert sites["copy.2"] == ("jit(fn)/op.limit/op.aggregate/copy", "copy")
+    assert sites["reduce-window.3"] == (
+        "jit(fn)/op.limit/op.aggregate/reduce-window", "reduce-window")
+    assert sites["sort.4"] == ("", "sort")
+    assert sites["p"] == ("", "parameter")

@@ -12,10 +12,12 @@ import pytest
 N, N_DIM = 8192, 2048
 
 
-def _sessions():
+def _sessions(kinds=("device", "sharded")):
     """(single-device session, four-device session, both tables
     sharded) over one fact and one dimension table; ``d_three`` takes
-    each value three times, so a join on it is M:N."""
+    each value three times, so a join on it is M:N.  ``kinds`` may ask
+    for a ``chunked`` session too, which streams the fact in chunks."""
+    from nds_tpu.engine.chunked_exec import make_chunked_factory
     from nds_tpu.engine.device_exec import make_device_factory
     from nds_tpu.engine.session import Session
     from nds_tpu.engine.types import INT32, Schema
@@ -47,9 +49,13 @@ def _sessions():
             s.register_table(from_arrays(t, schemas[t], arrays[t]))
         return s
 
-    return {"device": build(make_device_factory()),
-            "sharded": build(make_distributed_factory(
-                n_devices=4, shard_threshold=1000))}
+    factories = {
+        "device": make_device_factory,
+        "sharded": lambda: make_distributed_factory(
+            n_devices=4, shard_threshold=1000),
+        "chunked": lambda: make_chunked_factory(stream_bytes=1,
+                                                chunk_rows=2048)}
+    return {k: build(factories[k]()) for k in kinds}
 
 
 @pytest.fixture(scope="module")
@@ -284,3 +290,51 @@ def test_single_device_overflow_goes_round_the_shared_loop(
     assert entry["slack"] == 4.0
     # the bill carries both compiles
     assert ex.last_timings["compile_ms"] > max(c.dur_ms for c in compiles)
+
+
+@pytest.mark.parametrize("which", ["device", "sharded", "chunked"])
+def test_every_launch_names_its_program(kept_trees, which):
+    """``device.launch`` carries ``program``, the executable's number in
+    the registry of ``obs.costs``, on every path (the chunked one's
+    per-chunk programs included); ``costs.sites`` reads that program's
+    instructions, each with its scope path and opcode."""
+    from nds_tpu.obs import costs
+    session = _sessions((which,))[which]
+    ex = _executor(session)
+    session.sql(JOIN)
+    root = ex.last_query_span
+    while root.parent is not None:
+        root = root.parent
+    launches = root.find("device.launch")
+    if which == "chunked":
+        assert root.find("chunk.reduce")
+    assert launches
+    for launch in launches:
+        program = launch.attrs["program"]
+        sites = costs.sites(program)
+        assert sites and all(isinstance(op_name, str) and opcode
+                             for op_name, opcode in sites.values())
+        assert "fusion" in {opcode for _o, opcode in sites.values()}
+    # one number an executable: a repeat launches the same program
+    session.sql(JOIN)
+    again = ex.last_query_span.find("device.launch")
+    assert [a.attrs["program"] for a in again][-1] == \
+        launches[-1].attrs["program"]
+
+
+def test_a_sharded_compile_binds_its_uploads_as_a_first_bind(kept_trees):
+    """The sharded program's buffers are placed on the mesh when it is
+    first compiled: under ``device.compile``, in a ``device.bind`` with
+    ``first``, which the tracer sums under ``device.bind:first`` as it
+    does the single-device path's."""
+    from nds_tpu.obs.trace import get_tracer
+    session = _sessions(("sharded",))["sharded"]
+    ex = _executor(session)
+    before = get_tracer().totals().get("device.bind:first", {})
+    session.sql(JOIN)
+    (compiled,) = ex.last_query_span.find("device.compile")
+    bind = compiled.find("device.bind")[0]
+    assert bind.attrs["first"] is True
+    assert bind.attrs["uploads"] > 0 and bind.attrs["upload_bytes"] > 0
+    after = get_tracer().totals()["device.bind:first"]
+    assert after["count"] > before.get("count", 0)
