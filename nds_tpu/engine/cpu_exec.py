@@ -707,7 +707,9 @@ class CpuExecutor:
             v = ctx.valid[(b, name)]
             col = pd.Series(arr.astype(str) if arr.dtype == object else arr)
             if v is not None:
-                col = col.mask(~v)
+                # NULLs compare equal in a set operation: None, which
+                # equals itself (a masked number reads NaN, which does not)
+                col = col.astype(object).where(v, None)
             data[f"c{i}"] = col
         return pd.DataFrame(data)
 

@@ -70,8 +70,9 @@ def _pextreme(op, x, axes):
     with jax.named_scope("replicate"):
         return op(lax.all_gather(x, axes))
 
-# a group-by exchange whose key can take fewer values than this many a
-# device is sized at the local row count (`_DistTrace._run_aggregate`)
+# a group-by or row-hash exchange whose key can take fewer values than
+# this many a device is sized at the local row count
+# (`_DistTrace._run_aggregate`, `_DistTrace._exchange_by_row`)
 FEW_KEYS_A_DEVICE = 4
 
 # tables at or above this row count shard across the mesh; smaller ones
@@ -472,6 +473,14 @@ def _rows(ctx: DCtx) -> int:
     return getattr(ctx, "rows", ctx.n)
 
 
+def _domain(v: DVal) -> tuple:
+    """(lo, hi) a column's values lie in by its static bounds: a string's
+    dictionary codes, or the host bounds (None: not known)."""
+    if v.sdict is not None:
+        return 0, max(len(v.sdict) - 1, 0)
+    return v.lo, v.hi
+
+
 class _DistTrace(dx._Trace):
     """The sharded trace. Beside ``sharded`` every relation it makes
     carries ``rows``: how many rows a device is expected to hold of it,
@@ -550,9 +559,9 @@ class _DistTrace(dx._Trace):
         """Repartition a sharded ctx by an int64 key; returns (ctx', key')
         both with capacity ``slack x rows`` of ctx (rows colocated by key
         hash): the buckets are sized from the rows a device holds, not
-        from the buffer they sit in. ``few_keys``: the exchange of
-        `_run_aggregate` that must not overflow, sized so that a device
-        can send ALL its slots to one peer."""
+        from the buffer they sit in. ``few_keys``: an exchange by a key
+        of a handful of values, which must not overflow, sized so that a
+        device can send ALL its slots to one peer."""
         slack, rows = self.slack, _rows(ctx)
         if few_keys:
             slack, rows = max(self.slack, float(self.n_dev)), ctx.n
@@ -603,8 +612,7 @@ class _DistTrace(dx._Trace):
         arrs = [v.arr if v.valid is None
                 else jnp.where(v.valid, v.arr, jnp.zeros((), v.arr.dtype))
                 for v in vals]
-        bounds = [(0, max(len(v.sdict) - 1, 0)) if v.sdict is not None
-                  else (v.lo, v.hi) for v in vals]
+        bounds = [_domain(v) for v in vals]
         card = 1
         for lo, hi in bounds:
             card = (None if card is None or lo is None or hi is None
@@ -887,16 +895,105 @@ class _DistTrace(dx._Trace):
         out.sharded = False
         return out
 
-    def _run_distinct(self, node: P.Distinct) -> DCtx:
-        child = self.run(node.child)
-        if getattr(child, "sharded", False):
-            self.stash(node.child, self._replicate(child, "distinct"))
-            self._cache.pop(id(node), None)
-        out = super()._run_distinct(node)
-        out.sharded = False
+    # ------------------------------------------ DISTINCT and INTERSECT / EXCEPT
+    #
+    # Equal rows meet on one device by an exchange on a hash of the whole
+    # row, and each device runs the single-device operator on its share;
+    # the output stays sharded. What the exchange hashed is marked on its
+    # output (`by_row`: the ordered column keys) and kept by these two
+    # operators alone, whose rows stay where they are: a DISTINCT or set
+    # operation over a relation marked by its own columns exchanges
+    # nothing. The hash reads each column's raw value (a string's
+    # dictionary code, 0 under a NULL) and its validity, never bounds
+    # that differ from relation to relation, so two relations whose
+    # string columns share their dictionaries are placed alike.
+
+    @staticmethod
+    def _exact(vals) -> None:
+        if any(jnp.issubdtype(v.arr.dtype, jnp.floating) for v in vals):
+            raise DeviceExecError(
+                "a floating-point column does not hash exactly")
+
+    def _exchange_by_row(self, ctx: DCtx, cols) -> DCtx:
+        """ctx exchanged by a hash of ``cols``, (values, validity or None,
+        lo, hi) a column: a column without a validity hashes as one whose
+        every row is valid, so that equal values hash alike whichever
+        side may hold NULLs. Rows that can take fewer values than a few
+        a device are sized as `_run_aggregate` sizes such keys."""
+        hashed, card = [], 1
+        for arr, valid, lo, hi in cols:
+            card = (None if card is None or lo is None or hi is None
+                    else card * (hi - lo + 1 + (valid is not None)))
+            if valid is None:
+                valid = jnp.ones(arr.shape, bool)
+            hashed.append((jnp.where(valid, arr, jnp.zeros((), arr.dtype)),
+                           valid))
+        few_keys = card is not None and card < FEW_KEYS_A_DEVICE * self.n_dev
+        new, _ = self._exchange_ctx(ctx, hash_columns(hashed), ctx.row,
+                                    few_keys)
+        return new
+
+    def _by_row(self, ctx: DCtx, keys: tuple) -> DCtx:
+        """``ctx`` with its rows equal on the columns ``keys`` on one
+        device, marked so: as it is where it carries that mark already."""
+        if getattr(ctx, "by_row", None) == keys:
+            return ctx
+        vals = [ctx.cols[k] for k in keys]
+        self._exact(vals)
+        new = self._exchange_by_row(
+            ctx, [(v.arr, v.valid, *_domain(v)) for v in vals])
+        new.by_row = keys
+        return new
+
+    def _local(self, out: DCtx, src: DCtx) -> DCtx:
+        """``out``, made on each device from its share of ``src``: sharded
+        at ``src``'s rows, with ``src``'s mark."""
+        out = self._carry(out, src)
+        out.by_row = getattr(src, "by_row", None)
         return out
 
+    def _run_distinct(self, node: P.Distinct) -> DCtx:
+        child = self.run(node.child)
+        if not getattr(child, "sharded", False):
+            out = super()._run_distinct(node)
+            out.sharded = False
+            return out
+        try:
+            placed = self._by_row(
+                child, tuple((node.binding, name) for name, _ in node.output))
+        except DeviceExecError:
+            placed = self._replicate(child, "distinct")
+        self.stash(node.child, placed)
+        self._cache.pop(id(node), None)
+        out = super()._run_distinct(node)
+        if not placed.sharded:
+            out.sharded = False
+            return out
+        self._note("distinct.colocated")
+        return self._local(out, placed)
+
     def _run_setop(self, node: P.SetOp) -> DCtx:
+        if node.kind not in ("intersect", "except") or not getattr(
+                self.run(node.left), "sharded", False):
+            return self._setop_everywhere(node)
+        # a membership of each left row, which each device can decide
+        # for its own where every right row equal to one is on its device
+        lctx, rctx = self.run(node.left), self.run(node.right)
+        if getattr(rctx, "sharded", False):
+            try:
+                lctx, rctx = self._colocate(node, lctx, rctx)
+            except DeviceExecError:
+                return self._setop_everywhere(node)
+            self._note("setop.colocated")
+        else:
+            self._note("setop.local")
+        self.stash(node.left, lctx)
+        self.stash(node.right, rctx)
+        self._cache.pop(id(node), None)
+        return self._local(super()._run_setop(node), lctx)
+
+    def _setop_everywhere(self, node: P.SetOp) -> DCtx:
+        """The set operation over both sides whole on every device."""
         for side in (node.left, node.right):
             c = self.run(side)
             if getattr(c, "sharded", False):
@@ -905,6 +1002,46 @@ class _DistTrace(dx._Trace):
         out = super()._run_setop(node)
         out.sharded = False
         return out
+
+    def _colocate(self, node: P.SetOp, lctx: DCtx, rctx: DCtx) -> tuple:
+        """Both sides of an INTERSECT / EXCEPT with their equal rows on
+        one device: the left placed by its own row (and so marked), the
+        right by its row read as the left's values. Where that reading
+        changed a string column's codes the right is not marked."""
+        lkeys = tuple((node.left.binding, n) for n, _ in node.left.output)
+        rkeys = tuple((node.right.binding, n) for n, _ in node.right.output)
+        lvals = [lctx.cols[k] for k in lkeys]
+        rvals = [rctx.cols[k] for k in rkeys]
+        self._exact(lvals + rvals)
+        onto = [self._onto(lv, rv) for lv, rv in zip(lvals, rvals)]
+        if all(a is rv.arr for a, rv in zip(onto, rvals)):
+            rctx = self._by_row(rctx, rkeys)
+        else:
+            rctx = self._exchange_by_row(rctx, [
+                (a, rv.valid, *_domain(rv)) for a, rv in zip(onto, rvals)])
+        return self._by_row(lctx, lkeys), rctx
+
+    def _onto(self, lv: DVal, rv: DVal):
+        """The right column's values as the left's: a string's codes in
+        the left's dictionary, -1 where the left has no such string (no
+        left row can equal it); the values themselves where both sides
+        share a dictionary or hold no strings."""
+        if lv.sdict is None and rv.sdict is None:
+            return rv.arr
+        if lv.sdict is None or rv.sdict is None:
+            raise DeviceExecError("set operation of string and non-string")
+        if lv.sdict is rv.sdict or (len(lv.sdict) == len(rv.sdict)
+                                    and np.array_equal(lv.sdict, rv.sdict)):
+            return rv.arr
+        left, right = lv.sdict.astype(str), rv.sdict.astype(str)
+        codes = np.full(len(right), -1, np.int32)
+        if len(left):
+            order = np.argsort(left, kind="stable")
+            at = np.clip(np.searchsorted(left[order], right), 0,
+                         len(left) - 1)
+            codes = np.where(left[order][at] == right, order[at],
+                             -1).astype(np.int32)
+        return self._take(jnp.asarray(codes), rv.arr)
 
     def _run_window(self, node: P.Window) -> DCtx:
         # windows run post-aggregation on small relations; replicate

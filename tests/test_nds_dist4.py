@@ -17,7 +17,12 @@ mechanism scopes (``op.<kind>``, ``exchange``, ``replicate``,
 ``gather``): the same programs lowered with them and without them, equal
 to the byte but for locations, the compiled programs equal but for
 their metadata, and every instruction of them that the trace made under
-an operator.
+an operator.  DISTINCT and INTERSECT / EXCEPT on each chip's share
+after an exchange by the row hash: against the CPU oracle over
+duplicates on every shard, NULLs, two dictionaries, a replicated side
+and a floating-point column, on the flat and the 2-D mesh; query38's
+``kernels``; and every program but query38's lowered to the same text
+as with the whole relation on every device.
 """
 
 import contextlib
@@ -276,10 +281,13 @@ def test_rows_lost_after_a_replicate_read_rows_wrong(raw, monkeypatch):
 
 # ------------------------------------- (b) what the trace says it gathered
 
+# query38 at this scale: web_sales is under the shard threshold, so its
+# join replicates customer's shard; the INTERSECT chain stays sharded to
+# the root, which gathers it
 WHO = {"query7": {"replicate.limit": 1},
        "query27": {"replicate.setop": 2},
        "query98": {"replicate.window": 1},
-       "query38": {"replicate.distinct": 2, "replicate.join": 1},
+       "query38": {"replicate.join": 1, "replicate.root": 1},
        "query21": {"replicate.limit": 1}}
 
 
@@ -394,6 +402,33 @@ def _parent_replicate(self, ctx, who=None):
     return self._stamp(out, False, _rows(ctx) * self.n_dev)
 
 
+def _parent_run_distinct(self, node):
+    """``_DistTrace._run_distinct`` as the parent commit had it: a
+    sharded input whole on every device."""
+    from nds_tpu.engine import device_exec as dx
+    child = self.run(node.child)
+    if getattr(child, "sharded", False):
+        self.stash(node.child, self._replicate(child, "distinct"))
+        self._cache.pop(id(node), None)
+    out = dx._Trace._run_distinct(self, node)
+    out.sharded = False
+    return out
+
+
+def _parent_run_setop(self, node):
+    """``_DistTrace._run_setop`` as the parent commit had it: both sides
+    whole on every device, whatever the kind."""
+    from nds_tpu.engine import device_exec as dx
+    for side in (node.left, node.right):
+        c = self.run(side)
+        if getattr(c, "sharded", False):
+            self.stash(side, self._replicate(c, "setop"))
+    self._cache.pop(id(node), None)
+    out = dx._Trace._run_setop(self, node)
+    out.sharded = False
+    return out
+
+
 def _parent_nulls_first(item):
     """``planner._nulls_first`` as the parent commit had it: what the
     ORDER BY says or None, which every executor's sort reads as NULL
@@ -437,14 +472,17 @@ def _no_scope(_name):
 
 # (side, mixes lowered, the scan bound taken out, the replicate's notes
 # and the ORDER BY default put back to the commit before them, the
-# scopes taken out)
+# scopes taken out, DISTINCT and INTERSECT / EXCEPT put back to the
+# whole relation on every device)
 SIDES = (("change", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False,
-          False),
+          False, False),
          ("no_scan_bound", OLDER_MIXES + ((MIX, "nds", N_DEV),), True, False,
-          False),
-         ("parent", OLDER_MIXES, True, True, False),
+          False, False),
+         ("parent", OLDER_MIXES, True, True, False, False),
          ("no_scopes", OLDER_MIXES + ((MIX, "nds", N_DEV),), False, False,
-          True))
+          True, False),
+         ("sets_everywhere", OLDER_MIXES + ((MIX, "nds", N_DEV),), False,
+          False, False, True))
 # the sides whose programs are compiled afresh and kept as text: jax's
 # persistent cache leaves metadata out of its key, so a program it served
 # would carry the scopes of whichever tree compiled it first
@@ -458,9 +496,10 @@ def older_lowered(raw, tmp_path_factory):
     templates), as this tree lowers them, with the scan bound taken
     out, with the two changes that came with this cell put back too
     (the replicate without its notes, the planner without its ORDER BY
-    default), and with the scopes taken out (a recorder round
-    ``cache.aot.lower_and_compile``); the compiled text of this tree's
-    programs with the scopes and without them."""
+    default), with the scopes taken out, and with DISTINCT and
+    INTERSECT / EXCEPT replicating their sharded inputs again (a
+    recorder round ``cache.aot.lower_and_compile``); the compiled text
+    of this tree's programs with the scopes and without them."""
     from benchmarks import run
     from nds_tpu.cache import aot
     from nds_tpu.engine import device_exec as dx
@@ -474,13 +513,18 @@ def older_lowered(raw, tmp_path_factory):
     counts = dx._Trace.kernel_counts
     import jax
     texts, kernels, compiled = {}, {}, {}
-    for side, mixes, unbound, before_cell, unscoped in SIDES:
+    for side, mixes, unbound, before_cell, unscoped, everywhere in SIDES:
         kept = texts.setdefault(side, {})
         noted = kernels.setdefault(side, {})
         done = compiled.setdefault(side, {})
         with pytest.MonkeyPatch.context() as mp:
             if unscoped:
                 mp.setattr(jax, "named_scope", _no_scope)
+            if everywhere:
+                mp.setattr(dist_exec._DistTrace, "_run_distinct",
+                           _parent_run_distinct)
+                mp.setattr(dist_exec._DistTrace, "_run_setop",
+                           _parent_run_setop)
             if unbound:
                 mp.setattr(dx._Trace, "_scan_bound",
                            staticmethod(_no_scan_bound))
@@ -526,7 +570,7 @@ def older_lowered(raw, tmp_path_factory):
 def test_the_older_cells_statements_are_the_ones_lowered(older_lowered):
     texts = older_lowered["texts"]
     assert sorted(texts["change"]) == sorted(texts["no_scan_bound"]) == \
-        sorted(OLDER + OWN)
+        sorted(texts["sets_everywhere"]) == sorted(OLDER + OWN)
     assert sorted(texts["parent"]) == sorted(OLDER)
     sf1, sf5 = (_statements(m) for m in ("power_nds_h", "power_nds_h_sf5"))
     assert [s.sql for s in sf1] == [s.sql for s in sf5]
@@ -568,6 +612,36 @@ def test_only_a_program_that_notes_the_scan_bound_changes(older_lowered,
         else:
             assert after == before
             assert key not in SCAN_BOUNDED
+
+
+# the one statement of the six cells whose program the row-hash
+# colocation of DISTINCT and INTERSECT / EXCEPT changes
+COLOCATED = {f"{MIX}:query38#0"}
+COLOCATION_NOTES = ("distinct.colocated", "setop.colocated", "setop.local")
+
+
+@pytest.mark.parametrize("key", OLDER + OWN)
+def test_only_query38_changes_with_the_row_hash_colocation(older_lowered,
+                                                           key):
+    """Every program of `nds_h_sf1.dist4`, of this cell's query7,
+    query27 (its ROLLUP's UNION ALL), query98 and query21, and every
+    single-device program of `short`, `power_nds_h` and `power_nds`,
+    lowers to the same text with the colocation and with DISTINCT and
+    INTERSECT / EXCEPT replicating their sharded inputs as before it;
+    query38's first program (the staged INTERSECT chain) differs, and
+    it alone notes the colocation."""
+    texts, kernels = older_lowered["texts"], older_lowered["kernels"]
+    change, before = texts["change"][key], texts["sets_everywhere"][key]
+    assert len(change) == len(before) == len(kernels["change"][key]) >= 1
+    differs = []
+    for after, prior, noted, plain in zip(
+            change, before, kernels["change"][key],
+            kernels["sets_everywhere"][key]):
+        assert not set(COLOCATION_NOTES) & set(plain)
+        colocated = bool(set(COLOCATION_NOTES) & set(noted))
+        assert (after != prior) == colocated
+        differs.append(colocated)
+    assert any(differs) == (key in COLOCATED)
 
 
 @pytest.mark.parametrize("key", OLDER + OWN)
@@ -854,3 +928,187 @@ def test_traffic_is_the_issues_list_with_its_files():
                                stmt["template"] + ".py")) as f:
             source = f.read()
         assert not re.search(r"^\s*(from|import)\s+nds_tpu", source, re.M)
+
+
+# ------------- (g) DISTINCT and INTERSECT / EXCEPT by a hash of the row
+
+def _set_tables():
+    """Three tables on the four-device mesh (shard threshold 1000): ``a``
+    (8192 rows) and ``b`` (6144) sharded, ``c`` (600) replicated. Their
+    (k, s) rows repeat across every shard, a tenth of each column is
+    NULL over whatever the slot holds, and each table's strings carry a
+    dictionary of their own: ``b``'s and ``c``'s names overlap ``a``'s
+    in part. ``f`` is a floating-point column."""
+    from nds_tpu.engine.types import FLOAT64, INT32, STRING, Schema
+    rng = np.random.default_rng(37)
+    names = np.array(["ash", "birch", "cedar", "elm", "fir", "hazel",
+                      "larch", "oak", "pine", "yew"], dtype=object)
+    pools = {"a": names[:8], "b": names[3:], "c": names[5:]}
+    sizes = {"a": 8192, "b": 6144, "c": 600}
+    schemas, arrays = {}, {}
+    for t, n in sizes.items():
+        schemas[t] = Schema.of((f"{t}_id", INT32, False),
+                               (f"{t}_k", INT32, True),
+                               (f"{t}_s", STRING, True),
+                               (f"{t}_f", FLOAT64, False))
+        k_valid = rng.random(n) >= 0.1
+        s_valid = rng.random(n) >= 0.1
+        arrays[t] = {
+            f"{t}_id": np.arange(n, dtype=np.int32),
+            # garbage under the NULLs: no two alike
+            f"{t}_k": np.where(k_valid, rng.integers(0, 200, n),
+                               1000 + np.arange(n)).astype(np.int32),
+            f"{t}_k#null": k_valid,
+            f"{t}_s": rng.choice(pools[t], n),
+            f"{t}_s#null": s_valid,
+            f"{t}_f": rng.integers(0, 4, n) * 0.5}
+    return schemas, arrays
+
+
+def _set_sessions(mesh=None):
+    """(the CPU oracle, the four-device session) over ``_set_tables``;
+    ``mesh``: the four devices as a 2-D (host, lane) mesh instead."""
+    from nds_tpu.engine.session import Session
+    from nds_tpu.io.host_table import from_arrays
+    from nds_tpu.parallel.dist_exec import make_distributed_factory
+    from nds_tpu.sql.planner import CatalogInfo
+    schemas, arrays = _set_tables()
+    cat = CatalogInfo(schemas, {t: [f"{t}_id"] for t in schemas},
+                      {t: len(a[f"{t}_id"]) for t, a in arrays.items()})
+    out = []
+    for factory in (None, make_distributed_factory(
+            mesh=mesh, n_devices=None if mesh else N_DEV,
+            shard_threshold=1000)):
+        s = Session(cat, factory) if factory else Session(cat)
+        for t in schemas:
+            s.register_table(from_arrays(t, schemas[t], arrays[t]))
+        out.append(s)
+    return out
+
+
+_A = "select a_k, a_s from a"
+# case: (sql, the notes of its `kernels` that say where it ran, the
+# exchanges its program holds: one a relation placed by its row hash)
+SET_SQL = {
+    # duplicates on every shard, NULLs equal to NULLs
+    "distinct": ("select distinct a_k, a_s from a order by a_k, a_s",
+                 {"distinct.colocated": 1}, 1),
+    # nine values (eight names and NULL): sized as a few-keys group-by
+    # is, so that one device can take them all
+    "distinct_few_values": ("select distinct a_s from a order by a_s",
+                            {"distinct.colocated": 1}, 1),
+    # one dictionary a column: each side is exchanged by its raw row, and
+    # the planner's DISTINCT above reads the mark and exchanges nothing
+    "intersect": (f"{_A} where a_id < 5000 intersect {_A} where a_id >= 3000"
+                  " order by a_k, a_s",
+                  {"setop.colocated": 1, "distinct.colocated": 1}, 2),
+    "except": (f"{_A} where a_id < 5000 except {_A} where a_id >= 3000"
+               " and a_k < 100 order by a_k, a_s",
+               {"setop.colocated": 1, "distinct.colocated": 1}, 2),
+    # query38's shape: three DISTINCT branches, two INTERSECTs and the
+    # planner's two DISTINCTs above them; only the branches exchange
+    "distinct_chain": (
+        "select distinct a_k, a_s from a where a_id < 6000 intersect "
+        "select distinct a_k, a_s from a where a_id >= 2000 intersect "
+        "select distinct a_k, a_s from a where a_id % 2 = 0 "
+        "order by a_k, a_s",
+        {"distinct.colocated": 5, "setop.colocated": 2}, 3),
+    # different dictionaries: the right side's codes are read in the
+    # left's dictionary (-1 for a name the left lacks) and hashed so; the
+    # left keeps its mark, and the DISTINCT above exchanges nothing
+    "intersect_two_dictionaries": (
+        "select a_k, a_s from a intersect select b_k, b_s from b "
+        "order by a_k, a_s",
+        {"setop.colocated": 1, "distinct.colocated": 1}, 2),
+    "except_two_dictionaries": (
+        "select b_k, b_s from b except select a_k, a_s from a "
+        "order by b_k, b_s",
+        {"setop.colocated": 1, "distinct.colocated": 1}, 2),
+    # the right side replicated: each device checks its own left rows
+    # against the whole right, no collective
+    "intersect_right_replicated": (
+        "select a_k, a_s from a intersect select c_k, c_s from c "
+        "order by a_k, a_s",
+        {"setop.local": 1, "distinct.colocated": 1}, 1),
+    "except_right_replicated": (
+        "select a_k, a_s from a except select c_k, c_s from c "
+        "order by a_k, a_s",
+        {"setop.local": 1, "distinct.colocated": 1}, 1),
+    # the left side replicated: the right is gathered, as before
+    "except_left_replicated": (
+        "select c_k, c_s from c except select a_k, a_s from a "
+        "order by c_k, c_s",
+        {"replicate.setop": 1}, 0),
+    # a floating-point column does not hash exactly: whole relations on
+    # every device, as before
+    "distinct_float": ("select distinct a_k, a_f from a order by a_k, a_f",
+                       {"replicate.distinct": 1}, 0),
+}
+
+
+@pytest.mark.parametrize("mesh", ["flat", "2x2"])
+@pytest.mark.parametrize("case", sorted(SET_SQL))
+def test_set_operations_over_sharded_inputs_match_the_cpu_oracle(case, mesh):
+    """On the flat mesh and on the (host, lane) mesh, whose exchange is
+    two shuffles (host, then lane) that place a key where the flat one
+    does."""
+    from nds_tpu.obs import metrics as obs_metrics
+    from nds_tpu.parallel.mesh import make_multihost_mesh
+    sql, notes, exchanges = SET_SQL[case]
+    cpu_s, sharded_s = _set_sessions(
+        make_multihost_mesh(2, 2) if mesh == "2x2" else None)
+    if mesh == "2x2":
+        exchanges *= 2
+    want = cpu_s.sql(sql).to_pandas()
+    before = obs_metrics.snapshot()["counters"]
+    got = sharded_s.sql(sql).to_pandas()
+    after = obs_metrics.snapshot()["counters"]
+    assert len(got) == len(want) > 0
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert got.iloc[:, 0].isna().any()          # NULL rows came through
+    moved = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("exchanges_traced_total",
+                       "exchange_overflow_retries_total")}
+    assert moved == {"exchanges_traced_total": exchanges,
+                     "exchange_overflow_retries_total": 0}
+    kernels = sharded_s._executor_factory(sharded_s.tables).last_timings[
+        "__kernels"]
+    where = {k: v for k, v in kernels.items()
+             if k in COLOCATION_NOTES
+             or k in ("replicate.distinct", "replicate.setop")}
+    assert where == notes
+
+
+def test_query38_runs_its_distincts_and_intersects_on_each_chips_share(
+        raw, tmp_path):
+    """With web_sales sharded as well (as at SF1), query38's five
+    DISTINCTs and two INTERSECTs run on each chip's share: three
+    exchanges by the row hash (one a branch), no replicate but the
+    root's, no overflow, the reference's answer."""
+    from benchmarks import run
+    from nds_tpu.obs import metrics as obs_metrics
+    session = _session(raw, shards=N_DEV)
+    ex = _sharded_executor(session)
+    ex.shard_threshold = 4096
+    assert ex._is_sharded("web_sales")
+    pipe = session._executor_factory(session.tables)
+    before = obs_metrics.snapshot()["counters"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("NDS_TPU_TRACE", str(tmp_path / "t.jsonl"))
+        log = []
+        _replicate_log(mp, log)
+        rec = run.run_statement(session, _statement("query38"))
+    after = obs_metrics.snapshot()["counters"]
+    assert rec["error"] is None and rec["placement"] == "sharded"
+    assert _verdict([rec], raw)["correct"] is True
+    kernels = ex.last_timings["__kernels"]
+    assert kernels["distinct.colocated"] == 5
+    assert kernels["setop.colocated"] == 2
+    assert "setop.local" not in kernels
+    assert {k: v for k, v in kernels.items()
+            if k.startswith("replicate.")} == {"replicate.root": 1}
+    assert [who for _r, who, _n, _w in log] == ["root"]
+    launches = _root_of(pipe.last_query_span).find("device.launch")
+    assert sum(a.attrs["replicates"] for a in launches) == 1
+    assert after.get("exchange_overflow_retries_total", 0) == before.get(
+        "exchange_overflow_retries_total", 0)
