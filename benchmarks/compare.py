@@ -17,6 +17,10 @@ with a limit of its own from the configuration file:
                      read and what the lower-precision control reads
                      (PERF.md section 2).
 
+A mix that writes adds two (run.py): ``writes_wrong``, write executions
+after which a table they write holds another row count, or other rows
+(``table_digest``), than the reference's; ``tables_not_restored``.
+
 Nothing of the program is imported: a result is taken apart by the
 names of its column types.
 """
@@ -174,6 +178,32 @@ def verdict(numbers: dict, limits: dict) -> "tuple[bool, dict]":
               for k in numbers}
     ok = all(c["value"] <= c["limit"] for c in checks.values())
     return ok, checks
+
+
+NULL_NUMBER = -(2 ** 62)      # a NULL number, in a table digest
+
+
+def table_digest(columns: dict) -> "tuple[int, str]":
+    """(rows, digest) of a whole table, in no order: ``columns`` maps
+    each column name, in the schema's order, to ``(values, kind)`` with
+    a NULL as NaN or None.  A number is taken as an exact int64 (scaled
+    decimals, days and keys are integers), a string as ``_canon`` takes
+    it (no trailing blanks; a NULL is the empty string, as the raw
+    files write it).  Each row is hashed with pandas' fixed-key hash and
+    the row hashes are summed mod 2**64, so equal multisets of rows give
+    equal digests whatever their order."""
+    frame = {}
+    for name, (values, kind) in columns.items():
+        if kind == "str":
+            frame[name] = pd.Series(values, dtype=object).map(_canon)
+        else:
+            v = np.asarray(values)
+            if v.dtype.kind == "f":
+                v = np.where(np.isnan(v), NULL_NUMBER, v)
+            frame[name] = pd.Series(v.astype(np.int64))
+    df = pd.DataFrame(frame)
+    rows = pd.util.hash_pandas_object(df, index=False).to_numpy(np.uint64)
+    return len(df), f"{int(np.add.reduce(rows, dtype=np.uint64)):016x}"
 
 
 def frame_kinds(frame: pd.DataFrame) -> list:

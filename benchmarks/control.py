@@ -31,17 +31,24 @@ if ROOT not in sys.path:
 def control_numbers(config: dict, mix: dict, seed: int, raw_dir: str,
                     dtype=np.float32, tables=None) -> dict:
     """The compared numbers when the reference in ``dtype`` stands in
-    the program's place, per statement and at their worst."""
-    from benchmarks import compare, generator
+    the program's place, per statement and at their worst.  A mix's
+    writes are laid over the tables first, as in a pass."""
+    from benchmarks import compare, generator, run
     from benchmarks.reference import rawdata
-    tables = tables or rawdata.Tables(config["suite"], raw_dir)
+    tables = tables or run.reference_tables(config, raw_dir)
     exact, low = rawdata.Real(), rawdata.Real(dtype)
     sets = generator.variants(mix, seed)
     rows_wrong, gap, per_stmt = 0, 0.0, {}
+    tables.overlay.clear()
     for stmt in generator.distinct(mix, sets):
-        fn = importlib.import_module(
-            "benchmarks.reference." + stmt.template.replace("/", ".")
-        ).reference
+        module = importlib.import_module(
+            "benchmarks.reference." + stmt.template.replace("/", "."))
+        if stmt.writes:
+            for table, frame in module.apply(tables, stmt.params,
+                                             exact).items():
+                tables.write(table, frame)
+            continue
+        fn = module.reference
         ref = fn(tables, stmt.params, exact)
         got = fn(tables, stmt.params, low).reset_index(drop=True)
         got.columns = range(got.shape[1])
@@ -50,6 +57,7 @@ def control_numbers(config: dict, mix: dict, seed: int, raw_dir: str,
         per_stmt[stmt.label] = {"ok": ok, "rel_gap": g, "note": note}
         rows_wrong += 0 if ok else 1
         gap = max(gap, g)
+    tables.overlay.clear()
     return {"numbers": {"failed_statements": 0, "rows_wrong": rows_wrong,
                         "repeats_differ": 0, "max_rel_gap": gap},
             "per_stmt": per_stmt}
@@ -63,7 +71,6 @@ def main(argv=None) -> int:
                     default=os.path.join(ROOT, "BENCHMARK.json"))
     args = ap.parse_args(argv)
     from benchmarks import compare, generator, run
-    from benchmarks.reference import rawdata
     spec = run.load_cell(args.benchmark, args.workload)
     config = spec["config"]
     try:
@@ -72,7 +79,7 @@ def main(argv=None) -> int:
         run.kill_children()
     raw_dir = os.path.join(root, "raw")
     mix = generator.load_mix(spec["cell"]["traffic"])
-    tables = rawdata.Tables(config["suite"], raw_dir)
+    tables = run.reference_tables(config, raw_dir)
     all_failed = True
     for seed in args.seeds:
         out = control_numbers(config, mix, seed, raw_dir, tables=tables)

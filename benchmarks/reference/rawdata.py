@@ -6,6 +6,13 @@ engine has loaded: transcode, load and upload are then inside what
 ``correct`` covers.  It imports nothing of ``nds_tpu``; the column order
 comes from a frozen ``schema.json`` beside each suite's statements.
 
+A configuration with a refresh set (``"refresh": {"update": N}``) also
+has the set's staging tables, read from ``<raw>/../refresh<N>`` with the
+column order of ``refresh_schema.json``.  A write's reference
+(``apply``) gives the whole new content of the tables it writes; the
+harness lays that over the raw tables (``Tables.overlay``), so that the
+reads of a pass see the pass's writes, and drops it after the pass.
+
 Values as the statements see them: ``int`` -> int64, ``decN`` -> int64
 scaled by 10**N (exact; predicates compare scaled integers), ``date`` ->
 int64 days since 1970-01-01, ``str`` -> object.  NULLs (empty fields)
@@ -39,7 +46,8 @@ def plus_months(iso: str, n: int) -> int:
 class Tables:
     """``T(table, columns)`` -> DataFrame of just those columns."""
 
-    def __init__(self, suite: str, raw_dir: str):
+    def __init__(self, suite: str, raw_dir: str,
+                 refresh_dir: "str | None" = None):
         with open(os.path.join(HERE, suite, "schema.json")) as f:
             doc = json.load(f)
         self.schema = doc["tables"]
@@ -47,28 +55,68 @@ class Tables:
         self.cache_dir = os.path.join(os.path.dirname(
             os.path.abspath(raw_dir)), "ref_cache")
         self._cache: dict = {}
+        self.staging: dict = {}       # staging table -> its directory
+        if refresh_dir is not None:
+            with open(os.path.join(HERE, suite,
+                                   "refresh_schema.json")) as f:
+                staging = json.load(f)["tables"]
+            self.schema = {**self.schema, **staging}
+            self.staging = {t: refresh_dir for t in staging}
+        self.overlay: dict = {}       # table -> whole content after writes
+
+    def columns(self, table: str) -> list:
+        return [n for n, _k in self.schema[table]]
 
     def _paths(self, table: str) -> list:
-        tdir = os.path.join(self.raw_dir, table)
+        tdir = os.path.join(self.staging.get(table, self.raw_dir), table)
         if os.path.isdir(tdir):
             return sorted(os.path.join(tdir, f) for f in os.listdir(tdir)
                           if not f.startswith(".") and not f.startswith("_"))
         raise FileNotFoundError(f"no raw files for table {table!r} "
-                                f"under {self.raw_dir}")
+                                f"under {os.path.dirname(tdir)}")
 
     def __call__(self, table: str, columns: list) -> pd.DataFrame:
+        if table in self.overlay:
+            return self.overlay[table][list(columns)].copy()
         missing = [c for c in columns if (table, c) not in self._cache]
         if missing:
             self._load(table, missing)
         return pd.DataFrame({c: self._cache[(table, c)] for c in columns})
 
+    def write(self, table: str, frame: pd.DataFrame) -> None:
+        """Lay a write's whole new content of ``table`` over it, held to
+        the values ``T`` gives: every column of the schema in its order,
+        a number column int64, or float64 where it holds a NULL."""
+        if list(frame.columns) != self.columns(table):
+            raise ValueError(f"the new content of {table} has columns "
+                             f"{list(frame.columns)}, not the schema's")
+        out = {}
+        for c, kind in self.schema[table]:
+            v = frame[c]
+            if kind == "str":
+                out[c] = v.astype(object).where(v.notna(), None)
+            else:
+                v = pd.to_numeric(v).to_numpy(np.float64)
+                out[c] = v if np.isnan(v).any() else v.astype(np.int64)
+        self.overlay[table] = pd.DataFrame(out)
+
+    def rows(self, table: str) -> int:
+        """The table's row count as the reads see it."""
+        if table in self.overlay:
+            return len(self.overlay[table])
+        return len(self(table, self.columns(table)[:1]))
+
     def _load(self, table: str, columns: list) -> None:
         """Columns come from the reference's own column cache
-        (<raw>/../ref_cache/<table>/<column>.parquet), which it fills
+        (<raw>/../ref_cache/<table>/<column>.parquet, a staging table's
+        under ref_cache/<refresh dir's name>/), which it fills
         from the raw text on first use: every run is a new process, and
         parsing 6M-row text files again each time would make the
         reference longer than the window."""
         cdir = os.path.join(self.cache_dir, table)
+        if table in self.staging:
+            cdir = os.path.join(self.cache_dir, os.path.basename(
+                os.path.normpath(self.staging[table])), table)
         os.makedirs(cdir, exist_ok=True)
         todo = [c for c in columns
                 if not os.path.exists(os.path.join(cdir, c + ".parquet"))]

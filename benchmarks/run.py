@@ -5,9 +5,11 @@
 The window drives the shipped path: ``power_core.make_session`` with the
 configuration's engine template as shipped, ``power_core.load_warehouse``,
 then ``session.sql(text)`` per statement, one closed loop of whole passes
-in the one process that holds the chip.  Datagen and transcode are
-host-only children.  Everything that belongs to one cell is data: the
-cell's configuration file (benchmarks/configs/), its traffic mix
+in the one process that holds the chip.  A pass runs its mix's writes
+(refresh functions, through the engine's DML) before its reads, and
+the tables it wrote are restored outside the clock.  Datagen and
+transcode are host-only children.  Everything that belongs to one cell
+is data: the cell's configuration file (benchmarks/configs/), its traffic mix
 (benchmarks/traffic/), the plain references (benchmarks/reference/) and
 the per-layer metric readers (benchmarks/layers/), all found by the names
 in BENCHMARK.json.  This file names no cell, statement or scale.
@@ -32,6 +34,7 @@ import os                         # noqa: E402
 import shutil                     # noqa: E402
 import signal                     # noqa: E402
 import statistics                 # noqa: E402
+from contextlib import nullcontext  # noqa: E402
 import subprocess                 # noqa: E402
 import sys                        # noqa: E402
 import threading                  # noqa: E402
@@ -166,10 +169,20 @@ def kill_children() -> None:
             proc.wait()
 
 
+def refresh_dir(root: str, config: dict) -> "str | None":
+    """Where a configuration's refresh set (``"refresh": {"update":
+    N}``) is generated: ``<root>/refresh<N>``; None without one."""
+    refresh = config.get("refresh")
+    if not refresh:
+        return None
+    return os.path.join(root, f"refresh{int(refresh['update'])}")
+
+
 def build_warehouse(config: dict) -> str:
     """The configuration's population, generated and transcoded once per
     checkout by host-only children; later runs reuse it.  It is a
-    function of the scale factor alone, as with dbgen/dsdgen."""
+    function of the scale factor alone, as with dbgen/dsdgen, and of
+    the update number for a refresh set."""
     root = os.path.join(WORK, config["name"])
     ready = os.path.join(root, "ready.json")
     if os.path.exists(ready):
@@ -181,6 +194,11 @@ def build_warehouse(config: dict) -> str:
     host_child([f"nds_tpu.{suite}.gen_data", str(config["scale"]),
                 str(config["gen_parallel"]), os.path.join(root, "raw"),
                 "--overwrite_output"], os.path.join(root, "gen.log"))
+    refresh = refresh_dir(root, config)
+    if refresh is not None:
+        host_child([f"nds_tpu.{suite}.gen_data", str(config["scale"]), "1",
+                    refresh, "--update", str(config["refresh"]["update"]),
+                    "--overwrite_output"], os.path.join(root, "gen.log"))
     t1 = time.monotonic()
     host_child([f"nds_tpu.{suite}.transcode", os.path.join(root, "raw"),
                 os.path.join(root, "wh"),
@@ -201,7 +219,11 @@ def engine_config(config: dict):
 
 def statement_facts(session) -> dict:
     """What the pipeline recorded about the statement that just ran."""
-    pipe = session._executor_factory(session.tables)
+    return schedule_facts(session._executor_factory(session.tables))
+
+
+def schedule_facts(pipe) -> dict:
+    """What the pipeline recorded about the query it ran last."""
     sched = getattr(pipe, "last_schedule", None) or {}
     timings = getattr(pipe, "last_timings", None) or {}
     return {"placement": sched.get("placement"),
@@ -214,6 +236,14 @@ def on_device(facts: dict) -> bool:
     return (facts.get("placement") in ON_DEVICE
             and not facts.get("reschedules")
             and "cpu" not in facts.get("ladder", []))
+
+
+def placed(rec: dict) -> bool:
+    """Every query of the statement ended on the device: a read's one,
+    a write's engine-run SELECTs (an INSERT's, a DELETE's subqueries).
+    A DELETE's predicate is evaluated on the host by design and is no
+    query."""
+    return all(on_device(f) for f in rec.get("selects", [rec]))
 
 
 def warm_marker(cache_dir: str, config_name: str, sql: str) -> str:
@@ -265,6 +295,88 @@ def concurrent_warm_up(suite, econf, tables: dict, todo: list,
     return took
 
 
+def run_write(session, stmt, keep: bool = False) -> dict:
+    """One write (a refresh function): each of its statements through
+    ``session.sql``, the engine's DML path.  Its record holds the
+    schedule of every query the pipeline ran inside it and the row
+    count of each table it writes, as they stand after it; with
+    ``keep``, those tables' rows (``kept_rows``), for the comparison of
+    their content after the window."""
+    from nds_tpu.columnar import delta
+    pipe = session._executor_factory(session.tables)
+    inner, selects = pipe.execute, []
+
+    def execute(*args, **kwargs):
+        try:
+            return inner(*args, **kwargs)
+        finally:
+            selects.append(schedule_facts(pipe))
+
+    t0 = time.perf_counter()
+    error = None
+    pipe.execute = execute
+    try:
+        for part in stmt.parts:
+            session.sql(part)
+    except Exception as exc:  # noqa: BLE001 - counted in `failed`
+        error = f"{type(exc).__name__}: {str(exc)[:500]}"
+    finally:
+        del pipe.execute
+    t1 = time.perf_counter()
+    rows = ({t: int(delta.visible_rows(session.tables[t]))
+             for t in stmt.writes} if error is None else {})
+    rec = {"stmt": stmt, "start": t0, "end": t1, "result": None,
+           "error": error, "selects": selects, "rows": rows}
+    if keep and error is None:
+        rec["tables"] = {t: kept_rows(session.tables[t])
+                         for t in stmt.writes}
+    return rec
+
+
+def kept_rows(table) -> tuple:
+    """(live-row mask or None, columns): what a table holds for the
+    program, kept past its pass without keeping the table.  Each column
+    is a new object over the same arrays: a column's device copy lives
+    as long as the column object does, and a kept table would hold its
+    pass's uploads on the chip."""
+    import dataclasses
+    from nds_tpu.columnar import delta
+    return delta.live_mask(table), {
+        name: dataclasses.replace(col) for name, col in table.columns.items()}
+
+
+def live_columns(kept: tuple, fields: list) -> dict:
+    """The live rows of ``kept_rows``' reading, column by column as
+    ``compare.table_digest`` takes them."""
+    import numpy as np
+    live, columns = kept
+    out = {}
+    for name, kind in fields:
+        col = columns[name]
+        values = col.decode() if col.is_string else np.asarray(col.values)
+        valid = col.null_mask
+        if live is not None:
+            values = values[live]
+            valid = None if valid is None else valid[live]
+        if valid is not None and kind != "str":
+            values = np.where(valid, values.astype(np.float64), np.nan)
+        out[name] = (values, kind)
+    return out
+
+
+def restore(session, loaded: dict, written, spans: "Spans") -> None:
+    """The tables a pass wrote back to their load-time selves, as the
+    program's own compaction swaps a table (register, then the scoped
+    invalidation): every pass then does the same work.  Outside the
+    clock; a ``restore`` span."""
+    t = time.monotonic()
+    names = sorted(written)
+    for name in names:
+        session.register_table(loaded[name])
+    session.invalidate(tables=names)
+    spans.add("restore", t, time.monotonic(), tables=names)
+
+
 def run_statement(session, stmt) -> dict:
     """One client-side statement: call to host rows."""
     t0 = time.perf_counter()
@@ -281,17 +393,48 @@ def run_statement(session, stmt) -> dict:
 
 # ------------------------------------------------------------ the window
 
+def run_pass(session, statements: list, records: list, i: int,
+             annotate=None, kept: "set | None" = None) -> set:
+    """Pass ``i``'s statements, their records appended to ``records``;
+    returns the tables its writes mutated.  The first pass of each
+    sequence of writes keeps the tables they leave (``kept`` holds the
+    sequences that have)."""
+    written: set = set()
+    state = tuple(s.sql for s in statements if s.writes)
+    keep = kept is not None and bool(state) and state not in kept
+    if keep:
+        kept.add(state)
+    for stmt in statements:
+        with (annotate(f"bench.stmt:{stmt.label}") if annotate
+              else nullcontext()):
+            rec = (run_write(session, stmt, keep) if stmt.writes
+                   else run_statement(session, stmt))
+        rec["pass"] = i
+        written.update(stmt.writes)
+        records.append(rec)
+    return written
+
+
 def measure(session, sets: dict, names: list, seconds: float,
-            trace_dir: "str | None", slice_s: float) -> dict:
+            trace_dir: "str | None", slice_s: float, restore_fn) -> dict:
     """Whole passes back to back until ``seconds`` have elapsed; the
     window closes at the end of the pass then running.  With a trace
     directory, the first whole passes (``slice_s`` seconds of them, one
     pass at the least) run under the profiler, started on this, the
-    main, thread."""
+    main, thread.  After a pass that wrote, ``restore_fn(tables)`` puts
+    the tables back and the window's start moves on by its time."""
     import jax
     from benchmarks import generator
-    records, passes = [], 0
+    records, passes, kept = [], 0, set()
     sliced = None
+
+    def restored(written: set) -> float:
+        if not written:
+            return 0.0
+        t = time.perf_counter()
+        restore_fn(written)
+        return time.perf_counter() - t
+
     t0 = time.perf_counter()
     if trace_dir is not None:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -304,11 +447,12 @@ def measure(session, sets: dict, names: list, seconds: float,
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.slice"):
                 while passes == 0 or time.perf_counter() - t0 < slice_s:
-                    for stmt in generator.pass_statements(sets, names, passes):
-                        with jax.profiler.TraceAnnotation(
-                                f"bench.stmt:{stmt.label}"):
-                            records.append(run_statement(session, stmt))
+                    written = run_pass(
+                        session, generator.pass_statements(sets, names,
+                                                           passes),
+                        records, passes, jax.profiler.TraceAnnotation, kept)
                     passes += 1
+                    t0 += restored(written)
             sliced = (t0, time.perf_counter(), passes, len(records))
         finally:
             t_stop = time.perf_counter()
@@ -316,9 +460,11 @@ def measure(session, sets: dict, names: list, seconds: float,
             # writing the trace is not part of the window
             t0 += time.perf_counter() - t_stop
     while passes == 0 or time.perf_counter() - t0 < seconds:
-        for stmt in generator.pass_statements(sets, names, passes):
-            records.append(run_statement(session, stmt))
+        written = run_pass(session,
+                           generator.pass_statements(sets, names, passes),
+                           records, passes, kept=kept)
         passes += 1
+        t0 += restored(written)
     return {"start": t0, "end": time.perf_counter(), "passes": passes,
             "records": records, "slice": sliced}
 
@@ -331,49 +477,128 @@ def quantile95(values: list) -> float:
 
 # ------------------------------------------------------------ correctness
 
+def reference_tables(config: dict, raw_dir: str):
+    """The plain reference's tables: the raw files, and the refresh
+    set's staging tables where the configuration has one."""
+    from benchmarks.reference import rawdata
+    return rawdata.Tables(config["suite"], raw_dir, refresh_dir(
+        os.path.dirname(os.path.abspath(raw_dir)), config))
+
+
+def reference_columns(tables, table: str) -> dict:
+    """A table as the reference holds it now, as
+    ``compare.table_digest`` takes it."""
+    frame = tables(table, tables.columns(table))
+    return {name: (frame[name].to_numpy(), kind)
+            for name, kind in tables.schema[table]}
+
+
+def reference_module(stmt):
+    return importlib.import_module(
+        "benchmarks.reference." + stmt.template.replace("/", "."))
+
+
 def check_rows(window: dict, config: dict, raw_dir: str) -> dict:
     """Every answer of the window against the plain reference: the first
     execution of each distinct statement in full, every repeat against
-    that first one.  Runs after the window, on the host, without jax."""
+    that first one.  A read is held against the reference over the
+    state its pass's writes left (the references' ``apply`` laid over
+    the raw tables), a write by the row count of each table it writes
+    and, in the first pass of its state, by those tables' rows.  Runs
+    after the window, on the host, without jax."""
     from benchmarks import compare
     from benchmarks.reference import rawdata
-    tables = rawdata.Tables(config["suite"], raw_dir)
-    real = rawdata.Real()
+    tables, real = reference_tables(config, raw_dir), rawdata.Real()
+    # a pass's state: the writes it ran, in order; a read's answer
+    # depends on it, and every pass of one state gives the same answers
+    writes_of: dict = {}          # pass -> its writes, in order
+    for rec in window["records"]:
+        if rec["stmt"].writes:
+            writes_of.setdefault(rec.get("pass"), []).append(rec["stmt"])
+    states = {(): []}             # state -> its writes
+    for writes in writes_of.values():
+        states[tuple(w.sql for w in writes)] = writes
     first: dict = {}
+    wrote: dict = {}              # (state, position) -> write records
     repeats_differ = failed = 0
     notes = []
     for rec in window["records"]:
-        if rec["error"] is not None or not on_device(rec):
+        writes = writes_of.get(rec.get("pass"), [])
+        state = tuple(w.sql for w in writes)
+        if rec["stmt"].writes:
+            wrote.setdefault((state, writes.index(rec["stmt"])), []
+                             ).append(rec)
+        if rec["error"] is not None or not placed(rec):
             failed += 1
             notes.append(f"{rec['stmt'].label}: " + (
-                rec["error"] or f"placement {rec.get('placement')!r}, "
-                f"ladder {rec.get('ladder')}, "
-                f"{rec.get('reschedules')} reschedules"))
+                rec["error"] or "; ".join(
+                    f"placement {f.get('placement')!r}, "
+                    f"ladder {f.get('ladder')}, "
+                    f"{f.get('reschedules')} reschedules"
+                    for f in rec.get("selects", [rec]) if not on_device(f))))
             continue
-        sql = rec["stmt"].sql
+        if rec["stmt"].writes:
+            continue
+        key = (state, rec["stmt"].sql)
         d = compare.digest(rec["result"])
-        if sql not in first:
-            first[sql] = (rec, d)
-        elif first[sql][1] != d:
+        if key not in first:
+            first[key] = (rec, d)
+        elif first[key][1] != d:
             repeats_differ += 1
-    rows_wrong, gap, per_stmt = 0, 0.0, {}
-    for sql, (rec, _d) in first.items():
-        stmt = rec["stmt"]
-        ref_fn = importlib.import_module(
-            "benchmarks.reference." + stmt.template.replace("/", ".")
-        ).reference
-        ref = ref_fn(tables, stmt.params, real)
-        got, kinds = compare.result_frame(rec["result"])
-        ok, g, note = compare.compare_statement(got, kinds, ref,
-                                                 stmt.order_by)
-        per_stmt[stmt.label] = {"rows": int(got.shape[0]), "ok": ok,
-                                "rel_gap": g}
-        if not ok:
-            rows_wrong += 1
-            notes.append(f"{stmt.label}: {note}")
-        gap = max(gap, g)
+    rows_wrong, writes_wrong, gap, per_stmt = 0, 0, 0.0, {}
+    for state in sorted(states, key=len):
+        tables.overlay.clear()
+        for j, stmt in enumerate(states[state]):
+            for table, frame in reference_module(stmt).apply(
+                    tables, stmt.params, real).items():
+                tables.write(table, frame)
+            want = {t: tables.rows(t) for t in stmt.writes}
+            for rec in wrote.get((state, j), []):
+                if rec["error"] is not None:
+                    continue
+                ok = rec["rows"] == want
+                if not ok:
+                    notes.append(f"{stmt.label}: rows after it "
+                                 f"{rec['rows']} vs reference {want}")
+                elif "tables" in rec:
+                    differ = [t for t in stmt.writes if compare.table_digest(
+                        live_columns(rec["tables"][t], tables.schema[t]))
+                        != compare.table_digest(reference_columns(
+                            tables, t))]
+                    ok = not differ
+                    if differ:
+                        notes.append(f"{stmt.label}: rows of "
+                                     f"{', '.join(differ)} after it differ "
+                                     f"from the reference's (row counts "
+                                     f"{rec['rows']} agree)")
+                per_stmt[stmt.label] = {"rows": rec["rows"], "ok": ok}
+                writes_wrong += 0 if ok else 1
+        for (key_state, _sql), (rec, _d) in first.items():
+            if key_state != state:
+                continue
+            stmt = rec["stmt"]
+            ref = reference_module(stmt).reference(tables, stmt.params,
+                                                   real)
+            got, kinds = compare.result_frame(rec["result"])
+            ok, g, note = compare.compare_statement(got, kinds, ref,
+                                                     stmt.order_by)
+            per_stmt[stmt.label] = {"rows": int(got.shape[0]), "ok": ok,
+                                    "rel_gap": g}
+            if not ok:
+                rows_wrong += 1
+                notes.append(f"{stmt.label}: {note}")
+            gap = max(gap, g)
+    tables.overlay.clear()
     numbers = {"failed_statements": failed, "rows_wrong": rows_wrong,
                "repeats_differ": repeats_differ, "max_rel_gap": gap}
+    if "not_restored" in window:
+        # a mix that writes: what its writes left, and the session's
+        # tables after the window, which have to be the load-time ones
+        for table in window["not_restored"]:
+            notes.append(f"{table}: not the load-time table after the "
+                         f"window")
+        numbers["writes_wrong"] = writes_wrong
+        numbers["tables_not_restored"] = len(window["not_restored"])
     ok, checks = compare.verdict(numbers, config["limits"])
     return {"correct": ok and bool(first), "checks": checks,
             "failed": failed, "notes": notes[:20], "per_stmt": per_stmt}
@@ -453,18 +678,28 @@ def find_devices(cell: dict, config: dict):
 
 
 def warm_up(session, suite, econf, config: dict, todo: list,
-            counter: CompileCounter, spans: Spans) -> tuple:
+            counter: CompileCounter, spans: Spans, restore_fn) -> tuple:
     """Make every program of the run ready: statements this checkout has
     not yet put into the persistent cache compile concurrently in
     sessions of their own; then one untimed pass of the run's own
-    statements in the timed session (re-lower, cache load, upload)."""
+    statements in the timed session (re-lower, cache load, upload).
+    A mix that writes runs its writes first, so that the reads compile
+    against the tables as the writes leave them, and the tables are
+    restored (``restore_fn``) after that and after the untimed pass."""
     import jax
     cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
                  or jax.config.jax_compilation_cache_dir)
     t = time.monotonic()
     mark = counter.mark()
-    cold = [s for s in todo if not (cache_dir and os.path.exists(
-        warm_marker(cache_dir, config["name"], s.sql)))]
+    writes = [s for s in todo if s.writes]
+    for stmt in writes:
+        rec = run_write(session, stmt)
+        if rec["error"]:
+            say(f"warm-up {stmt.label} FAILED: {rec['error']}")
+    written = {name for s in writes for name in s.writes}
+    cold = [s for s in todo if not s.writes and not (
+        cache_dir and os.path.exists(
+            warm_marker(cache_dir, config["name"], s.sql)))]
     if cold:
         say(f"{len(cold)} of {len(todo)} statements not warmed in "
             f"{cache_dir}: compiling them concurrently")
@@ -475,10 +710,15 @@ def warm_up(session, suite, econf, config: dict, todo: list,
                 with open(warm_marker(cache_dir, config["name"], s.sql),
                           "w") as f:
                     f.write(s.label + "\n")
+    if written:
+        restore_fn(written)
     for stmt in todo:
-        rec = run_statement(session, stmt)
+        rec = (run_write(session, stmt) if stmt.writes
+               else run_statement(session, stmt))
         if rec["error"]:
             say(f"warm pass {stmt.label} FAILED: {rec['error']}")
+    if written:
+        restore_fn(written)
     runs, hits = counter.since(mark)
     spans.add("compile", t, time.monotonic(), compiler_runs=runs,
               cache_hits=hits)
@@ -520,15 +760,29 @@ def main(argv=None, tamper=None) -> int:
         root = build_warehouse(config)
         spans.add("datagen", t, time.monotonic())
 
-        suite = importlib.import_module(
-            f"nds_tpu.{config['suite']}.power").SUITE
         econf = engine_config(config)
+        refresh = refresh_dir(root, config)
+        # a refresh set's staging tables are in the catalog of the
+        # program's own data-maintenance session, and nowhere else
+        suite = (importlib.import_module(
+            f"nds_tpu.{config['suite']}.power").SUITE if refresh is None
+            else importlib.import_module(
+                f"nds_tpu.{config['suite']}.maintenance"
+            )._maintenance_suite(econf))
         t = time.monotonic()
         session = power_core.make_session(suite, econf)
         power_core.load_warehouse(
             suite, session, os.path.join(root, "wh"), "parquet",
             schemas=power_core.suite_schemas(suite, econf))
+        if refresh is not None:
+            schema = importlib.import_module(
+                f"nds_tpu.{config['suite']}.schema")
+            power_core.load_warehouse(
+                suite, session, refresh, "raw",
+                schemas=schema.get_maintenance_schemas(
+                    **power_core.schema_kwargs_for(suite, econf)))
         spans.add("load", t, time.monotonic())
+        loaded = dict(session.tables)
         if tamper is not None:
             tamper(session)
 
@@ -536,16 +790,25 @@ def main(argv=None, tamper=None) -> int:
         sets = generator.variants(mix, args.seed)
         names = generator.order(mix, args.seed)
         counter = CompileCounter()
+
+        def restore_fn(written):
+            restore(session, loaded, written, spans)
+
         runs, hits = warm_up(session, suite, econf, config,
-                             generator.distinct(mix, sets), counter, spans)
+                             generator.distinct(mix, sets), counter, spans,
+                             restore_fn)
 
         trace_dir = (os.path.join(WORK, cell["name"], "trace")
                      if args.trace else None)
         mark = counter.mark()
         setup_s = time.monotonic() - T_START
         window = measure(session, sets, names, args.seconds, trace_dir,
-                         float(mix.get("trace_slice_s", 10)))
+                         float(mix.get("trace_slice_s", 10)), restore_fn)
         window_runs, _hits = counter.since(mark)
+        if any(s.get("writes") for s in mix["statements"]):
+            window["not_restored"] = sorted(
+                t for t, table in loaded.items()
+                if session.tables.get(t) is not table)
         say(f"window {window['end'] - window['start']:.2f}s, "
             f"{window['passes']} passes, {len(window['records'])} "
             f"statements, {window_runs} compiler runs")
@@ -555,7 +818,7 @@ def main(argv=None, tamper=None) -> int:
                   "count": len(devices),
                   "memory_peak_bytes": int(max(
                       s.get("peak_bytes_in_use", 0) for s in stats))}
-        del session
+        del session, restore_fn, loaded
         gc.collect()
     finally:
         kill_children()

@@ -96,13 +96,14 @@ def test_real_cell_has_its_files_and_entries():
     assert config["guarantees"]["decimals"] == \
         one_chip["guarantees"]["decimals"]
     pass_s = {m["name"]: m for m in bench["end_to_end"]}["pass_s"]
-    assert pass_s["workloads"][-1] == "nds_h_sf1.dist4"
+    assert "nds_h_sf1.dist4" in pass_s["workloads"]
+    # the cell's own metrics, which later four-chip cells share
     new = [m for m in bench["per_layer"]
-           if m.get("workloads") == ["nds_h_sf1.dist4"]]
-    assert [m["name"] for m in new] == [
-        "collective_pct", "ici_roofline_pct", "exchange_mb_per_pass",
-        "hbm_roofline_pct.x4"]
+           if m["name"] in ("collective_pct", "ici_roofline_pct",
+                            "exchange_mb_per_pass", "hbm_roofline_pct.x4")]
+    assert len(new) == 4
     for m in new:
+        assert "nds_h_sf1.dist4" in m["workloads"]
         assert m["moves"] == "pass_s"
         assert os.path.exists(os.path.join(ROOT, "benchmarks", "layers",
                                            m["name"] + ".py"))

@@ -70,13 +70,13 @@ def test_traced_line_leaves_the_device_metrics_out():
     assert rc == 0 and line["correct"] is True, err[-3000:]
     # no device plane on the CPU: the readers of the device trace and of
     # spans laid over it (replicate_mb_per_pass among them) find nothing.
-    # Of set-up's spans the sharded path has all but a first bind (its
-    # uploads are made under device.compile): first_bind_s, listed in the
-    # rehearsal's file for this, is left out
+    # Set-up's spans are all there, a first bind among them: the sharded
+    # path binds its scans under device.bind since the operator scopes
+    # came in, so first_bind_s, listed in the rehearsal's file, is read
     assert set(line["metrics"]) == {
         "host_ms_per_stmt", "window_compiles", "compile_s", "load_s",
         "engine_init_s", "load_read_s", "load_build_s", "lower_s",
-        "cache_read_s"}
+        "cache_read_s", "first_bind_s"}
     assert line["metrics"]["window_compiles"]["value"] == 0
 
 

@@ -31,6 +31,15 @@ def test_untraced_line(run_cell, workload, seed):
     assert set(line["metrics"]) == want
     assert all(v["value"] > 0 for v in line["metrics"].values())
     assert line["device"]["platform"] == "cpu"     # never recorded
+    # a read-only mix: its line and its compared numbers as they were,
+    # every statement of every pass attempted once
+    assert set(line) == LINE_KEYS | {"passes", "checks"}
+    assert set(line["checks"]) == {"failed_statements", "rows_wrong",
+                                   "repeats_differ", "max_rel_gap"}
+    from benchmarks import generator
+    cell = {w["name"]: w for w in bench["workloads"]}[workload]
+    statements = generator.load_mix(cell["traffic"])["statements"]
+    assert line["attempted"] == line["passes"] * len(statements)
     for name in ("failed_statements", "rows_wrong", "repeats_differ",
                  "max_rel_gap"):
         assert f"compared {name}:" in err
